@@ -544,11 +544,19 @@ def bench_stream() -> None:
          })
 
 
+# f32 rounding of a row's sum over its few (power-law, ~6 per row) terms,
+# relative to the result's largest magnitude, with room to spare
+SPMV_TOL = 1e-5
+
+
 def bench_spmv() -> None:
     """Skinny-N SpMV fast lane vs the tall-N kernel at N in {1, 4, 8}:
-    the lane drops the NT grid dimension and pads N to 8 lanes instead of
-    TN=128, so every B window streams once and >90% of the padding work
-    disappears.  Results are bit-identical (asserted); the ratio is the
+    the lane pads N to 8 lanes instead of TN=128 (one column tile), so
+    every B window streams once and >90% of the padding work disappears.
+    Both kernels run compiled on a TPU and interpreted elsewhere.  The two
+    widths compile to different matmuls whose f32 sums may round
+    differently, so the lane is held to the tall-N result within
+    ``SPMV_TOL`` of its largest magnitude (asserted); the ratio is the
     lane's speedup at that width.  The ``serve_pool`` row routes a skinny
     request pool through ``impl="auto"`` and reports the scheduler's
     ``skinny_dispatches`` accounting."""
@@ -564,25 +572,26 @@ def bench_spmv() -> None:
     A = sp.from_sparse_matrix(a, tm=128, k0=128, chunk=8, bucket=True)
     for n in (1, 4, 8):
         b = jnp.asarray(rng.standard_normal((1024, n)), jnp.float32)
-        y_tall = np.asarray(sp.spmm(A, b, backend="pallas", tn=128,
-                                    interpret=True))
-        y_skinny = np.asarray(sp.spmm(A, b, backend="spmv", interpret=True))
-        bitexact = bool(np.array_equal(y_skinny, y_tall))
-        assert bitexact, f"spmv lane diverged from tall-N kernel at N={n}"
+        y_tall = np.asarray(sp.spmm(A, b, backend="pallas_onehot", tn=128))
+        y_skinny = np.asarray(sp.spmm(A, b, backend="spmv"))
+        diff = (float(np.abs(y_skinny - y_tall).max())
+                / max(float(np.abs(y_tall).max()), 1e-30))
+        assert diff <= SPMV_TOL, (
+            f"spmv lane diverged from tall-N kernel at N={n}: {diff:.2e}")
         us_t = _time_call(lambda: sp.spmm(
-            A, b, backend="pallas", tn=128,
-            interpret=True).block_until_ready())
+            A, b, backend="pallas_onehot", tn=128).block_until_ready())
         us_s = _time_call(lambda: sp.spmm(
-            A, b, backend="spmv", interpret=True).block_until_ready())
+            A, b, backend="spmv").block_until_ready())
         mnnz_t = a.nnz / (us_t / 1e6) / 1e6
         mnnz_s = a.nnz / (us_s / 1e6) / 1e6
         ratio = us_t / us_s
         _row(f"spmv_n{n}_tall", us_t, f"{mnnz_t:.2f}Mnnz/s_tn128",
              extra={"n": n, "mnnz_per_s": mnnz_t})
         _row(f"spmv_n{n}_skinny", us_s,
-             f"{mnnz_s:.2f}Mnnz/s_{ratio:.2f}x_vs_talln_bitexact",
+             f"{mnnz_s:.2f}Mnnz/s_{ratio:.2f}x_vs_talln",
              extra={"n": n, "mnnz_per_s": mnnz_s,
-                    "speedup_vs_talln": ratio, "bit_identical": bitexact})
+                    "speedup_vs_talln": ratio,
+                    "max_rel_diff_vs_talln": diff})
 
     # auto-routed skinny pool: the scheduler must count the lane
     reqs = [SpmmRequest(
@@ -713,34 +722,24 @@ def bench_autotune() -> None:
     on forced streaming (where the tuner picks the window-chunk/column-tile
     geometry the no-budget heuristic cannot), plus the cold-start story —
     ``autotune_first_build`` times this process's measure-mode plan build
-    (DB+exec persistence make it cheap on the second run over the same
-    ``SEXTANS_TUNE_DIR``), ``autotune_warm_rebuild`` rebuilds after
-    ``clear_plan_cache()`` from persisted executables, and
-    ``autotune_process2`` boots a fresh interpreter against the same tune
-    dir and reports its time-to-first-dispatch (bit-identity of every
-    tuned result is asserted/recorded throughout).  Uses
-    ``SEXTANS_TUNE_DIR`` when set (the CI smoke sets it to diff a cold vs
-    warm run), otherwise a fresh temp dir."""
-    import hashlib
+    (the TuningDB and JAX's persistent compilation cache make it cheap on
+    a second run over the same ``SEXTANS_TUNE_DIR`` and cache directory)
+    and ``autotune_warm_rebuild`` rebuilds after ``clear_plan_cache()``
+    (bit-identity of every tuned result is asserted/recorded throughout).
+    Without ``SEXTANS_TUNE_DIR`` the TuningDB lives in memory only."""
     import os
-    import subprocess
-    import sys
-    import tempfile
 
     import jax
     import jax.numpy as jnp
 
-    import repro
     import repro.sparse_api as sp
+    from repro import compile_cache
     from repro.core.engine import SextansEngine
     from repro.core.sparse import power_law_sparse
     from repro.data.matrices import magnitude_pruned
     from repro.launch.serve import SpmmRequest, serve_spmm_requests
 
-    if not os.environ.get("SEXTANS_TUNE_DIR"):
-        os.environ["SEXTANS_TUNE_DIR"] = tempfile.mkdtemp(
-            prefix="sextans-tune-")
-    tune_dir = os.environ["SEXTANS_TUNE_DIR"]
+    tune_dir = os.environ.get("SEXTANS_TUNE_DIR")
 
     rng = np.random.default_rng(0)
     # DLMC-style magnitude-pruned weight at the skinny-N boundary (N=8):
@@ -754,9 +753,9 @@ def bench_autotune() -> None:
 
     # -- cold-start: first measure-mode build in THIS process.  With a
     # pre-populated tune dir (CI run 2) the same call is a DB hit plus
-    # persisted-executable loads — no measurement, no compile.
+    # compilation-cache hits — no measurement, no compile.
     ts0 = dict(sp.TUNE_STATS)
-    ps0 = dict(sp.PLAN_STATS)
+    cc0 = dict(compile_cache.STATS)
     t0 = time.perf_counter()
     P_tuned = sp.plan(A, n, autotune="measure")
     build_s = time.perf_counter() - t0
@@ -768,10 +767,8 @@ def bench_autotune() -> None:
              "tune_db_hits": sp.TUNE_STATS["db_hits"] - ts0["db_hits"],
              "tune_db_misses": sp.TUNE_STATS["db_misses"] - ts0["db_misses"],
              "measured": sp.TUNE_STATS["measured"] - ts0["measured"],
-             "exec_persist_hits": (sp.PLAN_STATS["exec_persist_hits"]
-                                   - ps0["exec_persist_hits"]),
-             "exec_persist_stores": (sp.PLAN_STATS["exec_persist_stores"]
-                                     - ps0["exec_persist_stores"]),
+             "compile_cache_hits": (compile_cache.STATS["hits"]
+                                    - cc0["hits"]),
              "tune_dir": tune_dir,
          })
 
@@ -825,9 +822,9 @@ def bench_autotune() -> None:
                 "tuned": bool(S_tun.tuned), "bit_identical": sbit})
 
     # -- warm rebuild: drop the in-process plan cache, rebuild in cached
-    # mode — the decision comes from the DB, the executables from the
-    # persisted .jaxexec files (no re-trace/re-compile)
-    ps0 = dict(sp.PLAN_STATS)
+    # mode — the decision comes from the DB, the executable from JAX's
+    # persistent compilation cache (a re-trace, no re-compile)
+    cc0 = dict(compile_cache.STATS)
     sp.clear_plan_cache()
     t0 = time.perf_counter()
     P_warm = sp.plan(A, n, autotune="cached")
@@ -836,62 +833,13 @@ def bench_autotune() -> None:
     wbit = bool(np.array_equal(y_warm, y_ref))
     assert wbit, "warm-rebuilt plan diverged"
     _row("autotune_warm_rebuild", warm_s * 1e6,
-         f"{warm_s:.3f}s_persist_hits"
-         f"{sp.PLAN_STATS['exec_persist_hits'] - ps0['exec_persist_hits']}",
+         f"{warm_s:.3f}s_cache_hits"
+         f"{compile_cache.STATS['hits'] - cc0['hits']}",
          extra={
              "build_s": warm_s,
              "warm_lt_cold": bool(warm_s < build_s),
-             "exec_persist_hits": (sp.PLAN_STATS["exec_persist_hits"]
-                                   - ps0["exec_persist_hits"]),
+             "compile_cache_hits": compile_cache.STATS["hits"] - cc0["hits"],
              "bit_identical": wbit,
-         })
-
-    # -- process 2: a FRESH interpreter against the same tune dir must
-    # reach its first dispatch without measuring or re-tracing — the
-    # cross-process cold-start kill.  The child rebuilds the same matrix
-    # (deterministic seeds), plans in cached mode, and reports its
-    # time-to-first-dispatch + a result digest the parent checks.
-    child = (
-        "import json, time, hashlib\n"
-        "import numpy as np\n"
-        "import jax.numpy as jnp\n"
-        "import repro.sparse_api as sp\n"
-        "from repro.data.matrices import magnitude_pruned\n"
-        "w = magnitude_pruned(256, 512, 0.9, block=(16, 16), seed=1)\n"
-        "A = sp.from_dense(np.asarray(w.T, np.float32), tm=128, k0=128,\n"
-        "                  chunk=8, bucket=True)\n"
-        "rng = np.random.default_rng(0)\n"
-        "b = jnp.asarray(rng.standard_normal((A.shape[1], 8)), jnp.float32)\n"
-        "t0 = time.perf_counter()\n"
-        "P = sp.plan(A, 8, autotune='cached')\n"
-        "y = np.asarray(P.run(b))\n"
-        "dt = time.perf_counter() - t0\n"
-        "print(json.dumps({'build_s': dt,\n"
-        "                  'db_hits': sp.TUNE_STATS['db_hits'],\n"
-        "                  'db_misses': sp.TUNE_STATS['db_misses'],\n"
-        "                  'persist_hits': sp.PLAN_STATS['exec_persist_hits'],\n"
-        "                  'sha': hashlib.sha256(y.tobytes()).hexdigest()}))\n"
-    )
-    env = dict(os.environ)
-    src_root = os.path.dirname(os.path.dirname(
-        os.path.abspath(repro.__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [src_root] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep)
-                      if p])
-    proc = subprocess.run([sys.executable, "-c", child], env=env,
-                          capture_output=True, text=True, check=True)
-    rep = json.loads(proc.stdout.strip().splitlines()[-1])
-    p2bit = rep["sha"] == hashlib.sha256(y_ref.tobytes()).hexdigest()
-    assert p2bit, "process-2 result diverged from process 1"
-    _row("autotune_process2", rep["build_s"] * 1e6,
-         f"{rep['build_s']:.3f}s_to_first_dispatch_db_hits{rep['db_hits']}"
-         f"_persist{rep['persist_hits']}_bitexact",
-         extra={
-             "build_s": rep["build_s"],
-             "tune_db_hits": rep["db_hits"],
-             "tune_db_misses": rep["db_misses"],
-             "exec_persist_hits": rep["persist_hits"],
-             "bit_identical": p2bit,
          })
 
     # -- serving pool, default vs engine-tuned: the scheduler threads the
@@ -1058,6 +1006,9 @@ def main() -> None:
         import os
 
         os.environ["SEXTANS_CHECK"] = "1"
+    from repro import compile_cache
+
+    compile_cache.enable()
     sections = [
         ("table1", bench_table1),
         ("fig7", lambda: bench_fig7(args.budget)),

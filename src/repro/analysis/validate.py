@@ -42,6 +42,8 @@ from typing import Any, Optional
 
 import numpy as np
 
+from repro.core.hflex import slab_lanes
+
 __all__ = ["InvariantViolation", "validate", "maybe_validate", "enabled",
            "ENV_VAR"]
 
@@ -136,13 +138,20 @@ def _validate_packed(d: Any, where: str, m: Optional[int] = None,
     m = d.m if m is None else m
     k = d.k if k is None else k
 
-    if vals.ndim not in (3, 4):
-        _fail(f"{where}: vals must be (MB, NW, LW) or (G, MB, NW, LW), "
+    if vals.ndim not in (4, 5):
+        _fail(f"{where}: vals must be (MB, NW, R, L) or (G, MB, NW, R, L), "
               f"got ndim={vals.ndim}")
     for name, arr in (("cols", cols), ("rows", rows)):
         if arr.shape != vals.shape:
             _fail(f"{where}: {name} shape {arr.shape} != vals shape "
                   f"{vals.shape}")
+    lanes = vals.shape[-1]
+    if slab_lanes(vals.shape[-2] * lanes) != lanes:
+        _fail(f"{where}: slab rows of {lanes} lanes do not match the lane "
+              f"layout of LW={vals.shape[-2] * lanes}")
+    # checks below read the flat slot axis: ([G,] MB, NW, LW)
+    vals, cols, rows = (x.reshape(*x.shape[:-2], -1)
+                        for x in (vals, cols, rows))
     for name, arr in (("q", q), ("nse", nse)):
         if arr.shape != vals.shape[:-1]:
             _fail(f"{where}: {name} shape {arr.shape} != slab prefix "

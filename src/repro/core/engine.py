@@ -24,7 +24,8 @@ The engine restores the HFlex property by
 
 The engine is a thin stats-and-sharding wrapper over the unified front-end
 :mod:`repro.sparse_api` (SparseTensor + backend registry); ``impl`` is a
-registered backend name ("pallas" | "pallas_onehot" | "jnp" | "auto").
+registered backend name ("auto" — the default, platform-aware — |
+"pallas" | "pallas_onehot" | "jnp" | ...).
 
 :meth:`SextansEngine.spmm_async` is the futures-based entry point: the
 pack runs host-resident (``pack(device=False)``) on a worker thread, the
@@ -37,7 +38,9 @@ the async pipeline's threads and the owning thread can share one engine.
 Also provides the multi-chip execution plan: A row-blocks sharded across
 the ``data`` axis (the paper's `row mod P` lifted to chips — C shards are
 disjoint, the inner loop needs **zero** cross-chip collectives), B
-column-tiles sharded across ``model``.
+column-tiles sharded across ``model``.  On the Pallas backends each chip
+runs the kernel on its own row blocks under ``shard_map`` (XLA cannot
+partition a kernel call itself).
 """
 
 from __future__ import annotations
@@ -94,8 +97,8 @@ class EngineStats:
     tune_db_misses: int = 0
     # plan-build wall time, split by whether the build compiled something
     # (cold: PLAN_STATS exec_misses grew — trace+compile and, in measure
-    # mode, tuning measurement) or reused executables (warm: cache or
-    # cross-process persisted load)
+    # mode, tuning measurement) or reused an executable of the plan cache
+    # (warm)
     plan_builds_cold: int = 0
     plan_builds_warm: int = 0
     plan_build_cold_s: float = 0.0
@@ -132,7 +135,7 @@ class SextansEngine:
         k0: int = 4096,
         chunk: int = 8,
         tn: int = 128,
-        impl: str = "pallas",
+        impl: str = "auto",
         interleave: bool = True,
         bucket: bool = True,
         interpret: Optional[bool] = None,
@@ -264,7 +267,7 @@ class SextansEngine:
         # Snapshot the module counters around the build so this engine's
         # stats attribute the deltas to itself: a build that grew
         # exec_misses compiled something (cold); one that did not reused a
-        # cached or cross-process persisted executable (warm).
+        # cached executable (warm).
         db_hits0 = TUNE_STATS["db_hits"]
         db_misses0 = TUNE_STATS["db_misses"]
         exec_misses0 = PLAN_STATS["exec_misses"]
@@ -538,7 +541,7 @@ class SextansEngine:
     def shard_specs(data_axis: str = "data", model_axis: str = "model") -> Dict[str, P]:
         """PartitionSpecs for the sharded SpMM:
 
-        * slabs (MB, NW, LW): MB over data — each chip owns disjoint row
+        * slabs (MB, NW, R, L): MB over data — each chip owns disjoint row
           blocks => disjoint C rows => no collective in the compute loop
           (the paper's disjoint-PE property, Eq. 4, at chip scale);
         * B (K, N): N over model — the N0 column-tile loop of Eq. 2 at chip
@@ -546,9 +549,9 @@ class SextansEngine:
         * C (M, N): M over data, N over model — fully disjoint shards.
         """
         return {
-            "vals": P(data_axis, None, None),
-            "cols": P(data_axis, None, None),
-            "rows": P(data_axis, None, None),
+            "vals": P(data_axis, None, None, None),
+            "cols": P(data_axis, None, None, None),
+            "rows": P(data_axis, None, None, None),
             "q": P(data_axis, None),
             "nse": P(data_axis, None),
             "b": P(None, model_axis),

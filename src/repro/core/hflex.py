@@ -14,7 +14,11 @@ Two packed representations are produced from one :class:`SparseMatrix`:
    ``vals/cols/rows : (MB, NW, LW)`` with a count matrix ``q : (MB, NW)``.
    ``q`` is passed to the Pallas kernel as a *scalar-prefetch* operand —
    the TPU incarnation of the paper's pointer list Q: one compiled kernel
-   executes any matrix whose padded geometry fits the bucket.
+   executes any matrix whose padded geometry fits the bucket.  On the
+   device each slab is stored lane-major as ``(LW // L, L)`` rows of
+   ``L = min(LW, 128)`` lanes (:func:`lane_major`): the (8, 128) tile a
+   Pallas TPU block must be made of, and a layout XLA keeps row-major, so
+   the kernel reads the stored payload without a relayout copy.
 
 Padding slots carry ``val = 0`` so they are computationally inert (the
 paper's bubbles); correctness never depends on ``q``.
@@ -40,6 +44,10 @@ __all__ = [
     "BlockSlabs",
     "pack_block_slabs",
     "bucket_geometry",
+    "LANES",
+    "slab_lanes",
+    "slab_lw",
+    "lane_major",
 ]
 
 # ---------------------------------------------------------------------------
@@ -485,7 +493,7 @@ def pack_block_slabs(
     flat = blk.astype(np.int64) * nw + win
     counts = np.bincount(flat, minlength=mb * nw).reshape(mb, nw)
     lw_needed = int(counts.max()) if counts.size else 0
-    lw = max(chunk, cdiv(max(lw_needed, 1), chunk) * chunk)
+    lw = slab_lw(max(chunk, cdiv(max(lw_needed, 1), chunk) * chunk))
     if bucket:
         lw = bucket_geometry(mb, nw, lw, 1)[2]
     if lw_bucket is not None:
@@ -516,6 +524,39 @@ def pack_block_slabs(
     )
     bs.interleaved = bool(interleave and mb > 1)  # type: ignore[attr-defined]
     return bs
+
+
+#: Lane width of a TPU vector register: a device slab row holds this many
+#: packed non-zeros (fewer only when the whole slab is narrower).
+LANES = 128
+
+
+def slab_lanes(lw: int) -> int:
+    """Lanes per device slab row for slab width ``lw``."""
+    return lw if lw < LANES else LANES
+
+
+def slab_lw(lw: int) -> int:
+    """Round a slab width up to one the device layout can hold: any width
+    below one lane row, whole lane rows up to one (8, 128) tile, and whole
+    tiles beyond — so a slab of more than 8 rows is a stack of full tiles.
+    Power-of-two widths (the LW buckets) are already valid."""
+    if lw <= LANES:
+        return lw
+    unit = LANES if lw <= 8 * LANES else 8 * LANES
+    return cdiv(lw, unit) * unit
+
+
+def lane_major(x):
+    """``(..., LW)`` slab array -> its device layout ``(..., LW // L, L)``
+    (a row-major reshape: the flat slot order is unchanged).  Works on
+    numpy and jax arrays."""
+    lw = x.shape[-1]
+    if slab_lw(lw) != lw:
+        raise ValueError(f"slab width LW={lw} has no lane layout; round it "
+                         f"with slab_lw() (-> {slab_lw(lw)})")
+    lanes = slab_lanes(lw)
+    return x.reshape(*x.shape[:-1], lw // lanes, lanes)
 
 
 def cdiv_arr(a: np.ndarray, b: int) -> np.ndarray:

@@ -1,14 +1,10 @@
-"""Pallas-TPU API compatibility across jax versions."""
+"""Platform default for the Pallas ``interpret`` flag."""
 
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:

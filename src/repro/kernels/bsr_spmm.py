@@ -11,53 +11,131 @@ y[bm, f_tile] = Σ_{i ∈ Q[f_tile]} x[bm, brow(i)] @ W_block(i)
 
 Layout: blocks sorted by block-column (output tile); ``indptr`` (NF+1) is
 the CSR-style pointer list over output tiles; ``brow`` gives each block's
-K-tile. Grid: (BM tiles, NF tiles); the inner fori_loop trip count is
-data-dependent via scalar prefetch — one compiled kernel serves any
-sparsity pattern of the same bucketed geometry (HFlex).
+K-tile. Grid: (BM tiles, NF tiles, J block slots).  Slot ``j`` of output
+tile ``f`` is block ``indptr[f] + j``: the index maps read ``indptr`` and
+``brow`` from scalar prefetch, so each grid step streams exactly one
+``(TB, TK)`` tile of ``x`` and one ``(TK, TF)`` weight block HBM→VMEM
+into a resident f32 accumulator.  Slots past a tile's block count repeat
+the last block index (no new DMA) and skip the matmul.  One compiled
+kernel serves any sparsity pattern of the same bucketed geometry (HFlex).
+
+On TPU the blocks are lane tiles: ``TK`` and ``TF`` must be multiples of
+128 (``repro.sparse_api.plan`` refuses other BSR tilings there).
 """
 
 from __future__ import annotations
 
-from typing import Optional
-
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
 from ._compat import resolve_interpret as _resolve_interpret
 
 __all__ = ["bsr_matmul_pallas", "bsr_matmul_pallas_batched"]
 
 
-def _kernel(
-    indptr_ref,     # (NF+1,) i32 scalar prefetch
-    brow_ref,       # (NB,)   i32 scalar prefetch
-    x_ref,          # (TB, K) — full K stripe of x for this batch tile
-    blocks_ref,     # (NB, TK, TF) — all weight blocks (HBM->VMEM by index)
-    o_ref,          # (TB, TF)
-    *,
-    tk: int,
-):
-    f = pl.program_id(1)
-    start = indptr_ref[f]
-    stop = indptr_ref[f + 1]
+def _kernel(indptr_ref, brow_ref, x_ref, w_ref, o_ref, acc_ref, *,
+            batched: bool):
+    # refs: x ([1,] TB, TK), w ([1,] 1, TK, TF), o ([1,] TB, TF)
+    off = 1 if batched else 0
+    f = pl.program_id(1 + off)
+    j = pl.program_id(2 + off)
+    if batched:
+        g = pl.program_id(0)
+        count = indptr_ref[g, f + 1] - indptr_ref[g, f]
+    else:
+        count = indptr_ref[f + 1] - indptr_ref[f]
 
-    x = x_ref[...].astype(jnp.float32)      # (TB, K)
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def body(i, acc):
-        kblk = brow_ref[i]
-        xs = jax.lax.dynamic_slice_in_dim(x, kblk * tk, tk, axis=1)  # (TB, TK)
-        wb = blocks_ref[i].astype(jnp.float32)                       # (TK, TF)
-        return acc + jax.lax.dot_general(
-            xs, wb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    @pl.when(j < count)
+    def _accumulate():
+        x = (x_ref[0] if batched else x_ref[...]).astype(jnp.float32)
+        w = (w_ref[0, 0] if batched else w_ref[0]).astype(jnp.float32)
+        acc_ref[...] += jax.lax.dot_general(
+            x, w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
 
-    acc0 = jnp.zeros(o_ref.shape, jnp.float32)
-    o_ref[...] = jax.lax.fori_loop(start, stop, body, acc0).astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(2 + off) - 1)
+    def _store():
+        res = acc_ref[...].astype(o_ref.dtype)
+        if batched:
+            o_ref[0] = res
+        else:
+            o_ref[...] = res
+
+
+def _slot(ip, f, j, nb: int):
+    """Block index of slot ``j`` of output tile ``f``: ``indptr[f] + j``,
+    held at the tile's last block once ``j`` passes its count (so the
+    pipeline issues no new DMA) and clamped into ``[0, NB)`` for empty
+    tiles."""
+    start, stop = ip(f), ip(f + 1)
+    return jnp.clip(jnp.minimum(start + j, stop - 1), 0, nb - 1)
+
+
+def _call(x, blocks, brow, indptr, *, tb, tk, tf, interpret, batched):
+    interpret = _resolve_interpret(interpret)
+    bsz, k = x.shape[-2:]
+    nb = blocks.shape[-3]
+    nf = indptr.shape[-1] - 1
+    assert bsz % tb == 0 and k % tk == 0
+    assert blocks.shape[-2:] == (tk, tf)
+    # slots per output tile: a tile holds at most min(NB, K/TK) blocks
+    nj = max(1, min(nb, k // tk))
+    if batched:
+        g_sz = x.shape[0]
+        assert blocks.shape[0] == g_sz
+        grid = (g_sz, bsz // tb, nf, nj)
+
+        def x_map(g, b, f, j, ip, br):
+            return (g, b, br[g, _slot(lambda i: ip[g, i], f, j, nb)])
+
+        def w_map(g, b, f, j, ip, br):
+            return (g, _slot(lambda i: ip[g, i], f, j, nb), 0, 0)
+
+        in_specs = [pl.BlockSpec((1, tb, tk), x_map),
+                    pl.BlockSpec((1, 1, tk, tf), w_map)]
+        out_specs = pl.BlockSpec((1, tb, tf), lambda g, b, f, j, ip, br:
+                                 (g, b, f))
+        out_shape = jax.ShapeDtypeStruct((g_sz, bsz, nf * tf), x.dtype)
+        semantics = ("parallel", "parallel", "parallel", "arbitrary")
+    else:
+        grid = (bsz // tb, nf, nj)
+
+        def x_map(b, f, j, ip, br):
+            return (b, br[_slot(lambda i: ip[i], f, j, nb)])
+
+        def w_map(b, f, j, ip, br):
+            return (_slot(lambda i: ip[i], f, j, nb), 0, 0)
+
+        in_specs = [pl.BlockSpec((tb, tk), x_map),
+                    pl.BlockSpec((1, tk, tf), w_map)]
+        out_specs = pl.BlockSpec((tb, tf), lambda b, f, j, ip, br: (b, f))
+        out_shape = jax.ShapeDtypeStruct((bsz, nf * tf), x.dtype)
+        semantics = ("parallel", "parallel", "arbitrary")
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((tb, tf), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, batched=batched),
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        name="bsr_spmm",
+    )(indptr, brow, x, blocks)
 
 
 @functools.partial(
@@ -76,60 +154,8 @@ def bsr_matmul_pallas(
 ) -> jax.Array:
     """y = x @ W for block-sparse W. x padded to (B % tb == 0, K % tk == 0);
     output (B, NF*tf). ``interpret=None`` interprets only off-TPU."""
-    interpret = _resolve_interpret(interpret)
-    bsz, k = x.shape
-    nb = blocks.shape[0]
-    nf = indptr.shape[0] - 1
-    assert bsz % tb == 0 and k % tk == 0
-    assert blocks.shape[1:] == (tk, tf)
-
-    grid = (bsz // tb, nf)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tb, k), lambda b, f, ip, br: (b, 0)),
-            pl.BlockSpec((nb, tk, tf), lambda b, f, ip, br: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tb, tf), lambda b, f, ip, br: (b, f)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, tk=tk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, nf * tf), x.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-        ),
-    )(indptr, brow, x, blocks)
-
-
-def _kernel_batched(
-    indptr_ref,     # (G, NF+1) i32 scalar prefetch
-    brow_ref,       # (G, NB)   i32 scalar prefetch
-    x_ref,          # (1, TB, K) — member g's x stripe for this batch tile
-    blocks_ref,     # (1, NB, TK, TF) — member g's weight blocks
-    o_ref,          # (1, TB, TF)
-    *,
-    tk: int,
-):
-    g = pl.program_id(0)
-    f = pl.program_id(2)
-    start = indptr_ref[g, f]
-    stop = indptr_ref[g, f + 1]
-
-    x = x_ref[0].astype(jnp.float32)        # (TB, K)
-
-    def body(i, acc):
-        kblk = brow_ref[g, i]
-        xs = jax.lax.dynamic_slice_in_dim(x, kblk * tk, tk, axis=1)  # (TB, TK)
-        wb = blocks_ref[0, i].astype(jnp.float32)                    # (TK, TF)
-        return acc + jax.lax.dot_general(
-            xs, wb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    acc0 = jnp.zeros(o_ref.shape[1:], jnp.float32)
-    o_ref[0] = jax.lax.fori_loop(start, stop, body, acc0).astype(o_ref.dtype)
+    return _call(x, blocks, brow, indptr, tb=tb, tk=tk, tf=tf,
+                 interpret=interpret, batched=False)
 
 
 @functools.partial(
@@ -152,30 +178,5 @@ def bsr_matmul_pallas_batched(
     blocks; the pointer walk never reaches the zero padding, so each
     member's result is bit-identical to :func:`bsr_matmul_pallas` on its
     own payload.  Output ``(G, B, NF*tf)``."""
-    interpret = _resolve_interpret(interpret)
-    g, bsz, k = x.shape
-    nb = blocks.shape[1]
-    nf = indptr.shape[1] - 1
-    assert bsz % tb == 0 and k % tk == 0
-    assert blocks.shape[0] == g and blocks.shape[2:] == (tk, tf)
-
-    grid = (g, bsz // tb, nf)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, tb, k), lambda gg, b, f, ip, br: (gg, b, 0)),
-            pl.BlockSpec((1, nb, tk, tf),
-                         lambda gg, b, f, ip, br: (gg, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tb, tf), lambda gg, b, f, ip, br: (gg, b, f)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel_batched, tk=tk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((g, bsz, nf * tf), x.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
-        ),
-    )(indptr, brow, x, blocks)
+    return _call(x, blocks, brow, indptr, tb=tb, tk=tk, tf=tf,
+                 interpret=interpret, batched=True)

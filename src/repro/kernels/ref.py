@@ -34,10 +34,13 @@ def spmm_slabs_ref(vals, cols, rows, q, b, c_in, k0, tm, alpha=1.0, beta=0.0):
     """Oracle on the *packed slab format* — computes exactly what the kernel
     must produce on its (possibly padded/permuted) operands.
 
-    vals/cols/rows: (MB, NW, LW); q: (MB, NW); b: (NW*K0, N) padded;
-    c_in: (MB*TM, N) padded (already block-permuted if interleaved).
-    Padding slots have val == 0 so they contribute nothing.
+    vals/cols/rows: (MB, NW, LW) or lane-major (MB, NW, R, L); q: (MB, NW);
+    b: (NW*K0, N) padded; c_in: (MB*TM, N) padded (already block-permuted
+    if interleaved).  Padding slots have val == 0 so they contribute
+    nothing.
     """
+    vals, cols, rows = (x.reshape(*x.shape[:2], -1)
+                        for x in (vals, cols, rows))
     mb, nw, lw = vals.shape
     n = b.shape[1]
 

@@ -6,11 +6,13 @@ TPU re-derivation of the paper's streaming dataflow (DESIGN.md §2):
   (K0 × TN) HBM→VMEM — the BRAM window of the paper;
 * the C tile (TM × TN, fp32) lives in a VMEM scratch accumulator across
   all windows — the URAM scratchpad of the paper;
-* packed non-zero slabs (vals/cols/rows) are processed CHUNK at a time;
-  the scatter ``c[row] += val * b[col]`` is performed as a one-hot MXU
-  matmul, which reduces over the chunk axis associatively — this *is* the
-  resolution of the paper's RAW hazard on TPU (no D-cycle distance exists
-  to schedule around);
+* packed non-zero slabs (vals/cols/rows) arrive lane-major: each
+  (block, window) slab is an ``(R, L)`` tile (``L`` = 128 lanes, see
+  :func:`repro.core.hflex.slab_lanes`), and the kernel walks it one
+  ``L``-wide row of non-zeros per trip.  The scatter ``c[row] += val *
+  b[col]`` is a one-hot MXU matmul (TM × L) @ (L × TN) with the values
+  folded into the one-hot — this *is* the resolution of the paper's RAW
+  hazard on TPU (no D-cycle distance exists to schedule around);
 * the per-(block, window) non-zero count matrix ``q`` is a scalar-prefetch
   operand driving data-dependent ``fori_loop`` trip counts — the paper's
   HFlex pointer list Q;
@@ -22,10 +24,13 @@ TPU re-derivation of the paper's streaming dataflow (DESIGN.md §2):
 
 Two gather strategies for B rows:
 
-* ``gather``  — vector row-gather from the VMEM window (dynamic-gather on
-  sublanes; supported by modern Mosaic for 32-bit element types).
-* ``onehot``  — gather as a second one-hot matmul (CHUNK × K0) @ (K0 × TN):
-  guaranteed-lowerable on any MXU, trades FLOPs for regularity.
+* ``onehot``  — gather as a second one-hot matmul (K0 × L)ᵀ @ (K0 × TN):
+  the strategy that lowers through Mosaic, so the TPU path uses it.
+* ``gather``  — vector row-gather ``bwin[c]`` from the VMEM window.  Mosaic
+  refuses this gather, so it runs in interpret mode only.
+
+Both matmuls run at ``Precision.HIGHEST``: the operands are float32 and a
+default-precision TPU matmul would round them to bfloat16.
 
 Grid: (MB, NT, NW), windows innermost so the output block and accumulator
 stay resident while K streams — the exact loop nest of paper Algorithm 1
@@ -35,35 +40,78 @@ with (i ↔ NT, j ↔ NW, p·q ↔ intra-kernel parallelism).
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
 from ._compat import resolve_interpret as _resolve_interpret
 
 __all__ = ["sextans_spmm_pallas"]
 
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _slab_row(ref, i, lead: int, tile_rows: int):
+    """Row ``i`` (a ``(1, L)`` vector) of the ``(R, L)`` slab block ``ref``.
+
+    Mosaic only loads sublane-aligned tiles at a dynamic offset, so the
+    aligned ``(tile_rows, L)`` tile holding row ``i`` is loaded and the row
+    is selected with a mask and a sum over the tile (exact: the other terms
+    are zeros).  ``lead`` is the number of leading size-1 block axes."""
+    idx = (0,) * lead
+    if tile_rows == 1:
+        return ref[idx + (pl.ds(i, 1), slice(None))]
+    base = pl.multiple_of((i // tile_rows) * tile_rows, tile_rows)
+    tile = ref[idx + (pl.ds(base, tile_rows), slice(None))]
+    sel = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0) == i % tile_rows
+    return jnp.sum(jnp.where(sel, tile, jnp.zeros_like(tile)), axis=0,
+                   keepdims=True)
+
+
+def _scatter_trip(acc, v, c, r, bwin, *, tm: int, gather: str):
+    """One trip of ``L`` packed non-zeros: ``acc[r] += v * bwin[c]``.
+
+    ``v``/``c``/``r`` are ``(1, L)`` rows; ``bwin`` is the (K0, W) window.
+    The row scatter is a (TM × L) @ (L × W) matmul with the values folded
+    into the one-hot, so every product ``v * b`` is formed once, exactly
+    as the flat reference forms it."""
+    lanes = v.shape[-1]
+    if gather == "onehot":
+        k0 = bwin.shape[0]
+        oh_c = (jax.lax.broadcasted_iota(jnp.int32, (k0, lanes), 0)
+                == c).astype(jnp.float32)
+        brows = jax.lax.dot_general(                       # (L, W)
+            oh_c, bwin, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_HI)
+    else:
+        brows = bwin[c[0], :]                               # (L, W)
+    oh_rv = jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (tm, lanes), 0) == r,
+        v.astype(jnp.float32), 0.0)                         # (TM, L)
+    return acc + jax.lax.dot_general(
+        oh_rv, brows, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_HI)
+
 
 def _kernel(
-    q_ref,            # ([G,] MB, NW) int32, scalar prefetch (SMEM)
-    vals_ref,         # ([1,] 1, 1, LW) f32
-    cols_ref,         # ([1,] 1, 1, LW) i32
-    rows_ref,         # ([1,] 1, 1, LW) i32
+    q_ref,            # ([G *] MB * NW,) int32, scalar prefetch (SMEM)
+    vals_ref,         # ([1,] 1, 1, R, L) f32
+    cols_ref,         # ([1,] 1, 1, R, L) i32
+    rows_ref,         # ([1,] 1, 1, R, L) i32
     b_ref,            # ([1,] K0, TN)
     cin_ref,          # ([1,] TM, TN)
-    ab_ref,           # (1, 2) f32 SMEM block: [alpha, beta] (traced
-                      # epilogue; batched runs may index it per group)
+    ab_ref,           # ([G,] 2) f32 in SMEM: [alpha, beta] (traced
+                      # epilogue; a batched run reads its group's row)
     out_ref,          # ([1,] TM, TN)
     acc_ref,          # VMEM scratch (TM, TN) f32
     *,
     tm: int,
-    k0: int,
-    chunk: int,
+    mb: int,
     nw: int,
+    tile_rows: int,
     gather: str,
     batched: bool,
     accumulate: bool,
@@ -75,66 +123,43 @@ def _kernel(
     off = 1 if batched else 0
     w = pl.program_id(2 + off)
 
+    def _tile(ref):
+        return ref[0] if batched else ref[...]
+
     @pl.when(w == 0)
     def _init():
         if accumulate:
             # Out-of-core streaming: seed from the carried f32 accumulator
             # (c_in doubles as acc-in), so a chain of window-chunk dispatches
             # performs the exact add sequence of one full-NW launch.
-            acc_ref[...] = (cin_ref[0] if batched
-                            else cin_ref[...]).astype(jnp.float32)
+            acc_ref[...] = _tile(cin_ref).astype(jnp.float32)
         else:
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    m = pl.program_id(off)
-    if batched:
-        count = q_ref[pl.program_id(0), m, w]
-    else:
-        count = q_ref[m, w]                   # real (chunk-ceiled) nnz here
-
-    def _slab(ref, sl):
-        return ref[0, 0, 0, sl] if batched else ref[0, 0, sl]
-
-    def _tile(ref):
-        return ref[0] if batched else ref[...]
+    # (program ids are read here, outside the pl.when bodies: the
+    # interpreter only substitutes them at the kernel's top level)
+    g = pl.program_id(0) if batched else 0
+    m = g * mb + pl.program_id(off)
+    count = q_ref[m * nw + w]                 # real (chunk-ceiled) nnz here
 
     # Empty-slab skip: a (block, window) pair with zero non-zeros (sparsity
     # structure, known from the prefetched pointer matrix q) contributes
-    # nothing — skip the VMEM read of the B window and the accumulate
-    # entirely.  The grid still visits the step (the window stream is the
-    # ``arbitrary`` innermost dimension) but executes no vector work.
+    # nothing — skip the accumulate entirely.  The grid still visits the
+    # step (the window stream is the ``arbitrary`` innermost dimension)
+    # but executes no vector work.
     @pl.when(count > 0)
     def _process_window():
-        nchunks = count // chunk
+        lanes = vals_ref.shape[-1]
         bwin = _tile(b_ref).astype(jnp.float32)  # (K0, TN) window in VMEM
-        # Loop-invariant one-hot iotas, hoisted out of the chunk loop.
-        row_iota = jax.lax.broadcasted_iota(jnp.int32, (tm, chunk), 0)
-        col_iota = (jax.lax.broadcasted_iota(jnp.int32, (chunk, k0), 1)
-                    if gather == "onehot" else None)
+        lead = 3 if batched else 2
 
-        def body(ci, acc):
-            sl = pl.ds(ci * chunk, chunk)
-            v = _slab(vals_ref, sl).astype(jnp.float32)       # (CH,)
-            c = _slab(cols_ref, sl)                           # (CH,)
-            r = _slab(rows_ref, sl)                           # (CH,)
-            if gather == "onehot":
-                # (CH, K0) one-hot of column ids  @  (K0, TN) window
-                oh_c = (col_iota == c[:, None]).astype(jnp.float32)
-                brows = jax.lax.dot_general(
-                    oh_c, bwin, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            else:
-                brows = bwin[c, :]                            # (CH, TN) row gather
-            contrib = v[:, None] * brows                      # (CH, TN)
-            # scatter-by-row as one-hot matmul: (TM, CH) @ (CH, TN)
-            oh_r = (row_iota == r[None, :]).astype(jnp.float32)
-            return acc + jax.lax.dot_general(
-                oh_r, contrib, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+        def body(i, acc):
+            v, c, r = (_slab_row(ref, i, lead, tile_rows)
+                       for ref in (vals_ref, cols_ref, rows_ref))
+            return _scatter_trip(acc, v, c, r, bwin, tm=tm, gather=gather)
 
-        acc_ref[...] = jax.lax.fori_loop(0, nchunks, body, acc_ref[...])
+        trips = (count + lanes - 1) // lanes
+        acc_ref[...] = jax.lax.fori_loop(0, trips, body, acc_ref[...])
 
     @pl.when(w == nw - 1)
     def _epilogue():
@@ -143,8 +168,8 @@ def _kernel(
             # dispatch (alpha/beta are applied once, after the last chunk).
             res = acc_ref[...].astype(out_ref.dtype)
         else:
-            alpha = ab_ref[0, 0]
-            beta = ab_ref[0, 1]
+            alpha = ab_ref[g, 0]
+            beta = ab_ref[g, 1]
             res = (
                 alpha * acc_ref[...]
                 + beta * _tile(cin_ref).astype(jnp.float32)
@@ -155,15 +180,39 @@ def _kernel(
             out_ref[...] = res
 
 
+def _epilogue_operand(alpha, beta, g_sz: Optional[int]):
+    """The SMEM epilogue operand: ``(1, 2)`` for one matrix, ``(G, 2)`` —
+    one row per member — for a group.  Scalars broadcast, so uniform and
+    mixed (scalar/vector) epilogues share one signature."""
+    a_f = jnp.asarray(alpha, jnp.float32)
+    b_f = jnp.asarray(beta, jnp.float32)
+    rows = 1 if g_sz is None else g_sz
+    return jnp.stack([jnp.broadcast_to(a_f, (rows,)),
+                      jnp.broadcast_to(b_f, (rows,))], axis=-1)
+
+
+def _slab_specs(batched: bool, r: int, lanes: int):
+    """Block specs of the three slab operands: one (block, window) slab
+    ``(R, L)`` per grid step, addressed by the [group and] block program
+    ids and the window id — the last grid index, which the index map sees
+    just before the scalar-prefetch ref."""
+    if batched:
+        spec = pl.BlockSpec((1, 1, 1, r, lanes),
+                            lambda g, m, *rest: (g, m, rest[-2], 0, 0))
+    else:
+        spec = pl.BlockSpec((1, 1, r, lanes),
+                            lambda m, *rest: (m, rest[-2], 0, 0))
+    return [spec] * 3
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("tm", "k0", "chunk", "tn", "gather", "interpret",
-                     "accumulate"),
+    static_argnames=("tm", "k0", "tn", "gather", "interpret", "accumulate"),
 )
 def sextans_spmm_pallas(
-    vals: jax.Array,      # ([G,] MB, NW, LW) f32
-    cols: jax.Array,      # ([G,] MB, NW, LW) i32
-    rows: jax.Array,      # ([G,] MB, NW, LW) i32
+    vals: jax.Array,      # ([G,] MB, NW, R, L) f32
+    cols: jax.Array,      # ([G,] MB, NW, R, L) i32
+    rows: jax.Array,      # ([G,] MB, NW, R, L) i32
     q: jax.Array,         # ([G,] MB, NW) i32
     b: jax.Array,         # ([G,] NW*K0, N_pad)
     c_in: jax.Array,      # ([G,] MB*TM, N_pad)
@@ -172,7 +221,6 @@ def sextans_spmm_pallas(
     *,
     tm: int,
     k0: int,
-    chunk: int,
     tn: int = 128,
     gather: str = "gather",
     interpret: Optional[bool] = None,
@@ -182,7 +230,7 @@ def sextans_spmm_pallas(
     the user-facing API (handles packing, padding, permutation, autodiff).
 
     ``alpha``/``beta`` are *dynamic* operands (delivered to the kernel as a
-    (1, 2) SMEM block): sweeping them re-uses one compiled executable.  In
+    (1, 2) SMEM table): sweeping them re-uses one compiled executable.  In
     batched mode they may also be ``(G,)`` vectors — each group member's
     epilogue reads its own SMEM row, bit-identical to running that member
     alone with its scalar (α, β), which lets a serving scheduler fold
@@ -190,7 +238,7 @@ def sextans_spmm_pallas(
     ``interpret=None`` (the default) interprets only off-TPU — on a TPU the
     kernel compiles through Mosaic without the caller opting in.
 
-    4-D ``vals`` (and correspondingly 3-D ``b``/``c_in``/``q``) select the
+    5-D ``vals`` (and correspondingly 3-D ``b``/``c_in``/``q``) select the
     *batched* grid ``(G, MB, NT, NW)``: G stacked bucket-mate matrices run
     as one kernel launch — the dispatch-amortization analogue of the
     paper's multi-channel HBM parallelism, with the group as the outermost
@@ -200,74 +248,61 @@ def sextans_spmm_pallas(
     carried f32 accumulator that seeds the VMEM scratch at window 0, the
     epilogue is suppressed, and the raw f32 accumulator is emitted.  A
     chain of such dispatches over consecutive K0-window chunks performs the
-    exact per-(row, tile) add sequence of one full-NW launch, so streaming
-    a matrix larger than device memory stays bit-identical to the resident
-    path (apply alpha/beta once on the final accumulator).
+    exact per-(row, tile) add sequence of one full-NW launch (apply
+    alpha/beta once on the final accumulator).
+
+    A slab's trips walk whole ``L``-wide rows of non-zeros, ``ceil(q /
+    L)`` of them; padding slots hold zeros and add nothing.
     """
     interpret = _resolve_interpret(interpret)
     if accumulate:
         assert c_in.dtype == jnp.float32, "accumulate carries an f32 acc"
-    batched = vals.ndim == 4
-    mb, nw, lw = vals.shape[-3:]
+    batched = vals.ndim == 5
+    mb, nw, r, lanes = vals.shape[-4:]
     kpad, npad = b.shape[-2:]
     assert kpad == nw * k0, (kpad, nw, k0)
     assert npad % tn == 0
     nt = npad // tn
+    g_sz = vals.shape[0] if batched else None
     if batched:
-        g_sz = vals.shape[0]
         assert q.shape == (g_sz, mb, nw)
         assert b.shape == (g_sz, kpad, npad)
         assert c_in.shape == (g_sz, mb * tm, npad)
     else:
         assert c_in.shape == (mb * tm, npad)
-
-    a_f = jnp.asarray(alpha, jnp.float32)
-    b_f = jnp.asarray(beta, jnp.float32)
-    ab_vec = batched and (a_f.ndim > 0 or b_f.ndim > 0)
-    if ab_vec:
-        # Per-member epilogue: ab is (G, 2) and each grid group reads its
-        # own SMEM row.  Scalars broadcast, so mixed scalar/vector works.
-        ab = jnp.stack([jnp.broadcast_to(a_f, (g_sz,)),
-                        jnp.broadcast_to(b_f, (g_sz,))], axis=-1)
-    else:
-        ab = jnp.stack([a_f, b_f]).reshape(1, 2)
+    ab = _epilogue_operand(alpha, beta, g_sz)
+    # the whole (rows, 2) table sits in SMEM (a trivial window: Mosaic
+    # blocks SMEM operands only whole)
+    ab_spec = pl.BlockSpec(ab.shape, lambda *_: (0, 0),
+                           memory_space=pltpu.SMEM)
 
     kern = functools.partial(
-        _kernel,
-        tm=tm, k0=k0, chunk=chunk, nw=nw, gather=gather, batched=batched,
-        accumulate=accumulate,
+        _kernel, tm=tm, mb=mb, nw=nw, tile_rows=min(8, r), gather=gather,
+        batched=batched, accumulate=accumulate,
     )
     out_dtype = jnp.float32 if accumulate else b.dtype
     if batched:
         grid = (g_sz, mb, nt, nw)
-        in_specs = [
-            pl.BlockSpec((1, 1, 1, lw), lambda g, m, n, w, q_: (g, m, w, 0)),
-            pl.BlockSpec((1, 1, 1, lw), lambda g, m, n, w, q_: (g, m, w, 0)),
-            pl.BlockSpec((1, 1, 1, lw), lambda g, m, n, w, q_: (g, m, w, 0)),
+        in_specs = _slab_specs(True, r, lanes) + [
             pl.BlockSpec((1, k0, tn), lambda g, m, n, w, q_: (g, w, n)),
             pl.BlockSpec((1, tm, tn), lambda g, m, n, w, q_: (g, m, n)),
-            (pl.BlockSpec((1, 2), lambda g, m, n, w, q_: (g, 0),
-                          memory_space=pltpu.SMEM) if ab_vec else
-             pl.BlockSpec((1, 2), lambda g, m, n, w, q_: (0, 0),
-                          memory_space=pltpu.SMEM)),
+            ab_spec,
         ]
         out_specs = pl.BlockSpec((1, tm, tn), lambda g, m, n, w, q_: (g, m, n))
         out_shape = jax.ShapeDtypeStruct((g_sz, mb * tm, npad), out_dtype)
         semantics = ("parallel", "parallel", "parallel", "arbitrary")
     else:
         grid = (mb, nt, nw)
-        in_specs = [
-            pl.BlockSpec((1, 1, lw), lambda m, n, w, q_: (m, w, 0)),
-            pl.BlockSpec((1, 1, lw), lambda m, n, w, q_: (m, w, 0)),
-            pl.BlockSpec((1, 1, lw), lambda m, n, w, q_: (m, w, 0)),
+        in_specs = _slab_specs(False, r, lanes) + [
             pl.BlockSpec((k0, tn), lambda m, n, w, q_: (w, n)),
             pl.BlockSpec((tm, tn), lambda m, n, w, q_: (m, n)),
-            pl.BlockSpec((1, 2), lambda m, n, w, q_: (0, 0),
-                         memory_space=pltpu.SMEM),
+            ab_spec,
         ]
         out_specs = pl.BlockSpec((tm, tn), lambda m, n, w, q_: (m, n))
         out_shape = jax.ShapeDtypeStruct((mb * tm, npad), out_dtype)
         semantics = ("parallel", "parallel", "arbitrary")
+    # q goes to SMEM flat: a 2-D SMEM array pads its minor dim to 128
+    # words, which would cap MB*NW far below the 1 MiB of SMEM
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
@@ -280,7 +315,8 @@ def sextans_spmm_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics,
         ),
-    )(q, vals, cols, rows, b, c_in, ab)
+        name="sextans_spmm",
+    )(q.reshape(-1), vals, cols, rows, b, c_in, ab)

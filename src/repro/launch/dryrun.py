@@ -1,11 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST run before any jax import (jax locks the device
-count at first init); they are intentionally before the module docstring
-consumers and all other imports.
+The lines above MUST run before any jax import (jax locks the device
+count and platform at first init); they are intentionally before the
+module docstring consumers and all other imports.  The dry-run is a
+CPU-only tool: it never takes an accelerator, so it can run beside a
+process that holds the chip.
 
 Usage:
   python -m repro.launch.dryrun --arch llama3.2-1b --shape train_4k --mesh single
@@ -116,8 +119,6 @@ def run_cell(arch: str, shape: str, mesh_kind: str, extra: dict | None = None) -
     t_compile = time.time() - t0 - t_lower
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):                 # older jax: per-device list
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     hlo = compiled.as_text()
     # trip-count-aware reconstruction (cost_analysis counts loop bodies once)

@@ -14,12 +14,8 @@ __all__ = ["make_production_mesh", "make_mesh_for"]
 
 
 def _axis_types_kwargs(n: int) -> dict:
-    """jax.sharding.AxisType appeared after 0.4.x; omit on older jax (the
-    default there is the equivalent Auto behavior)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+    """Auto (compiler-propagated) sharding on every mesh axis."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

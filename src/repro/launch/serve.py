@@ -161,7 +161,7 @@ class _FlushCounters:
     # engine-stat deltas attributed to this flush (autotuning + plan cache;
     # see EngineStats): dispatches that ran a DB-tuned plan, TuningDB
     # lookups resolved while building this flush's plans, and the cold
-    # (compiled) vs warm (cache/persisted-exec) plan-build wall split.
+    # (compiled) vs warm (plan-cache) plan-build wall split.
     tuned: int = 0
     db_hits: int = 0
     db_misses: int = 0
@@ -1173,9 +1173,9 @@ def serve_spmm_requests(
     stats then report ``tuned_dispatches``, TuningDB traffic
     (``tune_db_hits`` / ``tune_db_misses``), the plan cache
     (``plan_cache_hits`` / ``plan_cache_misses`` / ``plan_cache_evictions``)
-    and the cold-vs-warm plan-build wall split — a warm process (DB +
-    persisted executables populated) shows ``plan_build_warm_s`` in place
-    of the cold trace/compile/measure time.
+    and the cold-vs-warm plan-build wall split — a warm pool (DB and plan
+    cache populated) shows ``plan_build_warm_s`` in place of the cold
+    trace/compile/measure time.
 
     ``policy`` enables the scheduler's cost-model grouping (near-miss
     bucket merging + epilogue folding; see

@@ -21,11 +21,8 @@ The paper's HFlex property makes execution geometry a *runtime* parameter
   discipline), keyed by (platform, dtype, bucketed geometry, padded N,
   group size) — matrix *contents* never enter the key, exactly like the
   executable cache;
-* **persisted executables**: where the JAX version supports
-  ``jax.experimental.serialize_executable``, compiled plan executables are
-  serialized to ``$SEXTANS_TUNE_DIR/execs/`` keyed by the existing
-  ``exec_key``, so a *second process* reaches first-dispatch without
-  re-tracing (the serving cold-start kill; see ``plan._aot_compile``).
+* compiled executables are *not* stored here: JAX's own persistent
+  compilation cache (:mod:`repro.compile_cache`) serves a second process.
 
 Modes (``plan(..., autotune=)`` / ``$SEXTANS_AUTOTUNE``):
 
@@ -33,21 +30,14 @@ Modes (``plan(..., autotune=)`` / ``$SEXTANS_AUTOTUNE``):
 * ``"cached"``  — apply a stored tuning decision when one exists; never
   measure.  Safe for latency-sensitive serving.
 * ``"measure"`` — on a DB miss, enumerate + measure + store, then apply.
-
-Security note: the executable store deserializes pickled XLA payloads
-from ``$SEXTANS_TUNE_DIR`` — point it only at directories you trust as
-much as the code itself (it is a *cache* directory, not an exchange
-format).
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import hashlib
 import json
 import os
-import pickle
 import tempfile
 import threading
 import time
@@ -78,8 +68,6 @@ __all__ = [
     "tune_plan",
     "tune_skinny_threshold",
     "apply_skinny_from_db",
-    "load_exec",
-    "save_exec",
 ]
 
 #: Bump when the record layout (or anything that invalidates stored
@@ -534,11 +522,17 @@ def tune_plan(a: SparseTensor, n: int, *, dtype=jnp.float32,
     measured: List[Dict[str, Any]] = []
     default_us: Optional[float] = None
     for cand in top:
-        try:
-            pl = default_pl if cand == default_cand else _build(cand)
-            y = np.asarray(jax.block_until_ready(pl.run(b, c, alpha, beta)))
-        except Exception:
-            continue                        # unsupported combo: skip, not fatal
+        if cand == default_cand:
+            # the default already ran above (y_ref): a failure there is
+            # the caller's own path failing and is raised, never skipped
+            pl, y = default_pl, y_ref
+        else:
+            try:
+                pl = _build(cand)
+                y = np.asarray(jax.block_until_ready(
+                    pl.run(b, c, alpha, beta)))
+            except Exception:
+                continue                    # combo this platform refuses
         if not np.array_equal(y, y_ref):
             _bump("rejected")               # bit-identity guard: reject
             continue
@@ -702,63 +696,3 @@ def apply_skinny_from_db(db: Optional[TuningDB] = None) -> Optional[int]:
     value = int(rec["skinny_n_max"])
     _bk.set_skinny_n_max(value)
     return value
-
-
-# ---------------------------------------------------------------------------
-# persisted executables (the cold-start kill)
-# ---------------------------------------------------------------------------
-
-_EXEC_SUBDIR = "execs"
-
-
-def _exec_path(key: Any) -> Optional[str]:
-    d = tune_dir()
-    if d is None:
-        return None
-    tag = f"{jax.__version__}|{jax.default_backend()}|{key!r}"
-    h = hashlib.sha256(tag.encode()).hexdigest()[:32]
-    return os.path.join(d, _EXEC_SUBDIR, h + ".jaxexec")
-
-
-def load_exec(key: Any) -> Optional[Any]:
-    """Deserialize a persisted AOT executable for an ``exec_key`` (None on
-    any miss or failure — the caller recompiles).  Keyed by exec_key repr
-    + jax version + platform, so stale builds can never load."""
-    path = _exec_path(key)
-    if path is None or not os.path.exists(path):
-        return None
-    try:
-        from jax.experimental import serialize_executable as _se
-
-        with open(path, "rb") as fh:
-            payload, in_tree, out_tree = pickle.load(fh)
-        return _se.deserialize_and_load(payload, in_tree, out_tree)
-    except Exception:
-        return None                         # corrupt/incompatible: recompile
-
-
-def save_exec(key: Any, compiled: Any) -> bool:
-    """Persist a compiled executable for cross-process reuse (best-effort:
-    returns False when unsupported — e.g. interpret-mode callbacks — or
-    when no ``$SEXTANS_TUNE_DIR`` is set)."""
-    path = _exec_path(key)
-    if path is None:
-        return False
-    try:
-        from jax.experimental import serialize_executable as _se
-
-        blob = pickle.dumps(_se.serialize(compiled))
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path),
-                                   prefix=".exec-", suffix=".tmp")
-        try:
-            with os.fdopen(fd, "wb") as fh:
-                fh.write(blob)
-            os.replace(tmp, path)
-        except BaseException:
-            with contextlib.suppress(OSError):
-                os.remove(tmp)
-            raise
-        return True
-    except Exception:
-        return False

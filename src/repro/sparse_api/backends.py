@@ -7,14 +7,17 @@ Backends declare which :class:`Format` s they support and are registered by
 name:
 
 * ``pallas``        — Sextans streaming kernel (HFLEX) / BSR tile kernel,
-                      vector row-gather.
-* ``pallas_onehot`` — Sextans kernel with pure-MXU one-hot gather
-                      (guaranteed-lowerable on any MXU; HFLEX only).
+                      vector row-gather (HFLEX: interpret mode only —
+                      Mosaic refuses the gather).
+* ``pallas_onehot`` — Sextans kernel with pure-MXU one-hot gather (the
+                      HFLEX kernel that lowers on TPU; HFLEX only).
 * ``jnp``           — segment-sum / einsum XLA path; also the CPU
                       production path and the autodiff reference.
-* ``spmv``          — skinny-N (N ≤ ``SKINNY_N_MAX``) vector lane: Pallas
-                      kernel with no NT grid dimension, the vector stripe
-                      resident per PE pass (Serpens-style; HFLEX only).
+* ``spmv``          — skinny-N (N ≤ ``SKINNY_N_MAX``) vector lane: the
+                      Sextans kernel with the dense operand padded to a
+                      few lanes instead of TN = 128, so the NT grid axis
+                      has one tile and each B window streams once
+                      (Serpens-style; HFLEX only; one-hot gather).
 * ``spmv_jnp``      — flat-jnp twin of the skinny lane (bit-identical to
                       ``jnp``; the off-TPU production path for SpMV shapes).
 * ``auto``          — resolves to one of the above from platform, format,
@@ -41,7 +44,6 @@ from repro.core.partition import cdiv
 from repro.kernels.bsr_spmm import bsr_matmul_pallas, bsr_matmul_pallas_batched
 from repro.kernels.ref import bsr_matmul_ref, bsr_matmul_ref_batched
 from repro.kernels.sextans_spmm import sextans_spmm_pallas
-from repro.kernels.spmv_vector import sextans_spmv_pallas
 
 from .tensor import Format, SparseTensor
 
@@ -255,7 +257,8 @@ def _default_auto_policy(a: SparseTensor, b, platform: Optional[str] = None) -> 
       below);
     * off-TPU the Pallas kernels run in interpret mode — the XLA ``jnp``
       path is the production one;
-    * on TPU, BSR always goes to the tile kernel;
+    * on TPU, BSR always goes to the tile kernel and HFLEX to the one-hot
+      kernel (the vector gather of ``pallas`` does not lower);
     * dense-ish unstructured matrices (density > 0.25) blow up slab padding,
       so they fall back to the XLA path too.
     """
@@ -270,7 +273,7 @@ def _default_auto_policy(a: SparseTensor, b, platform: Optional[str] = None) -> 
         return "pallas"
     if a.density > 0.25:
         return "jnp"
-    return "pallas"
+    return "pallas_onehot"
 
 
 _AUTO_POLICY = _default_auto_policy
@@ -345,9 +348,9 @@ def _hflex_global_ids(d, xp=jnp):
     Batched payloads (leading group axis) broadcast through: the returned
     ids are ``(G, MB*NW*LW)`` — each member carries its own structure.
     """
-    mb, nw = d.vals.shape[-3], d.vals.shape[-2]
-    rows = xp.asarray(d.rows)
-    cols = xp.asarray(d.cols)
+    mb, nw = d.mb, d.nw
+    rows = d.flat_slabs(xp.asarray(d.rows))
+    cols = d.flat_slabs(xp.asarray(d.cols))
     # (MB, 1, 1)/(1, NW, 1) broadcast against the *trailing* slab axes, so
     # the same expressions serve 3-D and group-stacked 4-D payloads.
     bi = xp.arange(mb, dtype=xp.int32).reshape(mb, 1, 1)
@@ -404,7 +407,7 @@ def _hflex_jnp(a: SparseTensor, b, c, alpha, beta):
     vmapped call."""
     d = a.data
     rows_g, cols_g = _hflex_global_ids(d)
-    lead = d.vals.shape[:-3]
+    lead = d.vals.shape[:-4]
     return _hflex_flat_exec(d.vals.reshape(*lead, -1), cols_g, rows_g,
                             b, c, alpha, beta, d.m)
 
@@ -421,8 +424,7 @@ def _hflex_pallas(a: SparseTensor, b, c, alpha, beta, *, gather, tn, interpret):
         cp = _permute_rows_fwd(cp, mb, tm)
     out = sextans_spmm_pallas(
         d.vals, d.cols, d.rows, d.q, bp, cp, alpha, beta,
-        tm=tm, k0=k0, chunk=d.chunk, tn=tn, gather=gather,
-        interpret=interpret,
+        tm=tm, k0=k0, tn=tn, gather=gather, interpret=interpret,
     )
     if d.interleaved:
         out = _permute_rows_inv(out, mb, tm)
@@ -430,26 +432,13 @@ def _hflex_pallas(a: SparseTensor, b, c, alpha, beta, *, gather, tn, interpret):
 
 
 def _hflex_spmv(a: SparseTensor, b, c, alpha, beta, *, gather, nv, interpret):
-    """Skinny-N vector lane: pad the dense operands to ``nvp`` columns (a
-    small multiple of ``nv``, NOT the tall-N TN=128) and launch the
-    NT-less kernel — each B window streamed once, vector stripe resident."""
-    d = a.data
-    m, k, tm, k0, mb, nw = d.m, d.k, d.tm, d.k0, d.mb, d.nw
-    n = b.shape[-1]
-    nvp = cdiv(n, nv) * nv
-    lead_pad = ((0, 0),) if d.batch is not None else ()
-    bp = jnp.pad(b, (*lead_pad, (0, nw * k0 - k), (0, nvp - n)))
-    cp = jnp.pad(c, (*lead_pad, (0, mb * tm - m), (0, nvp - n)))
-    if d.interleaved:
-        cp = _permute_rows_fwd(cp, mb, tm)
-    out = sextans_spmv_pallas(
-        d.vals, d.cols, d.rows, d.q, bp, cp, alpha, beta,
-        tm=tm, k0=k0, chunk=d.chunk, nv=nvp, gather=gather,
-        interpret=interpret,
-    )
-    if d.interleaved:
-        out = _permute_rows_inv(out, mb, tm)
-    return out[..., :m, :n]
+    """Skinny-N vector lane: the tall-N kernel with the dense operands
+    padded to ``nvp`` columns (a small multiple of ``nv``, NOT TN=128) and
+    one column tile — each B window streamed once, vector stripe
+    resident."""
+    nvp = cdiv(b.shape[-1], nv) * nv
+    return _hflex_pallas(a, b, c, alpha, beta, gather=gather, tn=nvp,
+                         interpret=interpret)
 
 
 # -- out-of-core streaming hooks (K0-window chunk accumulation) -------------
@@ -503,7 +492,7 @@ def _hflex_pallas_stream_step(a_chunk: SparseTensor, b_chunk, acc, *,
     bp = jnp.pad(b_chunk, ((0, d.nw * d.k0 - kc), (0, npad - nc)))
     return sextans_spmm_pallas(
         d.vals, d.cols, d.rows, d.q, bp, acc,
-        tm=d.tm, k0=d.k0, chunk=d.chunk, tn=tn, gather=gather,
+        tm=d.tm, k0=d.k0, tn=tn, gather=gather,
         interpret=interpret, accumulate=True,
     )
 
@@ -516,33 +505,16 @@ def _hflex_pallas_stream_collect(a: SparseTensor, acc, n: int, **_unused):
 
 
 def _hflex_spmv_stream_init(a: SparseTensor, n: int, *, nv=8, **_unused):
-    d = a.data
-    nvp = cdiv(n, nv) * nv
-    return jnp.zeros((d.mb * d.tm, nvp), jnp.float32)
+    return _hflex_pallas_stream_init(a, n, tn=cdiv(n, nv) * nv)
 
 
 def _hflex_spmv_stream_step(a_chunk: SparseTensor, b_chunk, acc, *,
-                            gather="gather", nv=8, interpret=None,
-                            **_unused):
+                            gather="onehot", interpret=None, **_unused):
     """Accumulate-mode launch of the skinny lane over the chunk's NW grid —
-    the SpMV twin of :func:`_hflex_pallas_stream_step` (same carried-acc
-    discipline, vector-width padding instead of TN)."""
-    d = a_chunk.data
-    nvp = acc.shape[-1]
-    kc, nc = b_chunk.shape
-    bp = jnp.pad(b_chunk, ((0, d.nw * d.k0 - kc), (0, nvp - nc)))
-    return sextans_spmv_pallas(
-        d.vals, d.cols, d.rows, d.q, bp, acc,
-        tm=d.tm, k0=d.k0, chunk=d.chunk, nv=nvp, gather=gather,
-        interpret=interpret, accumulate=True,
-    )
-
-
-def _hflex_spmv_stream_collect(a: SparseTensor, acc, n: int, **_unused):
-    d = a.data
-    if d.interleaved:
-        acc = _permute_rows_inv(acc, d.mb, d.tm)
-    return acc[..., :a.shape[0], :n]
+    :func:`_hflex_pallas_stream_step` with one column tile as wide as the
+    carried accumulator."""
+    return _hflex_pallas_stream_step(a_chunk, b_chunk, acc, gather=gather,
+                                     tn=acc.shape[-1], interpret=interpret)
 
 
 _JNP_STREAM = StreamOps(init=_hflex_jnp_stream_init,
@@ -553,7 +525,7 @@ _PALLAS_STREAM = StreamOps(init=_hflex_pallas_stream_init,
                            collect=_hflex_pallas_stream_collect)
 _SPMV_STREAM = StreamOps(init=_hflex_spmv_stream_init,
                          step=_hflex_spmv_stream_step,
-                         collect=_hflex_spmv_stream_collect)
+                         collect=_hflex_pallas_stream_collect)
 
 
 def _bsr_raw_jnp(a: SparseTensor, b):
@@ -639,7 +611,7 @@ def _backend_pallas_onehot(a, b, c, alpha, beta, *, tn=128, interpret=None,
                          interpret=interpret)
 
 
-def _backend_spmv(a, b, c, alpha, beta, *, gather="gather", nv=8,
+def _backend_spmv(a, b, c, alpha, beta, *, gather="onehot", nv=8,
                   interpret=None, **_unused):
     bump_trace()
     return _hflex_spmv(a, b, c, alpha, beta, gather=gather, nv=nv,
@@ -658,7 +630,8 @@ def _backend_spmv_jnp(a, b, c, alpha, beta, **_unused):
 register_backend(
     "pallas", _backend_pallas,
     formats=(Format.HFLEX, Format.BSR),
-    description="Sextans streaming kernel / BSR tile kernel (row-gather)",
+    description="Sextans streaming kernel / BSR tile kernel (row-gather; "
+                "HFLEX interprets only)",
     stream=_PALLAS_STREAM)
 register_backend(
     "pallas_onehot", _backend_pallas_onehot,
@@ -676,8 +649,8 @@ register_backend(
 register_backend(
     "spmv", _backend_spmv,
     formats=(Format.HFLEX,),
-    description="skinny-N vector lane: NT-less Pallas kernel, vector "
-                "stripe resident per PE pass",
+    description="skinny-N vector lane: Sextans kernel at a few lanes, "
+                "vector stripe resident per PE pass",
     stream=_SPMV_STREAM)
 register_backend(
     "spmv_jnp", _backend_spmv_jnp,

@@ -91,11 +91,7 @@ def _spmm_bwd(name, okey, res, g):
         # != 0 for them.  Mask by the true counts carried in the packing
         # (per-member counts for a batched tensor — nse carries the group
         # axis, so the mask is per-member too).
-        d = a.data
-        valid = (jax.lax.broadcasted_iota(jnp.int32, d.vals.shape,
-                                          d.vals.ndim - 1)
-                 < d.nse[..., None])
-        dvals = jnp.where(valid, dvals, 0)
+        dvals = jnp.where(a.data.valid_slots(), dvals, 0)
     elif a.batch is not None:
         # Stacked BSR: the padded block slots (position >= the true member
         # count indptr[g, -1]) alias real (brow=0, bcol-dropped) positions
@@ -223,13 +219,9 @@ def _stream_bwd(name, okey, wchunk, ntile, res, g):
 
             _, vjp = jax.vjp(raw_fn, a_w.values, b_w)
             dv, db_w = vjp(ct)
-            d_w = a_w.data
-            valid = (jax.lax.broadcasted_iota(jnp.int32, d_w.vals.shape,
-                                              d_w.vals.ndim - 1)
-                     < d_w.nse[..., None])
-            dvals_chunks.append(jnp.where(valid, dv, 0))
+            dvals_chunks.append(jnp.where(a_w.data.valid_slots(), dv, 0))
             db_chunks.append(db_w)
-        dv_t = jnp.concatenate(dvals_chunks, axis=-2)
+        dv_t = jnp.concatenate(dvals_chunks, axis=-3)
         dvals = dv_t if dvals is None else dvals + dv_t
         db_tiles.append(jnp.concatenate(db_chunks, axis=0))
     db = (db_tiles[0] if len(db_tiles) == 1

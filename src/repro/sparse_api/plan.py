@@ -39,7 +39,11 @@ dispatch.  ``values=`` substitution stays per-group (shape
 is AOT-compiled with the engine's multi-chip shardings (A row-blocks over
 ``data``, B column-tiles over ``model`` — see
 ``SextansEngine.shard_specs``), so the sharded multi-chip path and the
-batched serving path run through one plan abstraction.
+batched serving path run through one plan abstraction.  On the Pallas
+HFLEX backends XLA cannot partition the kernel call, so the plan pads the
+row blocks to a multiple of the ``data`` axis, places each chip's share
+of the slabs on that chip, and runs the kernel per chip under
+``shard_map``: no chip ever holds another chip's slabs.
 
 **Streaming plans** (:class:`StreamingPlan`, selected by
 ``plan(..., device_bytes=)`` or forced with ``stream=True``) are the
@@ -78,6 +82,8 @@ import numpy as np
 
 from repro.core.hflex import bucket_geometry
 from repro.core.partition import cdiv
+from repro.kernels._compat import resolve_interpret
+from repro.kernels.sextans_spmm import sextans_spmm_pallas
 
 from . import backends as _bk
 from .tensor import Format, PackedSpMM, SparseTensor, stack_bsr, stack_hflex
@@ -90,15 +96,17 @@ __all__ = ["SpmmPlan", "StreamingPlan", "plan", "plan_group",
 # batched scheduler's amortization target: dispatches << requests).
 # ``window_dispatches`` counts the streaming tier's per-chunk dispatches
 # separately (they are deliberate pipeline steps, not missed batching).
-# ``exec_persist_hits``/``exec_persist_stores`` count executables loaded
-# from / saved to the $SEXTANS_TUNE_DIR cross-process store (a persist hit
-# also counts as an exec_hit: the trace+compile was avoided either way).
+# A second process reuses compiled code through JAX's persistent
+# compilation cache (repro.compile_cache), not through this table.
 PLAN_STATS: Dict[str, int] = {"exec_hits": 0, "exec_misses": 0,
-                              "dispatches": 0, "window_dispatches": 0,
-                              "exec_persist_hits": 0,
-                              "exec_persist_stores": 0}
+                              "dispatches": 0, "window_dispatches": 0}
 
 _EXEC_CACHE: Dict[Tuple, Any] = {}
+
+#: Pallas HFLEX backends a mesh plan runs row-split under shard_map, with
+#: the gather strategy each uses.
+_ROW_SPLIT = {"pallas": "gather", "pallas_onehot": "onehot",
+              "spmv": "onehot"}
 
 # Plans are built both by the owning thread and the async dispatch thread
 # (PackExecutePipeline serializes *dispatch*, but a sync engine call can
@@ -117,64 +125,25 @@ def clear_plan_cache() -> None:
 
 def _aot_compile(key: Tuple, fn, arg_shapes, in_shardings=None,
                  out_shardings=None, donate_argnums=None):
-    """Lower + compile ``fn`` for ``arg_shapes`` once per cache key.
-
-    With ``$SEXTANS_TUNE_DIR`` set, misses first try the cross-process
-    executable store (``autotune.load_exec`` — serialized by an earlier
-    process under the same exec key, jax version and platform) before
-    paying the trace+compile, and freshly compiled executables are
-    persisted back (best-effort).  Mesh-sharded executables are excluded:
-    shardings bind to the live device topology.
-    """
+    """Lower + compile ``fn`` for ``arg_shapes`` once per cache key (a
+    miss still reads JAX's persistent compilation cache, where enabled)."""
     with _EXEC_LOCK:
         hit = _EXEC_CACHE.get(key)
         if hit is not None:
             PLAN_STATS["exec_hits"] += 1
             return hit
-        if in_shardings is None:
-            loaded = _persisted_exec_load(key)
-            if loaded is not None:
-                _EXEC_CACHE[key] = loaded
-                PLAN_STATS["exec_hits"] += 1
-                PLAN_STATS["exec_persist_hits"] += 1
-                return loaded
         PLAN_STATS["exec_misses"] += 1
-        compiled = _aot_compile_locked(key, fn, arg_shapes, in_shardings,
-                                       out_shardings, donate_argnums)
-        if in_shardings is None and _persisted_exec_save(key, compiled):
-            PLAN_STATS["exec_persist_stores"] += 1
+        kw = {}
+        if donate_argnums is not None:
+            kw["donate_argnums"] = donate_argnums
+        if in_shardings is None:
+            jfn = jax.jit(fn, **kw)
+        else:
+            jfn = jax.jit(fn, in_shardings=in_shardings,
+                          out_shardings=out_shardings, **kw)
+        compiled = jfn.lower(*arg_shapes).compile()
+        _EXEC_CACHE[key] = compiled
         return compiled
-
-
-def _persisted_exec_load(key):
-    from . import autotune as _at
-
-    if _at.tune_dir() is None:
-        return None
-    return _at.load_exec(key)
-
-
-def _persisted_exec_save(key, compiled) -> bool:
-    from . import autotune as _at
-
-    if _at.tune_dir() is None:
-        return False
-    return _at.save_exec(key, compiled)
-
-
-def _aot_compile_locked(key, fn, arg_shapes, in_shardings,
-                        out_shardings, donate_argnums):
-    kw = {}
-    if donate_argnums is not None:
-        kw["donate_argnums"] = donate_argnums
-    if in_shardings is None:
-        jfn = jax.jit(fn, **kw)
-    else:
-        jfn = jax.jit(fn, in_shardings=in_shardings,
-                      out_shardings=out_shardings, **kw)
-    compiled = jfn.lower(*arg_shapes).compile()
-    _EXEC_CACHE[key] = compiled
-    return compiled
 
 
 def device_memory_budget() -> Optional[int]:
@@ -270,6 +239,16 @@ class SpmmPlan:
         self.opts = dict(opts)
         self.dtype = jnp.dtype(dtype)
         okey = tuple(sorted(self.opts.items()))
+        if (a.format is Format.BSR and self.backend == "pallas"
+                and not resolve_interpret(self.opts.get("interpret"))):
+            d = a.data
+            if d.tk % 128 or d.tf % 128:
+                raise ValueError(
+                    f"BSR blocks of {d.tk}x{d.tf} cannot run on the TPU "
+                    f"kernel: its x and output tiles are lane tiles, so "
+                    f"both block sides must be multiples of 128 — repack "
+                    f"with block=(128, 128) (or a multiple), or plan with "
+                    f"backend='jnp'")
 
         m, k, n = self.m, self.k, self.n
         g = self.group
@@ -282,6 +261,8 @@ class SpmmPlan:
         flat = (a.format is Format.HFLEX and self.backend == "jnp"
                 and mesh is None and g is None)
         self._flat = flat
+        row_split = (mesh is not None and a.format is Format.HFLEX
+                     and self.backend in _ROW_SPLIT)
         if a.format is Format.HFLEX:
             d = a.data
             bucket = bucket_geometry(d.mb, d.nw, d.lw, n)
@@ -289,9 +270,8 @@ class SpmmPlan:
             d = a.data
             bucket = (d.nb, d.k, d.f, d.tk, d.tf)
         # Group plans compile a (G,) per-member epilogue signature (see
-        # _ab_operands) — the "abvec" marker keeps them from colliding with
-        # scalar-signature executables persisted under $SEXTANS_TUNE_DIR by
-        # older builds.
+        # _ab_operands) — the "abvec" marker keeps their keys apart from
+        # scalar-signature executables.
         self.exec_key = ("flat" if flat else "payload", self.backend, okey,
                          a.format, a.geometry, bucket, (m, k, n), g,
                          str(self.dtype), mesh) + (
@@ -305,7 +285,7 @@ class SpmmPlan:
             # Group plans carry the leading G axis straight through (the
             # body vmaps over it — still one compiled-call dispatch).
             rows_g, cols_g = _bk._hflex_global_ids(d, xp=np)
-            lead = d.vals.shape[:-3]
+            lead = d.vals.shape[:-4]
             self._operands = (
                 jnp.asarray(d.vals).reshape(*lead, -1),
                 jnp.asarray(cols_g),
@@ -319,7 +299,7 @@ class SpmmPlan:
                                             alpha, beta, m)
 
             self._traced = traced
-        else:
+        elif not row_split:
             # Generic payload plan: pass every leaf of the packed format as
             # an operand (so bucket-mates share the executable) and rebuild
             # the tensor inside the trace.  Host-resident leaves (numpy,
@@ -345,6 +325,14 @@ class SpmmPlan:
 
             self._traced = traced
 
+        self._place_values = None
+        if row_split:
+            in_sh, out_sh = self._init_row_split(mesh)
+        elif mesh is not None:
+            in_sh, out_sh = self._mesh_shardings(mesh)
+        else:
+            in_sh = out_sh = None
+
         self._bshape = (k, n) if g is None else (g, k, n)
         self._cshape = (m, n) if g is None else (g, m, n)
         b_s = jax.ShapeDtypeStruct(self._bshape, self.dtype)
@@ -353,9 +341,6 @@ class SpmmPlan:
         arg_shapes = tuple(
             jax.ShapeDtypeStruct(x.shape, x.dtype) for x in self._operands
         ) + (b_s, c_s, s_s, s_s)
-        in_sh = out_sh = None
-        if mesh is not None:
-            in_sh, out_sh = self._mesh_shardings(mesh)
         self._compiled = _aot_compile(self.exec_key, self._traced, arg_shapes,
                                       in_shardings=in_sh,
                                       out_shardings=out_sh)
@@ -363,6 +348,33 @@ class SpmmPlan:
         # Epilogue scalars are runtime operands; cache their device buffers
         # per value so the hot loop never re-commits host scalars.
         self._ab_cache: Dict[Tuple[float, float], Tuple[Any, Any]] = {}
+
+    def _init_row_split(self, mesh):
+        """Row-split mesh plan of a Pallas HFLEX backend (see
+        :func:`row_split_spmm`): places each chip's row blocks of the
+        payload on that chip and returns the operand and result
+        shardings."""
+        from jax.sharding import NamedSharding
+
+        d = self.a.data
+        traced, mbp, slab_spec, q_spec = row_split_spmm(
+            d, mesh, self.m, self.k, self.n, self.group, self.backend,
+            self.opts)
+
+        def place(x, spec, axis):
+            pad = [(0, 0)] * x.ndim
+            pad[axis] = (0, mbp - x.shape[axis])
+            xp = np if isinstance(x, np.ndarray) else jnp
+            return jax.device_put(xp.pad(x, pad), NamedSharding(mesh, spec))
+
+        self._place_values = lambda v: place(v, slab_spec, v.ndim - 4)
+        self._operands = tuple(place(x, slab_spec, x.ndim - 4)
+                               for x in (d.vals, d.cols, d.rows)) + (
+            place(np.asarray(d.q), q_spec, d.q.ndim - 2),)
+        self._values_slot = 0
+        self._traced = traced
+        rep = NamedSharding(mesh, jax.sharding.PartitionSpec())
+        return tuple(x.sharding for x in self._operands) + (rep,) * 4, rep
 
     def _mesh_shardings(self, mesh):
         """Operand/result NamedShardings for a mesh plan: the engine's
@@ -439,8 +451,10 @@ class SpmmPlan:
         if values is not None:
             values = jnp.asarray(values)
             if self._flat:                     # flat path stores vals flat
-                lead = values.shape[:-3] if values.ndim >= 3 else ()
+                lead = values.shape[:-4] if values.ndim >= 4 else ()
                 values = values.reshape(*lead, -1)
+            elif self._place_values is not None:   # row-split mesh plan
+                values = self._place_values(values)
             ops = (ops[:self._values_slot] + (values,)
                    + ops[self._values_slot + 1:])
         PLAN_STATS["dispatches"] += 1
@@ -455,6 +469,70 @@ class SpmmPlan:
         return (f"SpmmPlan(shape=({self.m}, {self.k}){gtag}@{self.n}, "
                 f"backend={self.backend!r}, format={self.a.format.value}"
                 f"{mtag})")
+
+
+def row_split_spmm(d: PackedSpMM, mesh, m: int, k: int, n: int,
+                   group: Optional[int], backend: str, opts: Dict[str, Any]):
+    """The traced row-split SpMM of a Pallas HFLEX backend on ``mesh``.
+
+    Each chip runs the kernel on its own row blocks (``shard_map`` over
+    ``data``) against the whole, replicated ``b``; the ``b``/``c``
+    padding and the row permutation stay outside, in the global program.
+    MB is padded to ``mbp``, a multiple of the ``data`` axis, with empty
+    row blocks (``q = 0``: skipped).  ``d`` supplies statics only, so
+    shape structs work.  Returns ``(traced, mbp, slab_spec, q_spec)``;
+    ``traced(vals, cols, rows, q, b, c, alpha, beta)`` takes slabs padded
+    to ``mbp`` and returns the replicated ``([G,] M, N)`` result."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    mb, nw = d.vals.shape[-4], d.vals.shape[-3]
+    tm, k0, interleaved = d.tm, d.k0, d.interleaved
+    n_data, n_model = mesh.shape["data"], mesh.shape["model"]
+    mbp = cdiv(mb, n_data) * n_data
+    lead = (None,) if group is not None else ()
+    slab_spec = P(*lead, "data", None, None, None)
+    q_spec = P(*lead, "data", None)
+    gather = ("onehot" if backend == "pallas_onehot"
+              else opts.get("gather", _ROW_SPLIT[backend]))
+    if backend == "spmv":
+        nv = opts.get("nv", 8)
+        tn = cdiv(n, nv) * nv
+    else:
+        tn = opts.get("tn", 128)
+    npad = cdiv(n, tn * n_model) * tn * n_model
+    interpret = opts.get("interpret")
+    lp = ((0, 0),) if group is not None else ()
+    rep = NamedSharding(mesh, P())
+
+    def local(vals, cols, rows, q, b, c, alpha, beta):
+        return sextans_spmm_pallas(vals, cols, rows, q, b, c, alpha, beta,
+                                   tm=tm, k0=k0, tn=tn,
+                                   gather=gather, interpret=interpret)
+
+    split = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(slab_spec,) * 3 + (q_spec, P(*lead, None, "model"),
+                                     P(*lead, "data", "model"), P(), P()),
+        out_specs=P(*lead, "data", "model"), check_vma=False)
+
+    def traced(vals, cols, rows, q, b, c, alpha, beta):
+        _bk.bump_trace()
+        bp = jnp.pad(b, (*lp, (0, nw * k0 - k), (0, npad - n)))
+        cp = jnp.pad(c, (*lp, (0, mb * tm - m), (0, npad - n)))
+        if interleaved:
+            cp = _bk._permute_rows_fwd(cp, mb, tm)
+        cp = jnp.pad(cp, (*lp, (0, (mbp - mb) * tm), (0, 0)))
+        # gather the row shards before un-permuting: the permutation
+        # interleaves rows of every chip's blocks
+        out = jax.sharding.reshard(
+            split(vals, cols, rows, q, bp, cp, alpha, beta), rep)
+        out = out[..., :mb * tm, :]
+        if interleaved:
+            out = _bk._permute_rows_inv(out, mb, tm)
+        return out[..., :m, :n]
+
+    return traced, mbp, slab_spec, q_spec
 
 
 class StreamingPlan:
@@ -602,7 +680,7 @@ class StreamingPlan:
                 f"M",
                 stacklevel=3)
 
-        # ONE window-step executable: bucketed (MB, WCHUNK, LW) chunk shape
+        # ONE window-step executable: bucketed (MB, WCHUNK, R, L) chunk shape
         # shared by every bucket-mate (the HFlex property, kept under
         # streaming) AND by every column tile — the step is tile-position-
         # independent (the tail tile arrives column-padded), so the 2-D
@@ -634,10 +712,11 @@ class StreamingPlan:
         self.exec_key = ("stream-step", self.backend, okey, geom, m, ntile,
                          str(self.dtype))
         sd = jax.ShapeDtypeStruct
+        slab = (d.mb, wc) + d.vals.shape[-2:]
         chunk_shapes = (
-            sd((d.mb, wc, d.lw), jnp.float32),      # vals
-            sd((d.mb, wc, d.lw), jnp.int32),        # cols
-            sd((d.mb, wc, d.lw), jnp.int32),        # rows
+            sd(slab, jnp.float32),                  # vals
+            sd(slab, jnp.int32),                    # cols
+            sd(slab, jnp.int32),                    # rows
             sd((d.mb, wc), jnp.int32),              # q
             sd((kc, ntile), self.dtype),            # b tile chunk
             sd(acc_shape, jnp.float32),             # carried accumulator
@@ -746,7 +825,7 @@ class StreamingPlan:
             # block-major: bi*TM + r >= MB*TM >= M), so the jnp scatter
             # drops them.  Bit-identity is unconditional (the padded
             # windows contribute no adds at all).
-            wpad = ((0, 0), (0, pad), (0, 0))
+            wpad = ((0, 0), (0, pad), (0, 0), (0, 0))
             vals_c = np.pad(vals_c, wpad)
             cols_c = np.pad(cols_c, wpad)
             rows_c = np.pad(rows_c, wpad, constant_values=d.mb * d.tm)

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.hflex import pack_block_slabs
+from repro.core.hflex import lane_major, pack_block_slabs, slab_lw
 from repro.core.partition import cdiv
 from repro.core.sparse import SparseMatrix
 from repro.core.sparse import from_dense as _coo_from_dense
@@ -72,16 +72,18 @@ class Format(enum.Enum):
 class PackedSpMM:
     """Device-resident HFlex-packed sparse matrix (slab format).
 
-    Slab arrays are ``(MB, NW, LW)`` for a single matrix, or carry a
-    *leading group axis* ``(G, MB, NW, LW)`` when ``G`` bucket-mates have
-    been stacked into one dispatch (:func:`stack_hflex`); ``q``/``nse``
-    gain the same leading axis.  All geometry/shape statics are shared by
-    the group members.
+    Slab arrays are ``(MB, NW, R, L)`` for a single matrix: slab ``(b, w)``
+    holds ``LW = R * L`` slots as ``R`` rows of ``L`` lanes
+    (:func:`repro.core.hflex.lane_major`; the flat slot order is the
+    row-major order).  They carry a *leading group axis* ``(G, MB, NW, R,
+    L)`` when ``G`` bucket-mates have been stacked into one dispatch
+    (:func:`stack_hflex`); ``q``/``nse`` gain the same leading axis.  All
+    geometry/shape statics are shared by the group members.
     """
 
-    vals: jax.Array  # ([G,] MB, NW, LW) f32
-    cols: jax.Array  # ([G,] MB, NW, LW) i32
-    rows: jax.Array  # ([G,] MB, NW, LW) i32
+    vals: jax.Array  # ([G,] MB, NW, R, L) f32
+    cols: jax.Array  # ([G,] MB, NW, R, L) i32
+    rows: jax.Array  # ([G,] MB, NW, R, L) i32
     q: jax.Array     # ([G,] MB, NW) i32, chunk-ceiled counts (kernel trips)
     nse: jax.Array   # ([G,] MB, NW) i32, true counts (autodiff padding mask)
     m: int = dataclasses.field(metadata=dict(static=True))
@@ -95,19 +97,31 @@ class PackedSpMM:
     @property
     def batch(self) -> Optional[int]:
         """Group size G for stacked payloads, None for a single matrix."""
-        return self.vals.shape[0] if self.vals.ndim == 4 else None
+        return self.vals.shape[0] if self.vals.ndim == 5 else None
 
     @property
     def mb(self) -> int:
-        return self.vals.shape[-3]
+        return self.vals.shape[-4]
 
     @property
     def nw(self) -> int:
-        return self.vals.shape[-2]
+        return self.vals.shape[-3]
 
     @property
     def lw(self) -> int:
-        return self.vals.shape[-1]
+        return self.vals.shape[-2] * self.vals.shape[-1]
+
+    def flat_slabs(self, x):
+        """A slab array viewed as ``([G,] MB, NW, LW)`` (flat slot axis)."""
+        return x.reshape(*x.shape[:-2], self.lw)
+
+    def valid_slots(self) -> jax.Array:
+        """Mask of the real (non-padding) slots, shaped like ``vals``: slot
+        ``r * L + l`` of slab ``(b, w)`` is real below ``nse[b, w]``."""
+        shape, nd = self.vals.shape, self.vals.ndim
+        pos = (jax.lax.broadcasted_iota(jnp.int32, shape, nd - 2) * shape[-1]
+               + jax.lax.broadcasted_iota(jnp.int32, shape, nd - 1))
+        return pos < jnp.asarray(self.nse)[..., None, None]
 
     @property
     def geometry(self) -> Tuple[int, int, int]:
@@ -185,9 +199,9 @@ def pack_hflex(
         (slabs.vals != 0).sum(-1), slabs.q)
     conv = jnp.asarray if device else np.asarray
     return PackedSpMM(
-        vals=conv(slabs.vals),
-        cols=conv(slabs.cols),
-        rows=conv(slabs.rows),
+        vals=conv(lane_major(slabs.vals)),
+        cols=conv(lane_major(slabs.cols)),
+        rows=conv(lane_major(slabs.rows)),
         q=conv(slabs.q),
         nse=conv(np.asarray(nse, np.int32)),
         m=slabs.m, k=slabs.k, tm=tm, k0=k0, chunk=chunk,
@@ -395,7 +409,7 @@ class SparseTensor:
         """The sub-matrix covering K0-windows ``[w0, w1)`` as a
         self-describing SparseTensor.
 
-        The result holds the ``(MB, w1-w0, LW)`` sub-payload (leading group
+        The result holds the ``(MB, w1-w0, R, L)`` sub-payload (leading group
         axes pass through) with per-window ``q``/``nse`` sliced along, and
         logical shape ``(M, min(K, w1*K0) - w0*K0)`` — i.e. column block
         ``[w0*K0, w1*K0)`` of ``A``, re-based to column 0.  Because slab
@@ -430,9 +444,9 @@ class SparseTensor:
         k_w = min(self.k, w1 * d.k0) - w0 * d.k0
         data_w = dataclasses.replace(
             d,
-            vals=d.vals[..., :, w0:w1, :],
-            cols=d.cols[..., :, w0:w1, :],
-            rows=d.rows[..., :, w0:w1, :],
+            vals=d.vals[..., w0:w1, :, :],
+            cols=d.cols[..., w0:w1, :, :],
+            rows=d.rows[..., w0:w1, :, :],
             q=d.q[..., :, w0:w1],
             nse=nse_w,
             k=k_w,
@@ -465,14 +479,15 @@ class SparseTensor:
         m, k = self.shape
         if self.format is Format.HFLEX:
             d = self.data
-            mb, nw, lw = d.vals.shape
+            mb, nw = d.mb, d.nw
+            rows, cols = d.flat_slabs(d.rows), d.flat_slabs(d.cols)
             bi = jnp.arange(mb, dtype=jnp.int32)[:, None, None]
             wi = jnp.arange(nw, dtype=jnp.int32)[None, :, None]
             if d.interleaved:
-                rows_g = d.rows * mb + bi          # undo block interleave
+                rows_g = rows * mb + bi            # undo block interleave
             else:
-                rows_g = bi * d.tm + d.rows
-            cols_g = wi * d.k0 + d.cols
+                rows_g = bi * d.tm + rows
+            cols_g = wi * d.k0 + cols
             out = jnp.zeros((m, k), jnp.float32)
             # padded slots carry val == 0 -> 'drop' only guards OOB pad rows
             return out.at[rows_g.reshape(-1), cols_g.reshape(-1)].add(
@@ -675,14 +690,17 @@ def repad_lw(t: SparseTensor, lw: int) -> SparseTensor:
         raise ValueError(f"cannot shrink LW: {cur} -> {lw}")
     if lw == cur:
         return t
-    pad = [(0, 0)] * (d.vals.ndim - 1) + [(0, lw - cur)]
+    if slab_lw(lw) != lw:
+        raise ValueError(f"LW={lw} has no lane layout (next valid width: "
+                         f"{slab_lw(lw)})")
+    pad = [(0, 0)] * (d.vals.ndim - 2) + [(0, lw - cur)]
     xp = np if t.on_host else jnp
+
+    def widen(x):
+        return lane_major(xp.pad(d.flat_slabs(x), pad))
+
     data = dataclasses.replace(
-        d,
-        vals=xp.pad(d.vals, pad),
-        cols=xp.pad(d.cols, pad),
-        rows=xp.pad(d.rows, pad),
-    )
+        d, vals=widen(d.vals), cols=widen(d.cols), rows=widen(d.rows))
     from repro.analysis.validate import maybe_validate
 
     return maybe_validate(SparseTensor(data=data, format=Format.HFLEX,

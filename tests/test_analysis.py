@@ -203,8 +203,8 @@ class TestValidator:
         t = _tensor()
         vals = np.asarray(t.data.vals).copy()
         slot = int(np.asarray(t.data.nse)[0, 0])
-        assert slot < vals.shape[-1]
-        vals[0, 0, slot] = 7.0
+        assert slot < t.data.lw
+        t.data.flat_slabs(vals)[0, 0, slot] = 7.0    # a view of vals
         with pytest.raises(InvariantViolation, match="padding slot"):
             validate(_corrupt(t, vals=vals))
 

@@ -12,8 +12,8 @@ Contract under test:
   measures + stores on a miss ("measure"), and every accepted candidate
   is **bit-identical** to the default resolution — the tuner may only
   re-route among result-identical implementations;
-* plan executables persist to disk and a cold plan cache (or a fresh
-  process) reloads them instead of re-tracing;
+* a second process reuses compiled code through JAX's persistent
+  compilation cache, placed by ``repro.compile_cache``;
 * the engine/scheduler surface the story as counters: plan-cache
   hits/misses/evictions, tuned dispatches, TuningDB traffic, cold vs
   warm plan-build seconds.
@@ -199,46 +199,70 @@ class TestTunedPlans:
         assert res.record["us"] <= res.record["default_us"] * 1.5
 
 
-class TestExecPersistence:
-    def test_roundtrip_after_cache_clear(self, tune_dir):
-        """Persisted executables reload after clear_plan_cache(): the
-        second build is a persist hit, not a recompile."""
-        rng = np.random.default_rng(0)
-        A = _packed(seed=23)
-        b = jnp.asarray(rng.standard_normal((A.shape[1], 8)), jnp.float32)
-        sp.clear_plan_cache()                     # force a compile HERE so
-        stores0 = sp.PLAN_STATS["exec_persist_stores"]  # it persists to
-        P = sp.plan(A, 8, backend="jnp")          # THIS test's tune dir
-        y = np.asarray(P.run(b))
-        assert sp.PLAN_STATS["exec_persist_stores"] > stores0
-        sp.clear_plan_cache()
-        hits0 = sp.PLAN_STATS["exec_persist_hits"]
-        P2 = sp.plan(A, 8, backend="jnp")
-        assert sp.PLAN_STATS["exec_persist_hits"] > hits0
-        np.testing.assert_array_equal(np.asarray(P2.run(b)), y)
+class TestCompileCache:
+    """repro.compile_cache: JAX's persistent compilation cache, placed from
+    outside (it replaced the repo's own pickled-executable store)."""
 
-    def test_exec_files_on_disk(self, tune_dir):
-        A = _packed(seed=29)
-        sp.clear_plan_cache()                     # compile under this dir
-        sp.plan(A, 8, backend="jnp")
-        execs = os.path.join(tune_dir, "execs")
-        assert os.path.isdir(execs) and os.listdir(execs)
-
-    def test_save_load_roundtrip_api(self, tune_dir):
+    @pytest.fixture()
+    def restore_config(self):
         import jax
+        from jax.experimental.compilation_cache import compilation_cache as cc
 
-        compiled = jax.jit(lambda x: x * 2).lower(
-            jnp.zeros((4,), jnp.float32)).compile()
-        key = ("unit", "roundtrip")
-        assert at.save_exec(key, compiled)
-        loaded = at.load_exec(key)
-        assert loaded is not None
-        np.testing.assert_array_equal(
-            np.asarray(loaded(jnp.ones((4,), jnp.float32))),
-            np.full((4,), 2.0, np.float32))
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs")
+        saved = {k: getattr(jax.config, k) for k in keys}
+        yield
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
 
-    def test_load_miss_returns_none(self, tune_dir):
-        assert at.load_exec(("never", "stored")) is None
+    def test_env_dir_is_honoured_and_nothing_set(self, tmp_path,
+                                                 monkeypatch,
+                                                 restore_config):
+        import jax
+        from repro import compile_cache
+
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_unset_uses_fixed_checkout_dir(self, monkeypatch,
+                                           restore_config):
+        import jax
+        from repro import compile_cache
+
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+        path = compile_cache.enable()
+        assert path == compile_cache.checkout_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+        root = os.path.dirname(path)
+        assert os.path.basename(path) == ".jax_cache"
+        assert os.path.isfile(os.path.join(root, "pyproject.toml"))
+        assert compile_cache.enable() == path      # the same every call
+
+    def test_recompile_after_clear_caches_is_a_hit(self, tmp_path,
+                                                   restore_config):
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache as cc
+        from repro import compile_cache
+
+        compile_cache.enable()
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        cc.reset_cache()
+
+        def f(x):
+            return jnp.sin(x) * 3.0 + jnp.cos(x[::-1])
+
+        x = jnp.arange(37, dtype=jnp.float32)
+        y0 = np.asarray(jax.jit(f)(x))
+        assert os.listdir(tmp_path), "nothing was written to the cache"
+        hits0 = compile_cache.STATS["hits"]
+        jax.clear_caches()
+        y1 = np.asarray(jax.jit(f)(x))
+        assert compile_cache.STATS["hits"] > hits0
+        np.testing.assert_array_equal(y0, y1)
 
 
 class TestEngineCounters:

@@ -156,8 +156,8 @@ class TestBatchedSpmm:
             lambda v: sp.spmm(s.with_values(v), b, backend="jnp").sum()
         )(s.values)
         d = s.data
-        pad = (jax.lax.broadcasted_iota(jnp.int32, d.vals.shape, 3)
-               >= d.nse[..., None])
+        slot = np.arange(d.lw).reshape(d.vals.shape[-2:])
+        pad = slot >= np.asarray(d.nse)[..., None, None]
         assert bool(jnp.all(jnp.where(pad, dv, 0) == 0))
         assert int(pad.sum()) > 0    # the mask actually covers something
 
@@ -374,7 +374,7 @@ class TestShardedEnginePlan:
         specs = SextansEngine.shard_specs()
         from jax.sharding import PartitionSpec as P
 
-        assert specs["vals"] == P("data", None, None)
+        assert specs["vals"] == P("data", None, None, None)
         assert specs["b"] == P(None, "model")
         assert specs["c"] == P("data", "model")
 
@@ -427,6 +427,38 @@ class TestShardedEnginePlan:
         same = eng.pack(random_sparse(64, 64, 0.05, seed=5))
         assert np.array_equal(np.asarray(fn(same, b, c)),
                               np.asarray(fn(packed, b, c)))
+
+    @pytest.mark.parametrize("backend", ["pallas_onehot", "spmv"])
+    def test_row_split_four_devices_bit_exact(self, rng, backend):
+        """A Pallas mesh plan runs each device's own row blocks (shard_map
+        over ``data``): MB = 15 is padded to 16 with empty blocks, every
+        device holds only its quarter of the slabs, and the result is
+        bit-identical to the one-device plan."""
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 devices")
+        mesh = jax.make_mesh((4, 1), ("data", "model"))
+        eng = SextansEngine(tm=64, k0=128, chunk=8, impl=backend,
+                            interpret=True)
+        a = power_law_sparse(900, 700, 5, seed=7)
+        packed = eng.pack(a, device=False)
+        assert packed.data.mb == 15
+        n = 8 if backend == "spmv" else 16
+        b = jnp.asarray(rng.standard_normal((700, n)), jnp.float32)
+        c = jnp.asarray(rng.standard_normal((900, n)), jnp.float32)
+        fn = eng.sharded_spmm_fn(mesh, packed, n, alpha=1.5, beta=-0.5)
+        vals = fn.plan._operands[0]
+        assert {s.data.shape[0] for s in vals.addressable_shards} == {4}
+        out = np.asarray(fn(None, b, c))
+        ref = eng.plan_for(packed, n).run(b, c, 1.5, -0.5)
+        np.testing.assert_array_equal(out, np.asarray(ref))
+        refm = spmm_reference(a, np.asarray(b), np.asarray(c), 1.5, -0.5)
+        np.testing.assert_allclose(out, refm, rtol=2e-4,
+                                   atol=2e-4 * np.abs(refm).max())
+        # values substitution pads and places the new payload the same way
+        y2 = np.asarray(fn(packed.with_values(packed.values * 2.0), b,
+                           jnp.zeros_like(c)))
+        y1 = np.asarray(fn(packed, b, jnp.zeros_like(c)))
+        np.testing.assert_allclose(y2, 2.0 * y1, rtol=1e-6, atol=1e-5)
 
     def test_group_plan_carries_mesh(self, rng):
         """plan_group(..., mesh=...) — the multi-chip and batched paths

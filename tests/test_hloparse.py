@@ -26,11 +26,7 @@ def test_scan_trip_count_flops():
     r = analyze(c.as_text())
     assert r["flops"] == 7 * 2 * 8 * 64 * 64
     # cost_analysis counts the body once — we must exceed it
-    # (older jax returns a per-device list instead of a flat dict)
-    ca = c.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0]
-    assert r["flops"] > ca["flops"]
+    assert r["flops"] > c.cost_analysis()["flops"]
 
 
 def test_nested_scan():

@@ -192,3 +192,17 @@ class TestInterpretDefault:
         assert resolve_interpret(None) is expected
         assert resolve_interpret(True) is True
         assert resolve_interpret(False) is False
+
+
+class TestBsrLaneTiles:
+    def test_compiled_bsr_kernel_refuses_narrow_blocks_at_plan_time(self):
+        """The TPU BSR kernel tiles x and the output by whole lane tiles:
+        a 16x16-block weight is refused when the plan is built, with the
+        block size in the message, instead of failing in the compiler."""
+        rng = np.random.default_rng(0)
+        B = sp.from_dense(rng.standard_normal((64, 96)).astype(np.float32),
+                          format=sp.Format.BSR, block=(16, 16))
+        with pytest.raises(ValueError, match="16x16.*multiples of 128"):
+            sp.plan(B, 8, backend="pallas", interpret=False)
+        # interpreted, the same weight still runs (CPU development path)
+        sp.plan(B, 8, backend="pallas", interpret=True)

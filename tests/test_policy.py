@@ -21,6 +21,7 @@ from repro.core.perfmodel import packed_event_cycles
 from repro.core.sparse import power_law_sparse, spmm_reference
 from repro.launch.policy import (ABVEC_BACKENDS, FLAT_BACKENDS, GroupSketch,
                                  MergeCluster, MergePolicy, family_key)
+from repro.core.hflex import slab_lw
 from repro.sparse_api import Format, from_sparse_matrix, repad_lw
 
 
@@ -234,8 +235,8 @@ class TestRepadLW:
         a = power_law_sparse(96, 80, 4, seed=3)
         t = from_sparse_matrix(a, tm=32, k0=32, chunk=8, bucket=False)
         lw = t.geometry[2]
-        wide = repad_lw(t, lw * 4)
-        assert wide.geometry[2] == lw * 4
+        wide = repad_lw(t, slab_lw(lw * 4))
+        assert wide.geometry[2] == slab_lw(lw * 4) > lw
         assert wide.nse == t.nse
         np.testing.assert_array_equal(np.asarray(wide.data.q),
                                       np.asarray(t.data.q))
@@ -252,9 +253,10 @@ class TestRepadLW:
         a = power_law_sparse(64, 64, 3, seed=1)
         t = from_sparse_matrix(a, tm=32, k0=32, chunk=8, bucket=False)
         lw = t.geometry[2]
-        wide = repad_lw(t, lw * 2)
-        assert np.all(np.asarray(wide.data.vals)[..., lw:] == 0.0)
-        assert np.all(np.asarray(wide.data.cols)[..., lw:] == 0)
+        wide = repad_lw(t, slab_lw(lw * 2))
+        flat = wide.data.flat_slabs
+        assert np.all(flat(np.asarray(wide.data.vals))[..., lw:] == 0.0)
+        assert np.all(flat(np.asarray(wide.data.cols))[..., lw:] == 0)
 
     def test_noop_and_errors(self):
         a = power_law_sparse(64, 64, 3, seed=1)

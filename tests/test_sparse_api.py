@@ -112,8 +112,8 @@ class TestAutodiff:
         # vals: compare on real slots only — the dense oracle also has
         # partials w.r.t. structural padding slots, which spmm (correctly)
         # pins to zero; that is asserted separately below.
-        lw = A.data.vals.shape[2]
-        valid = np.arange(lw) < np.asarray(A.data.nse)[:, :, None]
+        valid = (np.arange(A.data.lw).reshape(A.data.vals.shape[-2:])
+                 < np.asarray(A.data.nse)[:, :, None, None])
         np.testing.assert_allclose(np.asarray(g[0])[valid],
                                    np.asarray(gd[0])[valid],
                                    rtol=1e-4, atol=1e-4, err_msg="vals")
@@ -134,8 +134,8 @@ class TestAutodiff:
         np.testing.assert_allclose(
             np.asarray(sp.spmm(A2, b, backend="jnp")),
             np.asarray(A2.todense() @ b), rtol=1e-4, atol=1e-4)
-        lw = A.data.vals.shape[2]
-        valid = np.arange(lw) < np.asarray(A.data.nse)[:, :, None]
+        valid = (np.arange(A.data.lw).reshape(A.data.vals.shape[-2:])
+                 < np.asarray(A.data.nse)[:, :, None, None])
         assert np.all(np.asarray(v2)[~valid] == 0.0)
 
     def test_grad_through_bsr(self, rng):
@@ -176,7 +176,7 @@ class TestBackendRegistry:
     def test_auto_policy(self):
         _, A = _tensor()                           # density 0.08
         assert sp.resolve_backend("auto", A, platform="cpu") == "jnp"
-        assert sp.resolve_backend("auto", A, platform="tpu") == "pallas"
+        assert sp.resolve_backend("auto", A, platform="tpu") == "pallas_onehot"
         a_dense, = (random_sparse(32, 32, 0.5, seed=0),)
         D = sp.from_sparse_matrix(a_dense, tm=32, k0=32, bucket=False)
         assert sp.resolve_backend("auto", D, platform="tpu") == "jnp"

@@ -115,8 +115,8 @@ class TestSpmvJnpTwin:
 
         g = jax.grad(loss)(A.values)
         gd = jax.grad(loss_dense)(A.values)
-        lw = A.data.vals.shape[2]
-        valid = np.arange(lw) < np.asarray(A.data.nse)[:, :, None]
+        valid = (np.arange(A.data.lw).reshape(A.data.vals.shape[-2:])
+                 < np.asarray(A.data.nse)[:, :, None, None])
         np.testing.assert_allclose(np.asarray(g)[valid],
                                    np.asarray(gd)[valid],
                                    rtol=1e-4, atol=1e-4)
@@ -143,7 +143,7 @@ class TestAutoPolicyTable:
         ("cpu", 1, "spmv_jnp"),
         ("cpu", sp.SKINNY_N_MAX, "spmv_jnp"),
         # one past the threshold: the old rules verbatim
-        ("tpu", sp.SKINNY_N_MAX + 1, "pallas"),
+        ("tpu", sp.SKINNY_N_MAX + 1, "pallas_onehot"),
         ("cpu", sp.SKINNY_N_MAX + 1, "jnp"),
         ("gpu", 64, "jnp"),
     ])
@@ -153,7 +153,7 @@ class TestAutoPolicyTable:
 
     def test_unknown_width_keeps_old_rules(self):
         A = self._A()
-        assert _default_auto_policy(A, None, platform="tpu") == "pallas"
+        assert _default_auto_policy(A, None, platform="tpu") == "pallas_onehot"
         assert _default_auto_policy(A, None, platform="cpu") == "jnp"
 
     def test_dense_ish_tpu_overrides_skinny(self):
@@ -189,10 +189,10 @@ class TestAutoPolicyTable:
         assert sp.resolve_backend("auto", A, n=4,
                                   platform="cpu") == "spmv_jnp"
         assert sp.resolve_backend("auto", A, n=64,
-                                  platform="tpu") == "pallas"
+                                  platform="tpu") == "pallas_onehot"
         # no operand, no n: pre-operand resolution keeps the old rules
         assert sp.resolve_backend("auto", A,
-                                  platform="tpu") == "pallas"
+                                  platform="tpu") == "pallas_onehot"
 
 
 class TestSkinnyThresholdTunable:
@@ -224,7 +224,7 @@ class TestSkinnyThresholdTunable:
             assert _default_auto_policy(A, self._b(thr),
                                         platform="tpu") == "spmv"
             assert _default_auto_policy(A, self._b(thr + 1),
-                                        platform="tpu") == "pallas"
+                                        platform="tpu") == "pallas_onehot"
         finally:
             sp.set_skinny_n_max(None)
 

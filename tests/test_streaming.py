@@ -40,7 +40,8 @@ class TestWindows:
         d = A.data
         W = A.windows(2, 5)
         dw = W.data
-        assert dw.vals.shape == (d.mb, 3, d.lw)
+        assert dw.vals.shape == (d.mb, 3) + d.vals.shape[-2:]
+        assert dw.lw == d.lw
         assert dw.q.shape == (d.mb, 3)
         assert dw.nse.shape == (d.mb, 3)
         assert W.shape == (A.m, 3 * d.k0)
@@ -312,8 +313,8 @@ class TestSpmmStreamingDifferentiable:
         args = (A.values, b, c, jnp.float32(1.3), jnp.float32(0.7))
         g = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
         gd = jax.grad(loss_dense, argnums=(0, 1, 2, 3, 4))(*args)
-        lw = A.data.vals.shape[2]
-        valid = np.arange(lw) < np.asarray(A.data.nse)[:, :, None]
+        valid = (np.arange(A.data.lw).reshape(A.data.vals.shape[-2:])
+                 < np.asarray(A.data.nse)[:, :, None, None])
         np.testing.assert_allclose(np.asarray(g[0])[valid],
                                    np.asarray(gd[0])[valid],
                                    rtol=1e-4, atol=1e-4, err_msg="vals")

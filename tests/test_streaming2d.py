@@ -183,8 +183,8 @@ class TestSpmmStreaming2D:
         args = (A.values, b, c, jnp.float32(1.3), jnp.float32(0.7))
         g = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
         gd = jax.grad(loss_dense, argnums=(0, 1, 2, 3, 4))(*args)
-        lw = A.data.vals.shape[2]
-        valid = np.arange(lw) < np.asarray(A.data.nse)[:, :, None]
+        valid = (np.arange(A.data.lw).reshape(A.data.vals.shape[-2:])
+                 < np.asarray(A.data.nse)[:, :, None, None])
         np.testing.assert_allclose(np.asarray(g[0])[valid],
                                    np.asarray(gd[0])[valid],
                                    rtol=1e-4, atol=1e-4, err_msg="vals")

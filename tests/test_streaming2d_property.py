@@ -84,8 +84,8 @@ def test_tiled_gradients_match_dense_oracle(wc, nt, seed):
 
     g_s = jax.grad(loss_stream, argnums=(0, 1, 2))(A.values, bj, cj)
     g_d = jax.grad(loss_dense, argnums=(0, 1, 2))(A.values, bj, cj)
-    lw = A.data.vals.shape[2]
-    valid = np.arange(lw) < np.asarray(A.data.nse)[:, :, None]
+    valid = (np.arange(A.data.lw).reshape(A.data.vals.shape[-2:])
+             < np.asarray(A.data.nse)[:, :, None, None])
     np.testing.assert_allclose(np.asarray(g_s[0])[valid],
                                np.asarray(g_d[0])[valid],
                                rtol=1e-4, atol=1e-4, err_msg="vals")
