@@ -47,7 +47,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -58,6 +57,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.async_pipeline import PackExecutePipeline, SpmmFuture
 from repro.core.partition import cdiv
 from repro.core.sparse import SparseMatrix
+from repro.tracing import span
 
 # NOTE: repro.sparse_api is imported lazily inside methods — importing it
 # here would cycle (sparse_api -> core.hflex -> core.__init__ -> engine).
@@ -95,7 +95,8 @@ class EngineStats:
     tuned_dispatches: int = 0   # dispatches run through a DB-tuned plan
     tune_db_hits: int = 0       # TuningDB lookups resolved during plan builds
     tune_db_misses: int = 0
-    # plan-build wall time, split by whether the build compiled something
+    # plan-build wall time (the ``sextans.plan.build`` span's seconds,
+    # ``SpmmPlan.build_s``), split by whether the build compiled something
     # (cold: PLAN_STATS exec_misses grew — trace+compile and, in measure
     # mode, tuning measurement) or reused an executable of the plan cache
     # (warm)
@@ -182,10 +183,12 @@ class SextansEngine:
         """
         from repro.sparse_api import Format, from_sparse_matrix
 
-        t = from_sparse_matrix(
-            a, format=Format.HFLEX, tm=self.tm, k0=self.k0, chunk=self.chunk,
-            interleave=self.interleave, bucket=self.bucket, device=device,
-        )
+        with span("sextans.pack"):
+            t = from_sparse_matrix(
+                a, format=Format.HFLEX, tm=self.tm, k0=self.k0,
+                chunk=self.chunk, interleave=self.interleave,
+                bucket=self.bucket, device=device,
+            )
         with self._lock:
             self.stats.packs += 1
             self.stats.real_nnz += t.nnz
@@ -272,7 +275,6 @@ class SextansEngine:
         db_misses0 = TUNE_STATS["db_misses"]
         exec_misses0 = PLAN_STATS["exec_misses"]
         t = self._as_tensor(packed)
-        t0 = time.perf_counter()
         if stream:
             pl = _plan(t, n, backend=self.impl, dtype=dtype, stream=True,
                        device_bytes=device_bytes, window_chunk=window_chunk,
@@ -282,7 +284,7 @@ class SextansEngine:
             pl = _plan(t, n, backend=self.impl, dtype=dtype,
                        tn=self.tn, interpret=self.interpret,
                        autotune=self.autotune)
-        build_s = time.perf_counter() - t0
+        build_s = pl.build_s
         cold = PLAN_STATS["exec_misses"] > exec_misses0
         with self._lock:
             self.stats.plan_cache_misses += 1
@@ -310,28 +312,30 @@ class SextansEngine:
     ) -> jax.Array:
         from repro.sparse_api import SKINNY_BACKENDS, spmm
 
-        t = self._as_tensor(packed)
-        sig = self.signature(t, b.shape[1], b)
-        with self._lock:
-            if sig in self._seen_signatures:
-                self.stats.cache_hits += 1
-            else:
-                self.stats.cache_misses += 1
-                self._seen_signatures.add(sig)
-            self.stats.calls += 1
-            self.stats.dispatches += 1
-            if sig[-1] in SKINNY_BACKENDS:
-                self.stats.skinny_dispatches += 1
-        if self.use_plans:
-            # Pass the *caller's* object: the plan cache keys on its id, so
-            # legacy PackedSpMM inputs hit the cache across calls.
-            pl = self.plan_for(packed, b.shape[1], b.dtype)
-            if pl.tuned:
-                with self._lock:
-                    self.stats.tuned_dispatches += 1
-            return pl.run(b, c, alpha, beta)
-        return spmm(t, b, c, alpha, beta, backend=self.impl,
-                    tn=self.tn, interpret=self.interpret)
+        with span("sextans.engine.spmm"):
+            t = self._as_tensor(packed)
+            sig = self.signature(t, b.shape[1], b)
+            with self._lock:
+                if sig in self._seen_signatures:
+                    self.stats.cache_hits += 1
+                else:
+                    self.stats.cache_misses += 1
+                    self._seen_signatures.add(sig)
+                self.stats.calls += 1
+                self.stats.dispatches += 1
+                if sig[-1] in SKINNY_BACKENDS:
+                    self.stats.skinny_dispatches += 1
+            if self.use_plans:
+                # Pass the *caller's* object: the plan cache keys on its
+                # id, so legacy PackedSpMM inputs hit the cache across
+                # calls.
+                pl = self.plan_for(packed, b.shape[1], b.dtype)
+                if pl.tuned:
+                    with self._lock:
+                        self.stats.tuned_dispatches += 1
+                return pl.run(b, c, alpha, beta)
+            return spmm(t, b, c, alpha, beta, backend=self.impl,
+                        tn=self.tn, interpret=self.interpret)
 
     def spmm_streaming(
         self,

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.tracing import span
+
 from .common import Initializer, ModelConfig, compute_dtype
 
 __all__ = [
@@ -710,17 +712,19 @@ class SparseLinear:
                  **opts) -> jax.Array:
         from repro.sparse_api import spmm
 
-        lead = x.shape[:-1]
-        xb = x.reshape(-1, self.d_in)
-        if use_plan:
-            # Inference-only fast path (plans are AOT executables, not
-            # differentiable): pass the live weights as the values operand.
-            pl = self.plan_for(xb.shape[0], backend=backend, **opts)
-            y = pl.run(xb.T, values=params["w"]).T        # (B, d_out)
-        else:
-            a = self.skeleton.with_values(params["w"])
-            y = spmm(a, xb.T, backend=backend, **opts).T  # (B, d_out)
-        return y.reshape(*lead, self.d_out)
+        with span("sextans.layer.linear"):
+            lead = x.shape[:-1]
+            xb = x.reshape(-1, self.d_in)
+            if use_plan:
+                # Inference-only fast path (plans are AOT executables, not
+                # differentiable): pass the live weights as the values
+                # operand.
+                pl = self.plan_for(xb.shape[0], backend=backend, **opts)
+                y = pl.run(xb.T, values=params["w"]).T        # (B, d_out)
+            else:
+                a = self.skeleton.with_values(params["w"])
+                y = spmm(a, xb.T, backend=backend, **opts).T  # (B, d_out)
+            return y.reshape(*lead, self.d_out)
 
 
 # ---------------------------------------------------------------------------
@@ -752,7 +756,8 @@ class SparseLinearGroup:
         if not layers:
             raise ValueError("SparseLinearGroup needs at least one layer")
         self.layers = layers
-        self.skeleton = stack_bsr([l.skeleton for l in layers])
+        with span("sextans.pack"):
+            self.skeleton = stack_bsr([l.skeleton for l in layers])
         self._plans: Dict[Any, Any] = {}
 
     @property
@@ -771,12 +776,14 @@ class SparseLinearGroup:
         """Member payloads ``(nb_g, TK, TF)`` -> the stacked
         ``(G, NB_pad, TK, TF)`` payload.  Pad slots are zero; the grouped
         VJP masks them, so stacked values remain trainable."""
-        nb_pad = self.skeleton.values.shape[1]
-        vs = []
-        for v in values_list:
-            v = jnp.asarray(v)
-            vs.append(jnp.pad(v, ((0, nb_pad - v.shape[0]), (0, 0), (0, 0))))
-        return jnp.stack(vs)
+        with span("sextans.layer.stack_values"):
+            nb_pad = self.skeleton.values.shape[1]
+            vs = []
+            for v in values_list:
+                v = jnp.asarray(v)
+                vs.append(jnp.pad(v, ((0, nb_pad - v.shape[0]), (0, 0),
+                                      (0, 0))))
+            return jnp.stack(vs)
 
     def plan_for(self, batch: int, *, backend: str = "auto", **opts):
         from repro.sparse_api import plan_group
@@ -797,17 +804,18 @@ class SparseLinearGroup:
         """
         from repro.sparse_api import spmm
 
-        vals = self.stack_values([p["w"] for p in params_list])
-        if x.ndim == 2:
-            x = jnp.broadcast_to(x[None], (self.batch, *x.shape))
-        xb = jnp.swapaxes(x, -1, -2)                  # (G, d_in, B)
-        if use_plan:
-            pl = self.plan_for(x.shape[1], backend=backend, **opts)
-            y = pl.run(xb, values=vals)
-        else:
-            y = spmm(self.skeleton.with_values(vals), xb,
-                     backend=backend, **opts)
-        return jnp.swapaxes(y, -1, -2)                # (G, B, d_out)
+        with span("sextans.layer.group"):
+            vals = self.stack_values([p["w"] for p in params_list])
+            if x.ndim == 2:
+                x = jnp.broadcast_to(x[None], (self.batch, *x.shape))
+            xb = jnp.swapaxes(x, -1, -2)                  # (G, d_in, B)
+            if use_plan:
+                pl = self.plan_for(x.shape[1], backend=backend, **opts)
+                y = pl.run(xb, values=vals)
+            else:
+                y = spmm(self.skeleton.with_values(vals), xb,
+                         backend=backend, **opts)
+            return jnp.swapaxes(y, -1, -2)                # (G, B, d_out)
 
     def submit(self, scheduler, params_list, x) -> list:
         """Enqueue one pre-packed request per member on an

@@ -84,6 +84,7 @@ from repro.core.hflex import bucket_geometry
 from repro.core.partition import cdiv
 from repro.kernels._compat import resolve_interpret
 from repro.kernels.sextans_spmm import sextans_spmm_pallas
+from repro.tracing import span
 
 from . import backends as _bk
 from .tensor import Format, PackedSpMM, SparseTensor, stack_bsr, stack_hflex
@@ -220,6 +221,8 @@ class SpmmPlan:
     #: True when a TuningDB decision steered this plan's backend/tiling
     #: (set by ``plan()``/``plan_group()``; engines count tuned dispatches).
     tuned = False
+    #: seconds of the ``sextans.plan.build`` span that built this plan
+    build_s = 0.0
 
     def __init__(self, a: SparseTensor, n: int, backend: str,
                  opts: Dict[str, Any], dtype=jnp.float32, mesh=None):
@@ -432,33 +435,34 @@ class SpmmPlan:
         substitutes a new non-zero payload with the packed structure of
         ``A`` (same shape as ``A.values`` — per-group for a group plan).
         """
-        b = jnp.asarray(b)
-        if b.shape != self._bshape or b.dtype != self.dtype:
-            raise ValueError(
-                f"plan expects b of shape {self._bshape} dtype "
-                f"{self.dtype}, got {b.shape} {b.dtype}")
-        if c is None:
-            if self._zero_c is None:
-                self._zero_c = jnp.zeros(self._cshape, self.dtype)
-            c = self._zero_c
-        else:
-            # cast to the planned dtype: the executable was compiled for
-            # it, and the batched scheduler casts mismatched c the same way
-            c = jnp.asarray(c, self.dtype)
-        alpha, beta = _ab_operands(self._ab_cache, alpha, beta,
-                                   g=self.group)
-        ops = self._operands
-        if values is not None:
-            values = jnp.asarray(values)
-            if self._flat:                     # flat path stores vals flat
-                lead = values.shape[:-4] if values.ndim >= 4 else ()
-                values = values.reshape(*lead, -1)
-            elif self._place_values is not None:   # row-split mesh plan
-                values = self._place_values(values)
-            ops = (ops[:self._values_slot] + (values,)
-                   + ops[self._values_slot + 1:])
-        PLAN_STATS["dispatches"] += 1
-        return self._compiled(*ops, b, c, alpha, beta)
+        with span("sextans.plan.run"):
+            b = jnp.asarray(b)
+            if b.shape != self._bshape or b.dtype != self.dtype:
+                raise ValueError(
+                    f"plan expects b of shape {self._bshape} dtype "
+                    f"{self.dtype}, got {b.shape} {b.dtype}")
+            if c is None:
+                if self._zero_c is None:
+                    self._zero_c = jnp.zeros(self._cshape, self.dtype)
+                c = self._zero_c
+            else:
+                # cast to the planned dtype: the executable was compiled for
+                # it, and the batched scheduler casts mismatched c the same way
+                c = jnp.asarray(c, self.dtype)
+            alpha, beta = _ab_operands(self._ab_cache, alpha, beta,
+                                       g=self.group)
+            ops = self._operands
+            if values is not None:
+                values = jnp.asarray(values)
+                if self._flat:                     # flat path stores vals flat
+                    lead = values.shape[:-4] if values.ndim >= 4 else ()
+                    values = values.reshape(*lead, -1)
+                elif self._place_values is not None:   # row-split mesh plan
+                    values = self._place_values(values)
+                ops = (ops[:self._values_slot] + (values,)
+                       + ops[self._values_slot + 1:])
+            PLAN_STATS["dispatches"] += 1
+            return self._compiled(*ops, b, c, alpha, beta)
 
     def __call__(self, b, c=None, alpha=1.0, beta=0.0, **kw) -> jax.Array:
         return self.run(b, c, alpha, beta, **kw)
@@ -580,6 +584,8 @@ class StreamingPlan:
     #: True when a TuningDB decision steered this plan's tiling (see
     #: :class:`SpmmPlan.tuned`).
     tuned = False
+    #: seconds of the ``sextans.plan.build`` span that built this plan
+    build_s = 0.0
 
     def __init__(self, a: SparseTensor, n: int, backend: str,
                  opts: Dict[str, Any], dtype=jnp.float32,
@@ -1010,44 +1016,45 @@ def plan(
     on a streaming plan — and the returned plan's ``tuned`` flag records
     whether a DB decision applied.  Mesh plans are never tuned.
     """
-    mode = "off"
-    if mesh is None:
-        from .autotune import resolve_mode, resolve_plan_knobs
+    with span("sextans.plan.build") as sp:
+        mode = "off"
+        if mesh is None:
+            from .autotune import resolve_mode, resolve_plan_knobs
 
-        mode = resolve_mode(autotune)
-    budget: Optional[int] = None
-    if device_bytes is not None:
-        budget = (device_memory_budget() if device_bytes == "auto"
-                  else int(device_bytes))
-    if stream is None:
-        stream = False
-        if budget is not None:
-            itemsize = jnp.dtype(dtype).itemsize
-            m, k = a.shape
-            working = a.nbytes + (k * n + 2 * m * n) * itemsize
-            stream = working > budget
-    tuned = False
-    if mode != "off":
-        backend, window_chunk, n_tile, tuned = resolve_plan_knobs(
-            a, n, dtype=jnp.dtype(dtype), mode=mode, backend=backend,
-            stream=bool(stream), device_bytes=budget,
-            window_chunk=window_chunk, n_tile=n_tile, opts=opts)
-    if stream:
-        if mesh is not None:
-            raise ValueError(
-                "streaming plans cannot carry a mesh; shard rows across "
-                "chips first, then stream each shard (device_bytes applies "
-                "per chip)")
-        spl = StreamingPlan(a, n, backend, opts, dtype=dtype,
-                            device_bytes=budget, window_chunk=window_chunk,
-                            n_tile=n_tile)
-        spl.tuned = tuned
-        return spl
-    if n_tile is not None:
-        raise ValueError("n_tile applies to streaming plans only (pass "
-                         "stream=True or a device_bytes budget)")
-    pl = SpmmPlan(a, n, backend, opts, dtype=dtype, mesh=mesh)
-    pl.tuned = tuned
+            mode = resolve_mode(autotune)
+        budget: Optional[int] = None
+        if device_bytes is not None:
+            budget = (device_memory_budget() if device_bytes == "auto"
+                      else int(device_bytes))
+        if stream is None:
+            stream = False
+            if budget is not None:
+                itemsize = jnp.dtype(dtype).itemsize
+                m, k = a.shape
+                working = a.nbytes + (k * n + 2 * m * n) * itemsize
+                stream = working > budget
+        tuned = False
+        if mode != "off":
+            backend, window_chunk, n_tile, tuned = resolve_plan_knobs(
+                a, n, dtype=jnp.dtype(dtype), mode=mode, backend=backend,
+                stream=bool(stream), device_bytes=budget,
+                window_chunk=window_chunk, n_tile=n_tile, opts=opts)
+        if stream:
+            if mesh is not None:
+                raise ValueError(
+                    "streaming plans cannot carry a mesh; shard rows across "
+                    "chips first, then stream each shard (device_bytes applies "
+                    "per chip)")
+            pl = StreamingPlan(a, n, backend, opts, dtype=dtype,
+                               device_bytes=budget, window_chunk=window_chunk,
+                               n_tile=n_tile)
+        else:
+            if n_tile is not None:
+                raise ValueError("n_tile applies to streaming plans only (pass "
+                                 "stream=True or a device_bytes budget)")
+            pl = SpmmPlan(a, n, backend, opts, dtype=dtype, mesh=mesh)
+        pl.tuned = tuned
+    pl.build_s = sp.wall_s
     return pl
 
 
@@ -1079,27 +1086,29 @@ def plan_group(
     choice only — they are always resident; the tuning key carries the
     group size, so a G=16 pool and a singleton tune independently).
     """
-    if isinstance(tensors, SparseTensor):
-        a = tensors
-        if a.batch is None:
-            a = (stack_bsr([a]) if a.format is Format.BSR
-                 else stack_hflex([a]))
-    else:
-        ts = list(tensors)
-        if ts and ts[0].format is Format.BSR:
-            a = stack_bsr(ts)
+    with span("sextans.plan.build") as sp:
+        if isinstance(tensors, SparseTensor):
+            a = tensors
+            if a.batch is None:
+                a = (stack_bsr([a]) if a.format is Format.BSR
+                     else stack_hflex([a]))
         else:
-            a = stack_hflex(ts)
-    tuned = False
-    if mesh is None:
-        from .autotune import resolve_mode, resolve_plan_knobs
+            ts = list(tensors)
+            if ts and ts[0].format is Format.BSR:
+                a = stack_bsr(ts)
+            else:
+                a = stack_hflex(ts)
+        tuned = False
+        if mesh is None:
+            from .autotune import resolve_mode, resolve_plan_knobs
 
-        mode = resolve_mode(autotune)
-        if mode != "off":
-            backend, _, _, tuned = resolve_plan_knobs(
-                a, n, dtype=jnp.dtype(dtype), mode=mode, backend=backend,
-                stream=False, device_bytes=None, window_chunk=None,
-                n_tile=None, opts=opts, group=a.batch)
-    pl = SpmmPlan(a, n, backend, opts, dtype=dtype, mesh=mesh)
-    pl.tuned = tuned
+            mode = resolve_mode(autotune)
+            if mode != "off":
+                backend, _, _, tuned = resolve_plan_knobs(
+                    a, n, dtype=jnp.dtype(dtype), mode=mode, backend=backend,
+                    stream=False, device_bytes=None, window_chunk=None,
+                    n_tile=None, opts=opts, group=a.batch)
+        pl = SpmmPlan(a, n, backend, opts, dtype=dtype, mesh=mesh)
+        pl.tuned = tuned
+    pl.build_s = sp.wall_s
     return pl
