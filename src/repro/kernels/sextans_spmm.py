@@ -24,13 +24,20 @@ TPU re-derivation of the paper's streaming dataflow (DESIGN.md §2):
 
 Two gather strategies for B rows:
 
-* ``onehot``  — gather as a second one-hot matmul (K0 × L)ᵀ @ (K0 × TN):
-  the strategy that lowers through Mosaic, so the TPU path uses it.
+* ``onehot``  — gather as a second one-hot matmul: the strategy that
+  lowers through Mosaic, so the TPU path uses it.  Once per non-empty
+  grid step the window is transposed and split exactly into three
+  bfloat16 parts, ``x = hi + mid + lo`` (:func:`bf16_split3`), stacked
+  into one (3·TN × K0) operand.  Each trip contracts it against a
+  bfloat16 (K0 × L) one-hot in ONE default-precision MXU pass with
+  float32 accumulation: every output is ``1 · part`` plus exact zeros, so
+  ``hi + mid + lo`` summed in float32 is ``bwin[c]`` bit for bit.
 * ``gather``  — vector row-gather ``bwin[c]`` from the VMEM window.  Mosaic
   refuses this gather, so it runs in interpret mode only.
 
-Both matmuls run at ``Precision.HIGHEST``: the operands are float32 and a
-default-precision TPU matmul would round them to bfloat16.
+The scatter matmul runs at ``Precision.HIGHEST``: it forms the real
+products ``v · b`` of float32 operands, which a default-precision TPU
+matmul would round to bfloat16.
 
 Grid: (MB, NT, NW), windows innermost so the output block and accumulator
 stay resident while K streams — the exact loop nest of paper Algorithm 1
@@ -71,28 +78,59 @@ def _slab_row(ref, i, lead: int, tile_rows: int):
                    keepdims=True)
 
 
+_BF16_BITS = -65536          # 0xFFFF0000: sign, exponent, 7 mantissa bits
+
+
+def _bf16_head(x):
+    """``x`` with its low 16 bits cleared: a float32 that bfloat16 holds
+    exactly (truncated, so it never rounds up to infinity)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32) & _BF16_BITS
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def bf16_split3(x):
+    """Split float32 ``x`` into bfloat16 ``(hi, mid, lo)`` with
+    ``(hi + mid) + lo == x`` exactly in float32.
+
+    ``hi`` holds the top 8 significant bits, ``mid`` the next 8 of the
+    remainder and ``lo`` the rest (at most 8 bits), all with the sign of
+    ``x``.  Exact for zero and every finite ``|x| >= 2**-103``; below
+    that a part falls under the smallest normal and is flushed."""
+    hi = _bf16_head(x)
+    rem = x - hi                 # the low 16 bits of x: exact
+    mid = _bf16_head(rem)
+    lo = rem - mid               # exact, at most 8 significant bits
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, lo))
+
+
 def _scatter_trip(acc, v, c, r, bwin, *, tm: int, gather: str):
     """One trip of ``L`` packed non-zeros: ``acc[r] += v * bwin[c]``.
 
-    ``v``/``c``/``r`` are ``(1, L)`` rows; ``bwin`` is the (K0, W) window.
-    The row scatter is a (TM × L) @ (L × W) matmul with the values folded
-    into the one-hot, so every product ``v * b`` is formed once, exactly
-    as the flat reference forms it."""
+    ``v``/``c``/``r`` are ``(1, L)`` rows.  For ``onehot``, ``bwin`` is
+    the stacked (3·W, K0) bfloat16 split of the window's transpose and the
+    gathered rows come out transposed, (W, L); for ``gather`` it is the
+    (K0, W) window itself.  The row scatter is a (TM × L) one-hot matmul
+    against them with the values folded into the one-hot, so every
+    product ``v * b`` is formed once, exactly as the flat reference forms
+    it."""
     lanes = v.shape[-1]
     if gather == "onehot":
-        k0 = bwin.shape[0]
+        k0 = bwin.shape[1]
+        w = bwin.shape[0] // 3
         oh_c = (jax.lax.broadcasted_iota(jnp.int32, (k0, lanes), 0)
-                == c).astype(jnp.float32)
-        brows = jax.lax.dot_general(                       # (L, W)
-            oh_c, bwin, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_HI)
+                == c).astype(jnp.bfloat16)                  # (K0, L)
+        parts = jnp.dot(bwin, oh_c,
+                        preferred_element_type=jnp.float32)  # (3W, L)
+        brows = (parts[:w] + parts[w:2 * w]) + parts[2 * w:]  # (W, L)
+        dims = (((1,), (1,)), ((), ()))
     else:
         brows = bwin[c[0], :]                               # (L, W)
+        dims = (((1,), (0,)), ((), ()))
     oh_rv = jnp.where(
         jax.lax.broadcasted_iota(jnp.int32, (tm, lanes), 0) == r,
         v.astype(jnp.float32), 0.0)                         # (TM, L)
     return acc + jax.lax.dot_general(
-        oh_rv, brows, (((1,), (0,)), ((), ())),
+        oh_rv, brows, dims,
         preferred_element_type=jnp.float32, precision=_HI)
 
 
@@ -151,6 +189,9 @@ def _kernel(
     def _process_window():
         lanes = vals_ref.shape[-1]
         bwin = _tile(b_ref).astype(jnp.float32)  # (K0, TN) window in VMEM
+        if gather == "onehot":
+            # the gather's MXU operand, once per step: (3·TN, K0) bf16
+            bwin = jnp.concatenate(bf16_split3(bwin.T), axis=0)
         lead = 3 if batched else 2
 
         def body(i, acc):

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from repro.kernels.bsr_spmm import bsr_matmul_pallas_batched
@@ -29,6 +30,9 @@ HBM_BYTES = 16 * 10**9            # one v5e chip
 # ogbn-arxiv scale (169,343 nodes) packed at TM=128, K0=4096: the seeded
 # power-law graph of chip_smoke.py packs to LW=8192 slots per slab.
 MB, NW, R, L, K0, TM = 1323, 42, 64, 128, 4096, 128
+# The gap-kron graph cells (scale 18, 262,144 vertices) packed at TM=128,
+# K0=4096: 2,048 row blocks by 64 windows, four 128-lane rows a slab.
+KRON_MB, KRON_NW, KRON_R = 2048, 64, 4
 # qwen2-0.5b FFN ``wi`` (896 x 4864) at 90% 128x128 block sparsity, 24
 # layers in one group: 27 blocks each, padded to the 32-block bucket.
 D_MODEL, D_FF, NB_PAD, LAYERS, TOKENS = 896, 4864, 32, 24, 256
@@ -60,9 +64,16 @@ def no_cache():
 
 @pytest.fixture(scope="module")
 def shape(topo, no_cache):
-    """Shape-and-dtype factory placed on one described chip."""
+    """Shape-and-dtype factory placed on one described chip; ``row_major``
+    also pins the array's layout to row-major."""
     one_chip = SingleDeviceSharding(topo.devices[0])
-    return lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def make(s, dt, row_major=False):
+        where = (Format(Layout(major_to_minor=tuple(range(len(s)))), one_chip)
+                 if row_major else one_chip)
+        return jax.ShapeDtypeStruct(s, dt, sharding=where)
+
+    return make
 
 
 def _check(compiled):
@@ -76,16 +87,17 @@ def _check(compiled):
     return mem
 
 
-def _slabs(shape, lead, nw=NW):
-    s = (*lead, MB, nw, R, L)
+def _slabs(shape, lead, nw=NW, mb=MB, r=R):
+    s = (*lead, mb, nw, r, L)
     return (shape(s, jnp.float32), shape(s, jnp.int32), shape(s, jnp.int32),
-            shape((*lead, MB, nw), jnp.int32))
+            shape((*lead, mb, nw), jnp.int32))
 
 
-def _compile_hflex(shape, lead=(), n=128, tn=128, nw=NW, accumulate=False):
-    vals, cols, rows, q = _slabs(shape, lead, nw)
-    b = shape((*lead, nw * K0, n), jnp.float32)
-    c = shape((*lead, MB * TM, n), jnp.float32)
+def _compile_hflex(shape, lead=(), n=128, tn=128, nw=NW, accumulate=False,
+                   mb=MB, r=R, row_major=False):
+    vals, cols, rows, q = _slabs(shape, lead, nw, mb, r)
+    b = shape((*lead, nw * K0, n), jnp.float32, row_major)
+    c = shape((*lead, mb * TM, n), jnp.float32, row_major)
     ab = shape(lead, jnp.float32)
 
     def f(vals, cols, rows, q, b, c, alpha, beta):
@@ -94,8 +106,10 @@ def _compile_hflex(shape, lead=(), n=128, tn=128, nw=NW, accumulate=False):
                                    gather="onehot", interpret=False,
                                    accumulate=accumulate)
 
-    return _check(jax.jit(f).lower(vals, cols, rows, q, b, c, ab, ab)
-                  .compile())
+    out = (Format(Layout(major_to_minor=(0, 1)), vals.sharding)
+           if row_major else None)
+    return _check(jax.jit(f, out_shardings=out)
+                  .lower(vals, cols, rows, q, b, c, ab, ab).compile())
 
 
 def test_hflex_resident(shape):
@@ -115,6 +129,18 @@ def test_hflex_accumulate_chunk(shape):
 def test_spmv_lane(shape):
     """N = 1 pads to 8 lanes and one column tile."""
     _compile_hflex(shape, n=8, tn=8)
+
+
+@pytest.mark.parametrize("tn", [128, 8])
+def test_hflex_kron_cells(shape, tn):
+    """The graph cells' geometry at N = 128 (the tall lane) and at N = 1
+    padded to 8 lanes (the SpMV lane): every slab is non-empty there, so
+    each grid step builds the split B window the one-hot gather reads.
+    B, C and the result are row-major, as the plan's padding makes them
+    (a free (K, 8) parameter would be given a column-major layout and a
+    relayout copy)."""
+    _compile_hflex(shape, n=tn, tn=tn, nw=KRON_NW, mb=KRON_MB, r=KRON_R,
+                   row_major=True)
 
 
 def test_bsr_grouped_ffn(shape):
