@@ -110,8 +110,17 @@ def _dense(values, brow, bcol, d_in, d_out, b):
 
 
 def _split(a):
-    hi = a.astype(jnp.bfloat16).astype(jnp.float32)
-    return hi, (a - hi).astype(jnp.bfloat16).astype(jnp.float32)
+    """``a = hi + lo + rest`` with ``hi`` and ``lo`` bfloat16 values held
+    in float32, rounded to nearest even by ``reduce_precision``: under
+    ``jit`` on the TPU a round trip through ``bfloat16`` is folded away
+    (excess precision), which leaves ``lo`` zero and the control one
+    pass."""
+
+    def rnd(v):
+        return jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+
+    hi = rnd(a)
+    return hi, rnd(a - hi)
 
 
 def _dot(a, w, precision):

@@ -45,7 +45,7 @@ def _skeleton(values, brow, bcol, d_in, d_out, b):
 class FfnDecode(ClosedLoop):
 
     sample_count = 3
-    sample_of = 32
+    sample_of = 8
 
     def __init__(self, cfg: Dict, traffic: Dict, rt: Dict):
         super().__init__(rt)

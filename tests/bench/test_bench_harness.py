@@ -11,9 +11,9 @@ import sys
 import numpy as np
 import pytest
 
-from bench_helpers import REPO
+from bench_helpers import DEFAULT, FAULTS, HERE, REPO, load_json, tiny_file
 
-from bench import ffn, graph, trace, work
+from bench import ffn, graph, reference, trace, work
 
 # -- trace reduction ---------------------------------------------------------
 
@@ -167,6 +167,25 @@ def test_ffn_counts_match_the_published_widths():
         assert np.unique(keys).shape == (258,)
 
 
+def test_ffn_control_split_rounds_on_the_bits_under_jit():
+    """The FFN control's ``HIGH`` split gives the graph control's
+    round-to-nearest-even parts bit for bit under ``jit``, and holds no
+    round trip through bfloat16: on the TPU, ``jit`` folds that away and
+    leaves the low part zero, the control one bfloat16 pass."""
+    import jax
+
+    rng = np.random.default_rng(3)
+    x = (rng.standard_normal(4096)
+         * 10.0 ** rng.integers(-30, 30, 4096)).astype(np.float32)
+    split = jax.jit(ffn._split)
+    hi, lo = split(x)
+    want_hi, want_lo = reference._split_bf16(x)
+    np.testing.assert_array_equal(np.asarray(hi), want_hi)
+    np.testing.assert_array_equal(np.asarray(lo), want_lo)
+    assert np.count_nonzero(want_lo) > 4000
+    assert "bf16" not in split.lower(x).as_text()
+
+
 # -- useful work -------------------------------------------------------------
 
 
@@ -243,6 +262,24 @@ def test_every_named_file_exists():
                                            m["name"] + ".py"))
     for c in spec["configs"]:
         assert os.path.exists(os.path.join(REPO, c["file"]))
+
+
+def test_every_config_has_a_tiny_size_and_every_cell_file_a_cell():
+    spec = load_json(os.path.join(REPO, "BENCHMARK.json"))
+    configs = {c["name"] for c in spec["configs"]}
+    assert {n for n in configs if not os.path.exists(tiny_file(n))} == set()
+    tiny = {os.path.splitext(f)[0]
+            for f in os.listdir(os.path.join(HERE, "tiny"))}
+    assert tiny <= configs, tiny - configs
+    cells = {w["name"] for w in spec["workloads"]}
+    for f in sorted(os.listdir(os.path.join(HERE, "cells"))):
+        name, ext = os.path.splitext(f)
+        assert ext == ".json" and name in cells, f
+        exp = load_json(os.path.join(HERE, "cells", f))
+        assert set(exp) <= set(DEFAULT), (f, sorted(exp))
+        assert set(exp.get("faults", [])) <= set(FAULTS), (f, exp)
+        assert all(s.startswith("sextans.")
+                   for s in exp.get("window_spans", [])), (f, exp)
 
 
 def test_without_a_tpu_the_command_prints_no_result():

@@ -9,7 +9,7 @@ import sys
 
 import pytest
 
-from bench_helpers import REPO, make_tiny_root, run_cell
+from bench_helpers import REPO, expectations, make_tiny_root, run_cell
 
 from bench import run as R
 from bench import trace
@@ -69,7 +69,7 @@ def test_traced_run_reads_the_program_spans(cell, tmp_path, monkeypatch):
         return loaded[-1]
 
     monkeypatch.setattr(trace, "load", keep)
-    res = run_cell(make_tiny_root(tmp_path), cell, trace=1)
+    res = run_cell(make_tiny_root(tmp_path, cell), cell, trace=1)
     assert res["correct"], res["check"]
     m = res["metrics"]
     assert m["plan_build_s.setup"]["value"] > 0
@@ -78,12 +78,8 @@ def test_traced_run_reads_the_program_spans(cell, tmp_path, monkeypatch):
     t0, t1 = trace.window(t)
     inside = [n for n, s, d in spans if t0 <= s and s + d <= t1]
     assert inside.count("sextans.plan.run") >= res["attempted"]
-    if cell.startswith("olmo-ffn"):
-        assert "sextans.layer.group" in inside
-        assert "sextans.layer.stack_values" in inside
-        assert "sextans.layer.linear" in inside
-    else:
-        assert "sextans.engine.spmm" in inside
+    for name in expectations(cell)["window_spans"]:
+        assert name in inside, name
     # packs and plan builds are set-up work, so the set-up readers, which
     # count the whole run, read set-up alone
     assert "sextans.pack" not in inside
