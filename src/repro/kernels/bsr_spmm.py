@@ -19,6 +19,14 @@ into a resident f32 accumulator.  Slots past a tile's block count repeat
 the last block index (no new DMA) and skip the matmul.  One compiled
 kernel serves any sparsity pattern of the same bucketed geometry (HFlex).
 
+The *ragged* mode (:func:`bsr_matmul_pallas_ragged`, Pallas name
+``bsr_spmm_ragged``) serves a dropless mixture of experts: the rows of
+``x`` are token-expert pairs sorted by expert into 128-row-aligned
+segments of one static buffer, and each row tile multiplies by the
+weight of the expert that a scalar-prefetched table names for it.  Tiles
+past the used count repeat the last used tile's blocks (no DMA), skip the
+matmul and store zeros.
+
 On TPU the blocks are lane tiles: ``TK`` and ``TF`` must be multiples of
 128 (``repro.sparse_api.plan`` refuses other BSR tilings there).
 """
@@ -35,7 +43,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._compat import resolve_interpret as _resolve_interpret
 
-__all__ = ["bsr_matmul_pallas", "bsr_matmul_pallas_batched"]
+__all__ = ["bsr_matmul_pallas", "bsr_matmul_pallas_batched",
+           "bsr_matmul_pallas_ragged"]
 
 
 def _kernel(indptr_ref, brow_ref, x_ref, w_ref, o_ref, acc_ref, *,
@@ -180,3 +189,97 @@ def bsr_matmul_pallas_batched(
     own payload.  Output ``(G, B, NF*tf)``."""
     return _call(x, blocks, brow, indptr, tb=tb, tk=tk, tf=tf,
                  interpret=interpret, batched=True)
+
+
+def _ragged_kernel(indptr_ref, brow_ref, te_ref, used_ref, x_ref, w_ref,
+                   o_ref, acc_ref):
+    # refs: x (TB, TK), w (1, 1, TK, TF), o (TB, TF)
+    b = pl.program_id(0)
+    f = pl.program_id(1)
+    j = pl.program_id(2)
+    e = te_ref[b]
+    count = indptr_ref[e, f + 1] - indptr_ref[e, f]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when((b < used_ref[0]) & (j < count))
+    def _accumulate():
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[...].astype(jnp.float32),
+            w_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _store():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("tb", "tk", "tf", "interpret")
+)
+def bsr_matmul_pallas_ragged(
+    x: jax.Array,         # (R, K), rows grouped by expert in TB-row tiles
+    blocks: jax.Array,    # (E, NB, TK, TF), per expert sorted by block-col
+    brow: jax.Array,      # (E, NB) i32
+    indptr: jax.Array,    # (E, NF+1) i32 pointers into blocks per out tile
+    te: jax.Array,        # (R/TB,) i32 expert of each row tile
+    used: jax.Array,      # (1,) i32 row tiles in use
+    *,
+    tb: int = 128,
+    tk: int = 128,
+    tf: int = 128,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Ragged grouped ``y[rows of tile b] = x[rows of tile b] @ W[te[b]]``
+    over a stacked BSR group — ONE launch for every expert, whatever rows
+    each holds.  Grid ``(R/TB, NF, NJ)``; the index maps pick member
+    ``te[b]``'s blocks exactly as the batched mode picks member ``g``'s,
+    so a tile's rows are bit-identical to :func:`bsr_matmul_pallas_batched`
+    on the same rows.  Tiles ``b >= used[0]`` map every slot to the last
+    used tile's last block (no new DMA), skip the matmul and store zeros.
+    Output ``(R, NF*tf)``."""
+    interpret = _resolve_interpret(interpret)
+    rows, k = x.shape
+    nb = blocks.shape[-3]
+    nf = indptr.shape[-1] - 1
+    assert rows % tb == 0 and k % tk == 0
+    assert blocks.shape[-2:] == (tk, tf) and te.shape == (rows // tb,)
+    nj = max(1, min(nb, k // tk))
+
+    def where(b, f, j, ip, br, te, used):
+        """(expert, block) that grid step (b, f, j) reads."""
+        last = jnp.maximum(used[0] - 1, 0)
+        live = b < used[0]
+        e = te[jnp.minimum(b, last)]
+        f = jnp.where(live, f, nf - 1)
+        j = jnp.where(live, j, nj - 1)
+        return jnp.minimum(b, last), e, _slot(lambda i: ip[e, i], f, j, nb)
+
+    def x_map(b, f, j, ip, br, te, used):
+        bb, e, s = where(b, f, j, ip, br, te, used)
+        return (bb, br[e, s])
+
+    def w_map(b, f, j, ip, br, te, used):
+        _, e, s = where(b, f, j, ip, br, te, used)
+        return (e, s, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(rows // tb, nf, nj),
+        in_specs=[pl.BlockSpec((tb, tk), x_map),
+                  pl.BlockSpec((1, 1, tk, tf), w_map)],
+        out_specs=pl.BlockSpec((tb, tf), lambda b, f, j, *_: (b, f)),
+        scratch_shapes=[pltpu.VMEM((tb, tf), jnp.float32)],
+    )
+    return pl.pallas_call(
+        _ragged_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, nf * tf), x.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="bsr_spmm_ragged",
+    )(indptr, brow, te, used, x, blocks)

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["spmm_dense_ref", "spmm_coo_ref", "spmm_slabs_ref",
-           "bsr_matmul_ref", "bsr_matmul_ref_batched"]
+           "bsr_matmul_ref", "bsr_matmul_ref_batched", "bsr_dense_batched"]
 
 
 def spmm_dense_ref(a_dense, b, c, alpha=1.0, beta=0.0):
@@ -85,15 +85,22 @@ def bsr_matmul_ref_batched(x, blocks, block_row, block_col,
     member-wise.  Out-of-range ``block_col`` entries (the zero padding
     slots of a stacked group) are dropped by the scatter.
     """
-    g, nb, tk, tf = blocks.shape
-    k, f = nblk_rows * tk, nblk_cols * tf
-    gi = jnp.arange(g, dtype=jnp.int32)[:, None]
-    w = jnp.zeros((g, nblk_rows, nblk_cols, tk, tf), jnp.float32)
-    w = w.at[gi, block_row, block_col].add(blocks.astype(jnp.float32))
-    w = w.transpose(0, 1, 3, 2, 4).reshape(g, k, f)
+    w = bsr_dense_batched(blocks, block_row, block_col, nblk_rows,
+                          nblk_cols)
     y = jax.lax.dot_general(
         x.astype(jnp.float32), w,
         (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )
     return (alpha * y).astype(x.dtype)
+
+
+def bsr_dense_batched(blocks, block_row, block_col, nblk_rows, nblk_cols):
+    """The dense ``(G, K, F)`` float32 weights of a stacked BSR group;
+    out-of-range ``block_col`` entries (padding slots) are dropped."""
+    g, nb, tk, tf = blocks.shape
+    gi = jnp.arange(g, dtype=jnp.int32)[:, None]
+    w = jnp.zeros((g, nblk_rows, nblk_cols, tk, tf), jnp.float32)
+    w = w.at[gi, block_row, block_col].add(blocks.astype(jnp.float32))
+    return w.transpose(0, 1, 3, 2, 4).reshape(g, nblk_rows * tk,
+                                               nblk_cols * tf)

@@ -57,6 +57,10 @@ class ModelConfig:
     moe_capacity_factor: float = 1.25
     shared_expert: bool = False
     shared_expert_ff: int = 0
+    # serving router (SparseMoE use_plan=True): renormalise the top-k gates,
+    # then scale them (DeepSeek's norm_topk_prob, routed_scaling_factor)
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
     # SSM / recurrent
     ssm_state: int = 0

@@ -26,7 +26,7 @@ __all__ = [
     "mesh_context", "constrain",
     "linear", "rmsnorm_init", "rmsnorm", "rope", "attention_init", "attention_apply",
     "decode_attention_apply", "ffn_init", "ffn_apply", "moe_init", "moe_apply",
-    "SparseLinear", "SparseLinearGroup", "SparseMoE",
+    "SparseLinear", "SparseLinearGroup", "SparseFFN", "SparseMoE",
 ]
 
 # ---------------------------------------------------------------------------
@@ -629,22 +629,25 @@ def moe_apply(p: Dict[str, Any], cfg: ModelConfig, x: jax.Array) -> jax.Array:
 def _prune_blocks(w, block: Tuple[int, int], density: float):
     """Magnitude (block-L2) pruning of a dense ``(d_in, d_out)`` weight:
     keep the top-``density`` fraction of ``(bi, bo)`` tiles by L2 norm,
-    zero the rest.  Ties at the threshold are all kept, so the survivor
+    zero the rest.  A width that is not a multiple of its tile is covered
+    by ``ceil(width / tile)`` tiles whose pad part is zero; the result keeps
+    the logical shape.  Ties at the threshold are all kept, so the survivor
     count can exceed ``round(density * n_tiles)`` by the tie multiplicity
     (the grouped lane tolerates ragged kept-block counts)."""
     import numpy as np
 
     bi, bo = block
     d_in, d_out = w.shape
-    if d_in % bi or d_out % bo:
-        raise ValueError("d_in/d_out must be multiples of the block tile")
-    norms = np.linalg.norm(
-        w.reshape(d_in // bi, bi, d_out // bo, bo), axis=(1, 3))
+    nbi, nbo = -(-d_in // bi), -(-d_out // bo)
+    wp = np.zeros((nbi * bi, nbo * bo), w.dtype)
+    wp[:d_in, :d_out] = w
+    norms = np.linalg.norm(wp.reshape(nbi, bi, nbo, bo), axis=(1, 3))
     keep_n = max(1, int(round(density * norms.size)))
     thresh = np.sort(norms.reshape(-1))[-keep_n]
     mask = norms >= thresh
-    return (w.reshape(d_in // bi, bi, d_out // bo, bo)
-            * mask[:, None, :, None]).reshape(d_in, d_out)
+    wp = (wp.reshape(nbi, bi, nbo, bo)
+          * mask[:, None, :, None]).reshape(nbi * bi, nbo * bo)
+    return wp[:d_in, :d_out]
 
 
 class SparseLinear:
@@ -832,23 +835,135 @@ class SparseLinearGroup:
                 for l, p in zip(self.layers, params_list)]
 
 
+class SparseFFN:
+    """Gated FFN of block-pruned weights, ``down(act(gate(x)) * up(x))``,
+    served as pruned FFN layers are: ``gate`` and ``up`` as ONE
+    :class:`SparseLinearGroup` dispatch, ``down`` as a
+    :class:`SparseLinear`.  Params: ``{"gate": {"w"}, "up": {"w"},
+    "down": {"w"}}``.  Widths need not be multiples of the tile."""
+
+    def __init__(self, gate: SparseLinear, up: SparseLinear,
+                 down: SparseLinear):
+        self.gate_up = SparseLinearGroup([gate, up])
+        self.down = down
+
+    @classmethod
+    def create(cls, init: Initializer, d: int, ff: int,
+               block: Tuple[int, int] = (128, 128),
+               density: float = 0.5) -> Tuple["SparseFFN", Dict[str, Any]]:
+        bi, bo = block
+        (g, pg), (u, pu) = (SparseLinear.create(init, d, ff, block=block,
+                                                density=density)
+                            for _ in range(2))
+        dn, pd = SparseLinear.create(init, ff, d, block=(bo, bi),
+                                     density=density)
+        return cls(g, u, dn), {"gate": pg, "up": pu, "down": pd}
+
+    def __call__(self, p: Dict[str, Any], x: jax.Array, *, act: str = "silu",
+                 backend: str = "auto", use_plan: bool = False,
+                 **opts) -> jax.Array:
+        lead = x.shape[:-1]
+        gu = self.gate_up([p["gate"], p["up"]], x.reshape(-1, x.shape[-1]),
+                          backend=backend, use_plan=use_plan, **opts)
+        y = self.down(p["down"], _glu(gu, act=act), backend=backend,
+                      use_plan=use_plan, **opts)
+        return y.reshape(*lead, y.shape[-1])
+
+
+@functools.partial(jax.jit, static_argnames=("act",))
+def _glu(g, u=None, *, act: str):
+    """``act(g) * u``; with one argument, ``g`` stacks gate and up."""
+    if u is None:
+        g, u = g[0], g[1]
+    return _act(act)(g) * u
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "k", "norm", "scale", "tile", "rows"))
+def _moe_dispatch(router, x, stats, *, k: int, norm: bool, scale: float,
+                  tile: int, rows: int):
+    """Dropless top-``k`` routing of the ``(T, d)`` tokens ``x`` and their
+    expert-sorted row layout.
+
+    Softmax over every expert's float32 logit (matmul at ``HIGHEST``),
+    greedy top-k; gates are the softmax scores, renormalised over the k
+    only if ``norm``, then times ``scale``.  The ``T*k`` token-expert pairs
+    are sorted by expert, and expert ``e``'s segment starts at a
+    ``tile``-aligned row of one buffer of ``rows`` rows, so no pair is
+    dropped and no tensor has an expert-by-capacity axis.  Returns the
+    buffer ``(rows, d)`` (unused rows zero), the expert of each row tile,
+    the count of tiles in use ``(1,)``, each pair's row ``(T, k)``, the
+    gates ``(T, k)`` and ``stats`` plus this call's per-expert loads and
+    tiles ``(2, E)``."""
+    t, d = x.shape
+    e = router.shape[1]
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gate, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if norm:
+        gate = gate / gate.sum(-1, keepdims=True)
+    gate = gate * scale
+    pair_e = idx.reshape(-1)                      # pair p: token p // k
+    order = jnp.argsort(pair_e, stable=True)
+    sorted_e = pair_e[order]
+    loads = jnp.bincount(pair_e, length=e).astype(jnp.int32)
+    tiles = (loads + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)
+    first_row = (tile_end - tiles) * tile
+    first_pair = jnp.cumsum(loads) - loads
+    dest = (first_row[sorted_e] + jnp.arange(t * k, dtype=jnp.int32)
+            - first_pair[sorted_e])
+    pos = jnp.zeros(t * k, jnp.int32).at[order].set(dest).reshape(t, k)
+    src = jnp.full(rows, t, jnp.int32).at[dest].set(order // k)
+    xbuf = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[src]
+    te = jnp.minimum(jnp.searchsorted(tile_end, jnp.arange(rows // tile),
+                                      side="right"), e - 1).astype(jnp.int32)
+    return (xbuf, te, tile_end[-1:].astype(jnp.int32), pos, gate,
+            stats + jnp.stack([loads, tiles]))
+
+
+@jax.jit
+def _moe_combine(ybuf, pos, gate):
+    """Each token's gate-weighted sum of its k expert rows, in float32."""
+    return (ybuf[pos].astype(jnp.float32)
+            * gate[..., None].astype(jnp.float32)).sum(1).astype(ybuf.dtype)
+
+
 class SparseMoE:
     """Block-pruned MoE on the grouped BSR lane.
 
     Each expert's ``wi``/``wg``/``wo`` is magnitude-pruned to (nearly) the
     same kept-block count, so the E experts of each projection stack via
-    :func:`repro.sparse_api.stack_bsr` into one batched tensor and the E
-    expert matmuls execute as ONE grouped dispatch — 3 dispatches per MoE
-    layer instead of 3·E.  Routing reuses the GShard capacity router of
-    :func:`moe_apply`; the trainable payload is the stacked block array
-    ``(E, NB_pad, TK, TF)`` per projection, and the grouped VJP pins the
-    pad slots at exact zero, so pruned experts *train*.
+    :func:`repro.sparse_api.stack_bsr` into one batched tensor.  The
+    trainable payload is the stacked block array ``(E, NB_pad, TK, TF)``
+    per projection, and the grouped VJP pins the pad slots at exact zero,
+    so pruned experts *train*.  An optional shared expert
+    (:class:`SparseFFN`, params ``p["shared"]``) runs on every token.
+
+    Two paths:
+
+    * :meth:`apply` (training, differentiable): the GShard capacity router
+      of :func:`moe_apply`, E experts as one grouped ``spmm`` per
+      projection.
+    * ``__call__(..., use_plan=True)`` (serving): dropless top-k routing
+      (:func:`_moe_dispatch`, honouring ``cfg.norm_topk_prob`` and
+      ``cfg.routed_scaling_factor``), each projection one dispatch of a
+      cached :class:`~repro.sparse_api.RaggedPlan` over the resident
+      stacked payload, a gate-weighted combine, and the shared expert
+      through ``plan``-family executables.  Spans ``sextans.moe.route``,
+      ``.experts``, ``.combine``, ``.shared``.  :attr:`expert_stats` is a
+      device-side int32 ``(2, E)`` accumulator of per-expert routed rows
+      (row 0) and row tiles computed (row 1) over every serving call
+      since :meth:`reset_stats`; reading it is the caller's sync.
     """
 
-    def __init__(self, wi, wg, wo):
+    def __init__(self, wi, wg, wo, shared: Optional[SparseFFN] = None):
         # stacked SparseTensor skeletons, E members each, shapes:
         #   wi/wg: (d_ff, d_model)   wo: (d_model, d_ff)
         self.wi, self.wg, self.wo = wi, wg, wo
+        self.shared = shared
+        self._plans: Dict[Any, Any] = {}
+        self.reset_stats()
 
     @property
     def num_experts(self) -> int:
@@ -858,14 +973,18 @@ class SparseMoE:
     def density(self) -> float:
         return self.wi.density
 
+    def reset_stats(self) -> None:
+        self.expert_stats = jnp.zeros((2, self.wi.batch), jnp.int32)
+
     @classmethod
     def create(cls, init: Initializer, cfg: ModelConfig,
                block: Tuple[int, int] = (128, 128),
                density: float = 0.25) -> Tuple["SparseMoE", Dict[str, Any]]:
         """Init dense expert weights, block-prune each expert, stack per
         projection.  ``block`` is the (input-dim, output-dim) tile of each
-        projection.  Returns (layer, params) with ``params["wi"/"wg"/"wo"]``
-        the stacked trainable block values."""
+        projection; a shared expert (``cfg.shared_expert``) is pruned to the
+        same density.  Returns (layer, params) with ``params["wi"/"wg"/
+        "wo"]`` the stacked trainable block values."""
         import numpy as np
 
         from repro.sparse_api import Format, from_dense, stack_bsr
@@ -889,9 +1008,12 @@ class SparseMoE:
             "router": init.dense(d, e, scale=0.02),
             "wi": wi.values, "wg": wg.values, "wo": wo.values,
         }
+        shared = None
         if cfg.shared_expert:
-            params["shared"] = ffn_init(init, d, cfg.shared_expert_ff or ff)
-        return cls(wi, wg, wo), params
+            shared, params["shared"] = SparseFFN.create(
+                init, d, cfg.shared_expert_ff or ff, block=block,
+                density=density)
+        return cls(wi, wg, wo, shared), params
 
     @_scoped("sparse_moe")
     def apply(self, p: Dict[str, Any], cfg: ModelConfig, x: jax.Array, *,
@@ -925,9 +1047,57 @@ class SparseMoE:
 
         y = jnp.einsum("gecd,gtec->gtd", eout, combine)
         y = y.reshape(b, s, d)
-        if cfg.shared_expert and "shared" in p:
-            y = y + ffn_apply(p["shared"], cfg, x)
+        if self.shared is not None:
+            y = y + self.shared(p["shared"], x, act=cfg.act,
+                                backend=backend, **opts).astype(y.dtype)
         return y.astype(dtype)
 
-    def __call__(self, p, cfg, x, **kw) -> jax.Array:
+    def plan_for(self, name: str, rows: int, *, backend: str = "auto",
+                 **opts):
+        """The cached :class:`~repro.sparse_api.RaggedPlan` of projection
+        ``name`` (``"wg"``, ``"wi"``, ``"wo"``) for ``rows`` sorted rows."""
+        from repro.sparse_api import plan_ragged
+
+        key = (name, int(rows), backend, tuple(sorted(opts.items())))
+        pl = self._plans.get(key)
+        if pl is None:
+            pl = plan_ragged(getattr(self, name), int(rows), backend=backend,
+                             **opts)
+            self._plans[key] = pl
+        return pl
+
+    def serve(self, p: Dict[str, Any], cfg: ModelConfig, x: jax.Array, *,
+              backend: str = "auto", **opts) -> jax.Array:
+        """The serving path (see the class docstring); ``x`` is
+        ``(..., d_model)``, every leading position a token."""
+        from repro.sparse_api.backends import RAGGED_TILE as tile
+
+        lead, d = x.shape[:-1], x.shape[-1]
+        xt = x.reshape(-1, d)
+        t, e, k = xt.shape[0], self.num_experts, cfg.experts_per_token
+        rows = -(-(t * k + e * (tile - 1)) // tile) * tile
+        with span("sextans.moe.route"):
+            xbuf, te, used, pos, gate, self.expert_stats = _moe_dispatch(
+                p["router"], xt, self.expert_stats, k=k,
+                norm=cfg.norm_topk_prob,
+                scale=float(cfg.routed_scaling_factor), tile=tile, rows=rows)
+        def run(name, a):
+            pl = self.plan_for(name, rows, backend=backend, **opts)
+            return pl.run(a, te, used, values=p[name])
+
+        with span("sextans.moe.experts"):
+            h = _glu(run("wg", xbuf), run("wi", xbuf), act=cfg.act)
+            ybuf = run("wo", h)
+        with span("sextans.moe.combine"):
+            y = _moe_combine(ybuf, pos, gate)
+        if self.shared is not None:
+            with span("sextans.moe.shared"):
+                y = y + self.shared(p["shared"], xt, act=cfg.act,
+                                    backend=backend, use_plan=True, **opts)
+        return y.reshape(*lead, d)
+
+    def __call__(self, p, cfg, x, *, use_plan: bool = False,
+                 **kw) -> jax.Array:
+        if use_plan:
+            return self.serve(p, cfg, x, **kw)
         return self.apply(p, cfg, x, **kw)

@@ -59,12 +59,14 @@ from .backends import (
 from .ops import spmm, spmm_raw, spmm_streaming
 from .plan import (
     PLAN_STATS,
+    RaggedPlan,
     SpmmPlan,
     StreamingPlan,
     clear_plan_cache,
     device_memory_budget,
     plan,
     plan_group,
+    plan_ragged,
 )
 from .tensor import (
     BsrWeight,
@@ -93,7 +95,9 @@ __all__ = [
     "spmm_streaming",
     "plan",
     "plan_group",
+    "plan_ragged",
     "SpmmPlan",
+    "RaggedPlan",
     "StreamingPlan",
     "StreamOps",
     "PLAN_STATS",

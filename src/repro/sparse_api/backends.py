@@ -41,8 +41,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.partition import cdiv
-from repro.kernels.bsr_spmm import bsr_matmul_pallas, bsr_matmul_pallas_batched
-from repro.kernels.ref import bsr_matmul_ref, bsr_matmul_ref_batched
+from repro.kernels.bsr_spmm import (bsr_matmul_pallas,
+                                    bsr_matmul_pallas_batched,
+                                    bsr_matmul_pallas_ragged)
+from repro.kernels.ref import (bsr_dense_batched, bsr_matmul_ref,
+                               bsr_matmul_ref_batched)
 from repro.kernels.sextans_spmm import sextans_spmm_pallas
 
 from .tensor import Format, SparseTensor
@@ -540,13 +543,9 @@ def _bsr_raw_jnp(a: SparseTensor, b):
     w = a.data
     m, k = a.shape
     if a.batch is not None:
-        nb = w.blocks.shape[1]
         xb = jnp.pad(b, ((0, 0), (0, w.k - k), (0, 0)))
         xb = xb.transpose(0, 2, 1)                   # (G, N, K')
-        bcol = jax.vmap(
-            lambda ip: jnp.searchsorted(ip, jnp.arange(nb),
-                                        side="right") - 1)(w.indptr)
-        y = bsr_matmul_ref_batched(xb, w.blocks, w.brow, bcol,
+        y = bsr_matmul_ref_batched(xb, w.blocks, w.brow, _bcol_batched(w),
                                    w.k // w.tk, w.f // w.tf)  # (G, N, M')
         return y.transpose(0, 2, 1)[:, :m]
     xb = jnp.pad(b, ((0, w.k - k), (0, 0))).T        # (N, K')
@@ -586,6 +585,50 @@ def _bsr_pallas(a: SparseTensor, b, c, alpha, beta, *, tn, interpret):
                           tb=tn, tk=w.tk, tf=w.tf, interpret=interpret)
     raw = y[:n].T[:m].astype(jnp.float32)            # (M, N)
     return (alpha * raw + beta * c.astype(jnp.float32)).astype(b.dtype)
+
+
+#: rows of one tile of the ragged grouped product (:func:`bsr_ragged`)
+RAGGED_TILE = 128
+
+
+def _bcol_batched(w):
+    """Block column of every stored slot of a stacked BSR payload
+    ``(G, NB)``; padding slots get ``NBF``, out of range."""
+    nb = w.blocks.shape[1]
+    return jax.vmap(lambda ip: jnp.searchsorted(ip, jnp.arange(nb),
+                                                side="right") - 1)(w.indptr)
+
+
+def bsr_ragged(backend: str, a: SparseTensor, x, te, used, *,
+               interpret=None, **_unused):
+    """Ragged grouped product of a stacked BSR tensor ``a`` (E members of
+    logical shape (M, K)): ``y[r] = x[r] @ A[te[r // TB]]^T`` for the
+    ``(R, K)`` rows ``x``, laid out by expert in tiles of
+    ``TB = RAGGED_TILE`` rows; row tiles from ``used[0]`` on come back
+    zero.  Returns ``(R, M)``.  ``"pallas"`` runs the kernel's ragged
+    mode; ``"jnp"`` is its XLA twin (each tile against its member's dense
+    weight)."""
+    bump_trace()
+    w = a.data
+    m, k = a.shape
+    xb = jnp.pad(x, ((0, 0), (0, w.k - k)))              # (R, K')
+    if backend == "pallas":
+        y = bsr_matmul_pallas_ragged(xb, w.blocks, w.brow, w.indptr, te,
+                                     used, tb=RAGGED_TILE, tk=w.tk,
+                                     tf=w.tf, interpret=interpret)
+        return y[:, :m]
+    if backend != "jnp":
+        raise ValueError(f"no ragged BSR product on backend {backend!r}; "
+                         f"use 'pallas' or 'jnp'")
+    dense = bsr_dense_batched(w.blocks, w.brow, _bcol_batched(w),
+                              w.k // w.tk, w.f // w.tf)  # (E, K', M')
+    nt = x.shape[0] // RAGGED_TILE
+    xt = xb.reshape(nt, RAGGED_TILE, w.k).astype(jnp.float32)
+    y = jax.lax.dot_general(xt, dense[te], (((2,), (1,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32)
+    live = (jnp.arange(nt) < used[0])[:, None, None]
+    return jnp.where(live, y, 0.0).reshape(x.shape[0], w.f)[:, :m].astype(
+        x.dtype)
 
 
 def _backend_jnp(a, b, c, alpha, beta, **_unused):

@@ -89,8 +89,9 @@ from repro.tracing import span
 from . import backends as _bk
 from .tensor import Format, PackedSpMM, SparseTensor, stack_bsr, stack_hflex
 
-__all__ = ["SpmmPlan", "StreamingPlan", "plan", "plan_group",
-           "clear_plan_cache", "device_memory_budget", "PLAN_STATS"]
+__all__ = ["SpmmPlan", "StreamingPlan", "RaggedPlan", "plan", "plan_group",
+           "plan_ragged", "clear_plan_cache", "device_memory_budget",
+           "PLAN_STATS"]
 
 # Executable-cache hits/misses (the paper counts avoided place/route runs;
 # we count avoided traces+compiles) and compiled-call dispatches (the
@@ -145,6 +146,19 @@ def _aot_compile(key: Tuple, fn, arg_shapes, in_shardings=None,
         compiled = jfn.lower(*arg_shapes).compile()
         _EXEC_CACHE[key] = compiled
         return compiled
+
+
+def _check_bsr_tiles(a: SparseTensor, backend: str, opts: Dict[str, Any]):
+    """Refuse a BSR tiling that the compiled TPU kernel cannot run."""
+    d = a.data
+    if (backend == "pallas" and (d.tk % 128 or d.tf % 128)
+            and not resolve_interpret(opts.get("interpret"))):
+        raise ValueError(
+            f"BSR blocks of {d.tk}x{d.tf} cannot run on the TPU "
+            f"kernel: its x and output tiles are lane tiles, so "
+            f"both block sides must be multiples of 128 — repack "
+            f"with block=(128, 128) (or a multiple), or plan with "
+            f"backend='jnp'")
 
 
 def device_memory_budget() -> Optional[int]:
@@ -242,16 +256,8 @@ class SpmmPlan:
         self.opts = dict(opts)
         self.dtype = jnp.dtype(dtype)
         okey = tuple(sorted(self.opts.items()))
-        if (a.format is Format.BSR and self.backend == "pallas"
-                and not resolve_interpret(self.opts.get("interpret"))):
-            d = a.data
-            if d.tk % 128 or d.tf % 128:
-                raise ValueError(
-                    f"BSR blocks of {d.tk}x{d.tf} cannot run on the TPU "
-                    f"kernel: its x and output tiles are lane tiles, so "
-                    f"both block sides must be multiples of 128 — repack "
-                    f"with block=(128, 128) (or a multiple), or plan with "
-                    f"backend='jnp'")
+        if a.format is Format.BSR:
+            _check_bsr_tiles(a, self.backend, self.opts)
 
         m, k, n = self.m, self.k, self.n
         g = self.group
@@ -473,6 +479,78 @@ class SpmmPlan:
         return (f"SpmmPlan(shape=({self.m}, {self.k}){gtag}@{self.n}, "
                 f"backend={self.backend!r}, format={self.a.format.value}"
                 f"{mtag})")
+
+
+class RaggedPlan:
+    """A prepared ragged grouped product over a stacked BSR tensor: the
+    dropless mixture-of-experts lane.
+
+    ``A`` holds E experts' weights (``A.batch == E``, each of logical shape
+    ``(M, K)``).  :meth:`run` takes ``rows`` token rows ``x`` of shape
+    ``(rows, K)``, laid out by expert in tiles of
+    :data:`~repro.sparse_api.backends.RAGGED_TILE` rows, the expert of each
+    row tile ``te`` and the count of tiles in use ``used`` (both int32, on
+    the device), and returns ``(rows, M)`` with row ``r`` equal to
+    ``x[r] @ A[te[r // TILE]]^T``.  Built via :func:`plan_ragged`; the
+    executable is cached by (backend, geometry, E, logical shape, rows,
+    dtype), and ``run(values=...)`` substitutes the live stacked payload,
+    as :meth:`SpmmPlan.run` does.  Inference only.
+    """
+
+    #: seconds of the ``sextans.plan.build`` span that built this plan
+    build_s = 0.0
+
+    def __init__(self, a: SparseTensor, rows: int, backend: str,
+                 opts: Dict[str, Any], dtype=jnp.float32):
+        if a.format is not Format.BSR or a.batch is None:
+            raise ValueError("a ragged plan takes a stacked BSR tensor "
+                             "(stack_bsr)")
+        tile = _bk.RAGGED_TILE
+        if rows <= 0 or rows % tile:
+            raise ValueError(f"rows must be a positive multiple of {tile}, "
+                             f"got {rows}")
+        self.a = a
+        self.rows = int(rows)
+        self.m, self.k = a.shape
+        self.group = a.batch
+        self.backend = _bk.resolve_backend(backend, a, n=self.rows)
+        self.opts = dict(opts)
+        self.dtype = jnp.dtype(dtype)
+        _check_bsr_tiles(a, self.backend, self.opts)
+        d = a.data
+        self._operands = tuple(jnp.asarray(x)
+                               for x in (d.blocks, d.brow, d.indptr))
+        self.exec_key = ("ragged", self.backend,
+                         tuple(sorted(self.opts.items())), a.geometry,
+                         self.group, (self.m, self.k), self.rows,
+                         str(self.dtype))
+        treedef = jax.tree_util.tree_structure(a)
+        backend_name, opts_d = self.backend, self.opts
+
+        def traced(blocks, brow, indptr, x, te, used):
+            a_t = jax.tree_util.tree_unflatten(treedef,
+                                               [blocks, brow, indptr])
+            return _bk.bsr_ragged(backend_name, a_t, x, te, used, **opts_d)
+
+        sd = jax.ShapeDtypeStruct
+        arg_shapes = tuple(sd(x.shape, x.dtype) for x in self._operands) + (
+            sd((self.rows, self.k), self.dtype),
+            sd((self.rows // tile,), jnp.int32), sd((1,), jnp.int32))
+        self._compiled = _aot_compile(self.exec_key, traced, arg_shapes)
+
+    def run(self, x, te, used, *, values=None) -> jax.Array:
+        """One compiled-call dispatch; ``values`` replaces the stacked
+        ``(E, NB, TK, TF)`` payload."""
+        with span("sextans.plan.run"):
+            ops = self._operands
+            if values is not None:
+                ops = (values,) + ops[1:]
+            PLAN_STATS["dispatches"] += 1
+            return self._compiled(*ops, x, te, used)
+
+    def __repr__(self) -> str:
+        return (f"RaggedPlan(shape=({self.m}, {self.k})x{self.group}"
+                f"@{self.rows} rows, backend={self.backend!r})")
 
 
 def row_split_spmm(d: PackedSpMM, mesh, m: int, k: int, n: int,
@@ -1110,5 +1188,15 @@ def plan_group(
                     n_tile=None, opts=opts, group=a.batch)
         pl = SpmmPlan(a, n, backend, opts, dtype=dtype, mesh=mesh)
         pl.tuned = tuned
+    pl.build_s = sp.wall_s
+    return pl
+
+
+def plan_ragged(a: SparseTensor, rows: int, *, backend: str = "auto",
+                dtype=jnp.float32, **opts) -> RaggedPlan:
+    """Prepare the ragged grouped product of the stacked BSR tensor ``a``
+    for ``rows`` expert-sorted token rows (:class:`RaggedPlan`)."""
+    with span("sextans.plan.build") as sp:
+        pl = RaggedPlan(a, rows, backend, opts, dtype=dtype)
     pl.build_s = sp.wall_s
     return pl

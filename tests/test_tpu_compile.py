@@ -20,7 +20,8 @@ import pytest
 from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
-from repro.kernels.bsr_spmm import bsr_matmul_pallas_batched
+from repro.kernels.bsr_spmm import (bsr_matmul_pallas_batched,
+                                    bsr_matmul_pallas_ragged)
 from repro.kernels.sextans_spmm import sextans_spmm_pallas
 from repro.sparse_api.plan import row_split_spmm
 from repro.sparse_api.tensor import PackedSpMM
@@ -36,6 +37,11 @@ KRON_MB, KRON_NW, KRON_R = 2048, 64, 4
 # qwen2-0.5b FFN ``wi`` (896 x 4864) at 90% 128x128 block sparsity, 24
 # layers in one group: 27 blocks each, padded to the 32-block bucket.
 D_MODEL, D_FF, NB_PAD, LAYERS, TOKENS = 896, 4864, 32, 24, 256
+# DeepSeek-V2-Lite's routed experts at 90% 128x128 block sparsity: 64
+# experts of 2048 -> 1408 (gate, up) and 1408 -> 2048 (down), 18 tiles
+# kept each; a 2,048-token chunk's 6 pairs a token sorted into 128-row
+# tiles: round_up(2048*6 + 64*127, 128) rows.
+MOE_E, MOE_D, MOE_FF, MOE_NB, MOE_ROWS = 64, 2048, 1408, 18, 20480
 
 
 @pytest.fixture(scope="module")
@@ -154,6 +160,25 @@ def test_bsr_grouped_ffn(shape):
                                          tk=128, tf=128, interpret=False)
 
     _check(jax.jit(f).lower(x, blocks, brow, indptr).compile())
+
+
+@pytest.mark.parametrize("k,f", [(MOE_D, MOE_FF), (MOE_FF, MOE_D)])
+def test_bsr_ragged_moe(shape, k, f):
+    """The ragged mode at the MoE cell's gate/up and down geometry."""
+    x = shape((MOE_ROWS, k), jnp.float32)
+    blocks = shape((MOE_E, MOE_NB, 128, 128), jnp.float32)
+    brow = shape((MOE_E, MOE_NB), jnp.int32)
+    indptr = shape((MOE_E, f // 128 + 1), jnp.int32)
+    te = shape((MOE_ROWS // 128,), jnp.int32)
+    used = shape((1,), jnp.int32)
+
+    def g(x, blocks, brow, indptr, te, used):
+        return bsr_matmul_pallas_ragged(x, blocks, brow, indptr, te, used,
+                                        interpret=False)
+
+    compiled = jax.jit(g).lower(x, blocks, brow, indptr, te, used).compile()
+    _check(compiled)
+    assert "bsr_spmm_ragged" in compiled.as_text()
 
 
 def test_row_split_four_chips(topo, no_cache):
