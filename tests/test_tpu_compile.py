@@ -20,7 +20,8 @@ import pytest
 from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
-from repro.kernels.bsr_spmm import (bsr_matmul_pallas_batched,
+from repro.kernels.bsr_spmm import (bsr_matmul_pallas,
+                                    bsr_matmul_pallas_batched,
                                     bsr_matmul_pallas_ragged)
 from repro.kernels.sextans_spmm import sextans_spmm_pallas
 from repro.sparse_api.plan import row_split_spmm
@@ -42,6 +43,10 @@ D_MODEL, D_FF, NB_PAD, LAYERS, TOKENS = 896, 4864, 32, 24, 256
 # kept each; a 2,048-token chunk's 6 pairs a token sorted into 128-row
 # tiles: round_up(2048*6 + 64*127, 128) rows.
 MOE_E, MOE_D, MOE_FF, MOE_NB, MOE_ROWS = 64, 2048, 1408, 18, 20480
+# Olmo-Hybrid-7B's FFN (3840 -> 11008 -> 3840) at 90% 128x128 block
+# sparsity, a 2,048-token prefill chunk: gate and up as one group of two
+# (258 blocks each in the 512-block bucket), down alone (258 blocks).
+OLMO_D, OLMO_FF, OLMO_T = 3840, 11008, 2048
 
 
 @pytest.fixture(scope="module")
@@ -160,6 +165,27 @@ def test_bsr_grouped_ffn(shape):
                                          tk=128, tf=128, interpret=False)
 
     _check(jax.jit(f).lower(x, blocks, brow, indptr).compile())
+
+
+@pytest.mark.parametrize("g,k,f,nb", [(2, OLMO_D, OLMO_FF, 512),
+                                      (None, OLMO_FF, OLMO_D, 258)])
+def test_bsr_olmo_prefill(shape, g, k, f, nb):
+    """The Olmo prefill cell's gate+up (batched) and down (single) calls:
+    the walk's tables go to scalar prefetch flat, and the kernel keeps
+    the name the trace metrics read."""
+    lead = () if g is None else (g,)
+    x = shape((*lead, OLMO_T, k), jnp.float32)
+    blocks = shape((*lead, nb, 128, 128), jnp.float32)
+    brow = shape((*lead, nb), jnp.int32)
+    indptr = shape((*lead, f // 128 + 1), jnp.int32)
+    call = bsr_matmul_pallas if g is None else bsr_matmul_pallas_batched
+
+    def run(x, blocks, brow, indptr):
+        return call(x, blocks, brow, indptr, interpret=False)
+
+    compiled = jax.jit(run).lower(x, blocks, brow, indptr).compile()
+    _check(compiled)
+    assert "bsr_spmm" in compiled.as_text()
 
 
 @pytest.mark.parametrize("k,f", [(MOE_D, MOE_FF), (MOE_FF, MOE_D)])
