@@ -215,7 +215,7 @@ def bench_kernels() -> None:
     a = power_law_sparse(512, 512, 6, seed=1)
     b = jnp.asarray(rng.standard_normal((512, 64)), jnp.float32)
     A = sp.from_sparse_matrix(a, tm=128, k0=128, chunk=8, bucket=False)
-    for backend in ("pallas", "pallas_onehot", "jnp"):
+    for backend in ("pallas", "jnp"):
         us = _time_call(
             lambda: sp.spmm(A, b, backend=backend).block_until_ready())
         gf = a.problem_size_flop(64) / (us / 1e6) / 1e9
@@ -551,7 +551,8 @@ SPMV_TOL = 1e-5
 
 def bench_spmv() -> None:
     """Skinny-N SpMV fast lane vs the tall-N kernel at N in {1, 4, 8}:
-    the lane pads N to 8 lanes instead of TN=128 (one column tile), so
+    ``pallas`` takes its lane from N (``hflex_lane``), so left alone it
+    pads N to 8 lanes instead of TN=128 (one column tile), so
     every B window streams once and >90% of the padding work disappears.
     Both kernels run compiled on a TPU and interpreted elsewhere.  The two
     widths compile to different matmuls whose f32 sums may round
@@ -572,16 +573,16 @@ def bench_spmv() -> None:
     A = sp.from_sparse_matrix(a, tm=128, k0=128, chunk=8, bucket=True)
     for n in (1, 4, 8):
         b = jnp.asarray(rng.standard_normal((1024, n)), jnp.float32)
-        y_tall = np.asarray(sp.spmm(A, b, backend="pallas_onehot", tn=128))
-        y_skinny = np.asarray(sp.spmm(A, b, backend="spmv"))
+        y_tall = np.asarray(sp.spmm(A, b, backend="pallas", tn=128))
+        y_skinny = np.asarray(sp.spmm(A, b, backend="pallas"))
         diff = (float(np.abs(y_skinny - y_tall).max())
                 / max(float(np.abs(y_tall).max()), 1e-30))
         assert diff <= SPMV_TOL, (
             f"spmv lane diverged from tall-N kernel at N={n}: {diff:.2e}")
         us_t = _time_call(lambda: sp.spmm(
-            A, b, backend="pallas_onehot", tn=128).block_until_ready())
+            A, b, backend="pallas", tn=128).block_until_ready())
         us_s = _time_call(lambda: sp.spmm(
-            A, b, backend="spmv").block_until_ready())
+            A, b, backend="pallas").block_until_ready())
         mnnz_t = a.nnz / (us_t / 1e6) / 1e6
         mnnz_s = a.nnz / (us_s / 1e6) / 1e6
         ratio = us_t / us_s
@@ -1021,7 +1022,7 @@ def main() -> None:
         ("slo", bench_slo),
         ("bsr_serve", bench_bsr_serve),
         ("stream", bench_stream),
-        ("spmv", bench_spmv),
+        ("skinny", bench_spmv),
         ("autotune", bench_autotune),
     ]
     if args.validate:

@@ -18,13 +18,14 @@ autotune off) on data made from ``--seed``:
 (b) out-of-core lane: the same graph with ``device_bytes`` = payload / 4,
     so ``StreamingPlan`` runs the kernel's ``accumulate=True`` steps;
 (c) SpMV lane: N = 1, three PageRank-style steps through ``auto`` ->
-    ``spmv``;
+    ``pallas`` at its narrow 8-column lane;
 (d) pruned FFN: qwen2-0.5b's ``wi`` (896 x 4864) at 90% 128x128 block
     sparsity, 24 layers' weights as one grouped BSR dispatch, N = 256.
 
 Every phase is checked against the float64 host reference
 (``repro.core.sparse.spmm_reference`` / a dense ``x @ W``) and must have
-resolved to its Pallas kernel.  The per-phase lines are set-up figures of
+resolved to its Pallas kernel (``pallas``), on HFLEX at the column tile
+the phase is meant to run: 128 for N = 128, 8 for N = 1.  The per-phase lines are set-up figures of
 a bring-up check (compile and wall seconds include host packing and
 transfers; ``peak_bytes_in_use`` is the process high-water, so it never
 falls from one phase to the next), not benchmark numbers.  The last line
@@ -115,9 +116,15 @@ def _graph(size, seed):
     return power_law_sparse(nodes, nodes, edges / nodes, seed=seed)
 
 
-def _expect(engine, t, n, want):
+def _expect(engine, t, n, want, tn=None):
+    """The backend a width-``n`` call of ``t`` resolves to, checked
+    against ``want`` — and, given ``tn``, the HFLEX kernel's column tile
+    too."""
     got = sp.resolve_backend(engine.impl, t, n=n)
     _require(got == want, f"resolved to {got!r}, expected {want!r}")
+    if tn is not None:
+        lane = engine.tn or sp.hflex_lane(n)
+        _require(lane == tn, f"column tile {lane}, expected {tn}")
     return got
 
 
@@ -136,7 +143,7 @@ def phase_a(size, seed):
         c=(rng.standard_normal((m, N_FEAT)).astype(np.float32)
            if has_c else None), alpha=al, beta=be)
         for al, be, has_c in epi]
-    backend = _expect(eng, A, N_FEAT, "pallas_onehot")
+    backend = _expect(eng, A, N_FEAT, "pallas", tn=128)
     sched = SpmmScheduler(eng, async_pipeline=True)
     outs = []
     # two flushes: the group's two stacked copies of the slabs (~11 GB at
@@ -154,7 +161,8 @@ def phase_a(size, seed):
         c = r.c if r.c is not None else np.zeros((m, N_FEAT), np.float32)
         errs.append(_check("a", y, spmm_reference(a, r.b, c, r.alpha,
                                                   r.beta)))
-    return {"backend": backend, "nnz": a.nnz, "payload_bytes": A.nbytes,
+    return {"backend": backend, "tn": 128, "nnz": a.nnz,
+            "payload_bytes": A.nbytes,
             "requests": len(reqs), "group_dispatches": 1,
             "max_rel_err": f"{max(errs):.2e}", "tol": TOL}
 
@@ -175,7 +183,7 @@ def phase_b(size, seed):
     pl = eng.last_streaming_plan
     _require(isinstance(pl, sp.StreamingPlan)
              and sched.stats["streamed"] == 1, "request was not streamed")
-    _require(pl.backend == "pallas_onehot", f"streamed on {pl.backend!r}")
+    _require(pl.backend == "pallas", f"streamed on {pl.backend!r}")
     err = _check("b", y, spmm_reference(a, b, c, 0.75, -0.5))
     return {"backend": pl.backend, "nnz": a.nnz, "payload_bytes": A.nbytes,
             "device_bytes": budget, "window_chunk": pl.window_chunk,
@@ -193,7 +201,7 @@ def phase_c(size, seed):
     eng = SextansEngine()
     A = eng.pack(a, device=False)
     n = a.shape[0]
-    backend = _expect(eng, A, 1, "spmv")
+    backend = _expect(eng, A, 1, "pallas", tn=8)
     damp = 0.85
     tele = np.full((n, 1), (1.0 - damp) / n, np.float32)
     x = np.full((n, 1), 1.0 / n, np.float32)
@@ -203,8 +211,8 @@ def phase_c(size, seed):
                                 damp, 1.0))
         err = max(err, _check("c", y, spmm_reference(a, x, tele, damp, 1.0)))
         x = y
-    return {"backend": backend, "nnz": a.nnz, "payload_bytes": A.nbytes,
-            "steps": 3, "max_rel_err": f"{err:.2e}", "tol": TOL}
+    return {"backend": backend, "tn": 8, "nnz": a.nnz,
+            "payload_bytes": A.nbytes, "steps": 3, "max_rel_err": f"{err:.2e}", "tol": TOL}
 
 
 def phase_d(size, seed):
@@ -244,7 +252,7 @@ def phase_a_row_split(size, seed):
     rng = np.random.default_rng(seed + 1)
     b = jnp.asarray(rng.standard_normal((a.shape[1], N_FEAT)), jnp.float32)
     c = jnp.asarray(rng.standard_normal((a.shape[0], N_FEAT)), jnp.float32)
-    backend = _expect(eng, A, N_FEAT, "pallas_onehot")
+    backend = _expect(eng, A, N_FEAT, "pallas", tn=128)
     one_chip = SextansEngine()
     one = np.asarray(one_chip.plan_for(A, N_FEAT).run(b, c, 0.5, 2.0))
     del one_chip                     # release chip 0's copy of the slabs

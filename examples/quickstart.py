@@ -33,7 +33,8 @@ def main():
     alpha, beta = 1.0, 0.5
 
     # Pack once; the Format/backend split is orthogonal: the same tensor
-    # runs on "pallas", "pallas_onehot", "jnp", or "auto" dispatch.
+    # runs on "pallas" (the format's chip kernel), "jnp" (the XLA
+    # reference), or "auto" dispatch.
     A = sp.from_sparse_matrix(a, tm=128, k0=256, chunk=8)
     print(f"packed: {A.format} geometry={A.geometry} "
           f"(padding handled by Q pointers — HFlex)")

@@ -24,8 +24,10 @@ The engine restores the HFlex property by
 
 The engine is a thin stats-and-sharding wrapper over the unified front-end
 :mod:`repro.sparse_api` (SparseTensor + backend registry); ``impl`` is a
-registered backend name ("auto" — the default, platform-aware — |
-"pallas" | "pallas_onehot" | "jnp" | ...).
+registered backend name: "auto" (the default, platform-aware), "pallas"
+(the format's Pallas kernel) or "jnp" (the XLA reference).  ``tn`` pins
+the kernel's column tile; left None, the backend takes it from N
+(``repro.sparse_api.hflex_lane``).
 
 :meth:`SextansEngine.spmm_async` is the futures-based entry point: the
 pack runs host-resident (``pack(device=False)``) on a worker thread, the
@@ -79,7 +81,7 @@ class EngineStats:
     window_dispatches: int = 0  # K0-window-chunk dispatches (streaming,
                               # summed over column tiles)
     n_tiles: int = 0          # max column tiles any streamed call needed
-    skinny_dispatches: int = 0  # dispatches routed to a skinny-N backend
+    skinny_dispatches: int = 0  # SpMV-shaped dispatches (sparse_api.is_skinny)
     peak_payload_bytes: int = 0  # max device working set of a streamed call
     cache_hits: int = 0
     cache_misses: int = 0
@@ -135,7 +137,7 @@ class SextansEngine:
         tm: int = 128,
         k0: int = 4096,
         chunk: int = 8,
-        tn: int = 128,
+        tn: Optional[int] = None,
         impl: str = "auto",
         interleave: bool = True,
         bucket: bool = True,
@@ -215,14 +217,21 @@ class SextansEngine:
 
         ``b`` is forwarded to backend resolution so custom ``auto`` policies
         that inspect the operand see the same value dispatch will; ``n`` is
-        forwarded too, so the N-aware skinny-lane policy resolves even when
-        only the width is known."""
+        forwarded too, so such a policy sees the width even when only the
+        width is known."""
         from repro.sparse_api import resolve_backend
 
         t = self._as_tensor(packed)
-        npad = cdiv(n, self.tn) * self.tn
         backend = resolve_backend(self.impl, t, b, n=n)
-        return (*t.geometry, npad, backend)
+        return (*t.geometry, self._npad(n), backend)
+
+    def _npad(self, n: int) -> int:
+        """N padded to the kernel's column tile (``tn``, or the lane
+        ``hflex_lane`` picks from N)."""
+        from repro.sparse_api import hflex_lane
+
+        lane = self.tn or hflex_lane(n)
+        return cdiv(n, lane) * lane
 
     #: plan_for keeps at most this many plans; oldest evicted first.
     PLAN_CACHE_CAP = 256
@@ -310,7 +319,7 @@ class SextansEngine:
         alpha: float = 1.0,
         beta: float = 0.0,
     ) -> jax.Array:
-        from repro.sparse_api import SKINNY_BACKENDS, spmm
+        from repro.sparse_api import is_skinny, spmm
 
         with span("sextans.engine.spmm"):
             t = self._as_tensor(packed)
@@ -323,7 +332,7 @@ class SextansEngine:
                     self._seen_signatures.add(sig)
                 self.stats.calls += 1
                 self.stats.dispatches += 1
-                if sig[-1] in SKINNY_BACKENDS:
+                if is_skinny(t, sig[-1], b.shape[1]):
                     self.stats.skinny_dispatches += 1
             if self.use_plans:
                 # Pass the *caller's* object: the plan cache keys on its
@@ -372,9 +381,8 @@ class SextansEngine:
         pl = self.plan_for(packed, n, dtype, stream=True,
                            device_bytes=device_bytes,
                            window_chunk=window_chunk, n_tile=n_tile)
-        npad = cdiv(n, self.tn) * self.tn
-        sig = (*t.geometry, npad, pl.backend, "stream", pl.window_chunk,
-               pl.n_tile)
+        sig = (*t.geometry, self._npad(n), pl.backend, "stream",
+               pl.window_chunk, pl.n_tile)
         with self._lock:
             self.last_streaming_plan = pl
             if pl.tuned:
@@ -420,7 +428,7 @@ class SextansEngine:
         C_g``, bit-identical to a scalar call with that member's
         coefficients.
         """
-        from repro.sparse_api import SKINNY_BACKENDS, Format
+        from repro.sparse_api import Format, is_skinny
         from repro.sparse_api import plan_group as _plan_group
         from repro.sparse_api import stack_bsr, stack_hflex
 
@@ -452,7 +460,7 @@ class SextansEngine:
             self.stats.group_calls += 1
             if ab_vec:
                 self.stats.abvec_group_calls += 1
-            if sig[-1] in SKINNY_BACKENDS:
+            if is_skinny(t, sig[-1], n):
                 self.stats.skinny_dispatches += 1
         from repro.sparse_api import TUNE_STATS
 

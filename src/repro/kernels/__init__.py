@@ -1,1 +1,3 @@
-from .ops import PackedSpMM, pack_for_device, sextans_spmm, BsrWeight, bsr_pack, bsr_matmul
+"""Pallas TPU kernels: the HFLEX streaming kernel (``sextans_spmm``), the
+BSR tile kernel (``bsr_spmm``) and their XLA oracles (``ref``).  Callers
+go through :mod:`repro.sparse_api`."""

@@ -22,18 +22,14 @@ TPU re-derivation of the paper's streaming dataflow (DESIGN.md §2):
   serves any epilogue scaling (HFlex — the hardware reads α/β from
   registers, it is not re-synthesized per scaling).
 
-Two gather strategies for B rows:
-
-* ``onehot``  — gather as a second one-hot matmul: the strategy that
-  lowers through Mosaic, so the TPU path uses it.  Once per non-empty
-  grid step the window is transposed and split exactly into three
-  bfloat16 parts, ``x = hi + mid + lo`` (:func:`bf16_split3`), stacked
-  into one (3·TN × K0) operand.  Each trip contracts it against a
-  bfloat16 (K0 × L) one-hot in ONE default-precision MXU pass with
-  float32 accumulation: every output is ``1 · part`` plus exact zeros, so
-  ``hi + mid + lo`` summed in float32 is ``bwin[c]`` bit for bit.
-* ``gather``  — vector row-gather ``bwin[c]`` from the VMEM window.  Mosaic
-  refuses this gather, so it runs in interpret mode only.
+B rows are gathered as a second one-hot matmul, the form that lowers
+through Mosaic (a vector row gather does not).  Once per non-empty grid
+step the window is transposed and split exactly into three bfloat16
+parts, ``x = hi + mid + lo`` (:func:`bf16_split3`), stacked into one
+(3·TN × K0) operand.  Each trip contracts it against a bfloat16 (K0 × L)
+one-hot in ONE default-precision MXU pass with float32 accumulation:
+every output is ``1 · part`` plus exact zeros, so ``hi + mid + lo``
+summed in float32 is ``bwin[c]`` bit for bit.
 
 The scatter matmul runs at ``Precision.HIGHEST``: it forms the real
 products ``v · b`` of float32 operands, which a default-precision TPU
@@ -103,34 +99,28 @@ def bf16_split3(x):
     return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, lo))
 
 
-def _scatter_trip(acc, v, c, r, bwin, *, tm: int, gather: str):
+def _scatter_trip(acc, v, c, r, bwin, *, tm: int):
     """One trip of ``L`` packed non-zeros: ``acc[r] += v * bwin[c]``.
 
-    ``v``/``c``/``r`` are ``(1, L)`` rows.  For ``onehot``, ``bwin`` is
-    the stacked (3·W, K0) bfloat16 split of the window's transpose and the
-    gathered rows come out transposed, (W, L); for ``gather`` it is the
-    (K0, W) window itself.  The row scatter is a (TM × L) one-hot matmul
-    against them with the values folded into the one-hot, so every
-    product ``v * b`` is formed once, exactly as the flat reference forms
-    it."""
+    ``v``/``c``/``r`` are ``(1, L)`` rows and ``bwin`` is the stacked
+    (3·W, K0) bfloat16 split of the window's transpose, so the gathered
+    rows come out transposed, (W, L).  The row scatter is a (TM × L)
+    one-hot matmul against them with the values folded into the one-hot,
+    so every product ``v * b`` is formed once, exactly as the flat
+    reference forms it."""
     lanes = v.shape[-1]
-    if gather == "onehot":
-        k0 = bwin.shape[1]
-        w = bwin.shape[0] // 3
-        oh_c = (jax.lax.broadcasted_iota(jnp.int32, (k0, lanes), 0)
-                == c).astype(jnp.bfloat16)                  # (K0, L)
-        parts = jnp.dot(bwin, oh_c,
-                        preferred_element_type=jnp.float32)  # (3W, L)
-        brows = (parts[:w] + parts[w:2 * w]) + parts[2 * w:]  # (W, L)
-        dims = (((1,), (1,)), ((), ()))
-    else:
-        brows = bwin[c[0], :]                               # (L, W)
-        dims = (((1,), (0,)), ((), ()))
+    k0 = bwin.shape[1]
+    w = bwin.shape[0] // 3
+    oh_c = (jax.lax.broadcasted_iota(jnp.int32, (k0, lanes), 0)
+            == c).astype(jnp.bfloat16)                      # (K0, L)
+    parts = jnp.dot(bwin, oh_c,
+                    preferred_element_type=jnp.float32)     # (3W, L)
+    brows = (parts[:w] + parts[w:2 * w]) + parts[2 * w:]    # (W, L)
     oh_rv = jnp.where(
         jax.lax.broadcasted_iota(jnp.int32, (tm, lanes), 0) == r,
         v.astype(jnp.float32), 0.0)                         # (TM, L)
     return acc + jax.lax.dot_general(
-        oh_rv, brows, dims,
+        oh_rv, brows, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32, precision=_HI)
 
 
@@ -150,7 +140,6 @@ def _kernel(
     mb: int,
     nw: int,
     tile_rows: int,
-    gather: str,
     batched: bool,
     accumulate: bool,
 ):
@@ -189,15 +178,14 @@ def _kernel(
     def _process_window():
         lanes = vals_ref.shape[-1]
         bwin = _tile(b_ref).astype(jnp.float32)  # (K0, TN) window in VMEM
-        if gather == "onehot":
-            # the gather's MXU operand, once per step: (3·TN, K0) bf16
-            bwin = jnp.concatenate(bf16_split3(bwin.T), axis=0)
+        # the gather's MXU operand, once per step: (3·TN, K0) bf16
+        bwin = jnp.concatenate(bf16_split3(bwin.T), axis=0)
         lead = 3 if batched else 2
 
         def body(i, acc):
             v, c, r = (_slab_row(ref, i, lead, tile_rows)
                        for ref in (vals_ref, cols_ref, rows_ref))
-            return _scatter_trip(acc, v, c, r, bwin, tm=tm, gather=gather)
+            return _scatter_trip(acc, v, c, r, bwin, tm=tm)
 
         trips = (count + lanes - 1) // lanes
         acc_ref[...] = jax.lax.fori_loop(0, trips, body, acc_ref[...])
@@ -248,7 +236,7 @@ def _slab_specs(batched: bool, r: int, lanes: int):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("tm", "k0", "tn", "gather", "interpret", "accumulate"),
+    static_argnames=("tm", "k0", "tn", "interpret", "accumulate"),
 )
 def sextans_spmm_pallas(
     vals: jax.Array,      # ([G,] MB, NW, R, L) f32
@@ -263,7 +251,6 @@ def sextans_spmm_pallas(
     tm: int,
     k0: int,
     tn: int = 128,
-    gather: str = "gather",
     interpret: Optional[bool] = None,
     accumulate: bool = False,
 ) -> jax.Array:
@@ -318,8 +305,8 @@ def sextans_spmm_pallas(
                            memory_space=pltpu.SMEM)
 
     kern = functools.partial(
-        _kernel, tm=tm, mb=mb, nw=nw, tile_rows=min(8, r), gather=gather,
-        batched=batched, accumulate=accumulate,
+        _kernel, tm=tm, mb=mb, nw=nw, tile_rows=min(8, r), batched=batched,
+        accumulate=accumulate,
     )
     out_dtype = jnp.float32 if accumulate else b.dtype
     if batched:

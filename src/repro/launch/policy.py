@@ -23,7 +23,7 @@ the wrong call:
       cycles(merged union) < sum_i cycles(split group i),
 
   each side including ``dispatch_overhead_cycles`` per dispatch, and the
-  padded-slot walk of the flat (``jnp``-family) backends charged via
+  padded-slot walk of the flat ``jnp`` backend charged via
   ``packed_event_cycles(..., lw=bucket)``.  No ad-hoc thresholds: a
   near-miss pair merges when overhead dominates and splits when padding
   waste dominates, and the contract tests pin both directions.
@@ -61,23 +61,15 @@ import numpy as np
 
 from repro.core.perfmodel import Platform, packed_event_cycles
 
-__all__ = ["ABVEC_BACKENDS", "FLAT_BACKENDS", "GroupSketch", "MergeCluster",
-           "MergePolicy", "family_key"]
+__all__ = ["ABVEC_BACKENDS", "GroupSketch", "MergeCluster", "MergePolicy",
+           "family_key"]
 
 #: Backends whose batched (group) execution path applies ``(alpha, beta)``
 #: as a per-member ``(G,)`` vector, bit-identically to the member's scalar
 #: epilogue (same FMA, same operand order — see the SMEM ``(G, 2)`` block
 #: of the Pallas kernels and ``_ab_expand`` on the jnp paths).  The fold
 #: gate: only these may drop the epilogue scalars from the group key.
-ABVEC_BACKENDS = frozenset(
-    {"pallas", "pallas_onehot", "jnp", "spmv", "spmv_jnp"})
-
-#: Backends that walk every padded LW slot (the flat segment-sum paths):
-#: their cost must be charged at the full bucket width
-#: (``packed_event_cycles(..., lw=bucket)``), not the true per-window
-#: counts.  The Pallas kernels walk exactly ``q`` chunk trips, so LW
-#: padding is free for them and they price at the true ``q``.
-FLAT_BACKENDS = frozenset({"jnp", "spmv_jnp"})
+ABVEC_BACKENDS = frozenset({"pallas", "jnp"})
 
 
 def family_key(key: Tuple) -> Tuple:
@@ -110,7 +102,10 @@ class GroupSketch:
     BSR, the pseudo-``q`` ``(G, 1, 1)`` of true block counts — the
     pointer walk is the block walk); ``lw`` is the group's padded bucket
     width (slab LW / BSR block-count bucket) and ``flat`` says whether
-    the resolved backend walks padded slots (``FLAT_BACKENDS``).
+    the resolved backend walks every padded slot: the flat segment-sum
+    path (``jnp``) is charged at the full bucket width
+    (``packed_event_cycles(..., lw=bucket)``), while the kernel walks
+    exactly ``q`` chunk trips and prices at the true ``q``.
     """
 
     key: Tuple

@@ -66,9 +66,9 @@ import numpy as np
 from repro.core.async_pipeline import PackExecutePipeline, SpmmFuture
 from repro.core.engine import SextansEngine
 from repro.core.sparse import SparseMatrix
-from repro.launch.policy import FLAT_BACKENDS, GroupSketch, MergePolicy
-from repro.sparse_api import (SKINNY_BACKENDS, Format, SparseTensor,
-                              bucket_block_count, repad_lw, resolve_backend,
+from repro.launch.policy import GroupSketch, MergePolicy
+from repro.sparse_api import (Format, SparseTensor, bucket_block_count,
+                              is_skinny, repad_lw, resolve_backend,
                               stack_bsr, stack_hflex)
 
 __all__ = ["SpmmRequest", "SpmmFuture", "SpmmScheduler", "MergePolicy",
@@ -259,8 +259,8 @@ class SpmmScheduler:
       window-chunk dispatches issued (summed over column tiles), the
       column-tile high-water, and the device working-set high-water of any
       streamed request;
-    * ``skinny_dispatches`` — dispatches (singleton or group) that
-      resolved to the skinny-N SpMV lane (``SKINNY_BACKENDS``);
+    * ``skinny_dispatches`` — SpMV-shaped dispatches (singleton or
+      group), as ``repro.sparse_api.is_skinny`` decides;
     * ``preprocess_s`` vs ``wall_s`` — pack() time separated from
       execution, the paper's preprocessing/execution split;
     * ``overlap_s`` / ``pack_stall_s`` — async mode: pack time hidden
@@ -608,7 +608,7 @@ class SpmmScheduler:
             q = np.stack([np.asarray(e.tensor.data.q) for e in members])
             lw, k0 = geo[2], geo[4]
         return GroupSketch(key=key, q=q, n=n_b, k0=k0, lw=lw,
-                           flat=backend in FLAT_BACKENDS)
+                           flat=backend == "jnp")
 
     def _merge_groups(self, groups: Dict, ctr: _FlushCounters) -> Dict:
         """Near-miss merge pass: let the policy re-price this flush's
@@ -661,9 +661,10 @@ class SpmmScheduler:
         ctr.build_warm_s = after.plan_build_warm_s - before.plan_build_warm_s
 
     def _count_skinny(self, tensor, b, ctr: _FlushCounters) -> None:
-        """Bump ``ctr.skinny`` when this dispatch resolves to the SpMV
-        lane — the same resolution (operand included) the engine performs."""
-        if resolve_backend(self.engine.impl, tensor, b) in SKINNY_BACKENDS:
+        """Bump ``ctr.skinny`` when this dispatch is SpMV-shaped — the same
+        resolution (operand included) and test the engine performs."""
+        if is_skinny(tensor, resolve_backend(self.engine.impl, tensor, b),
+                     np.shape(b)[-1]):
             ctr.skinny += 1
 
     def _dispatch_single(self, e: _Entry, results: Dict,
@@ -1160,8 +1161,8 @@ def serve_spmm_requests(
     the streaming lane (``streamed``, ``window_dispatches``, ``n_tiles``,
     ``peak_payload_bytes`` — ``n_tile`` forces/overrides the column-tile
     width of streamed requests), the skinny-N SpMV lane
-    (``skinny_dispatches`` — dispatches that resolved to a
-    ``SKINNY_BACKENDS`` member), the pipeline overlap (``overlap_s``,
+    (``skinny_dispatches`` — SpMV-shaped dispatches, see
+    ``repro.sparse_api.is_skinny``), the pipeline overlap (``overlap_s``,
     ``pack_hidden_fraction`` — zero outside async mode) and both
     ``gflops`` (wall clock including ``pack()`` preprocessing) and
     ``compute_gflops`` (wall − *non-hidden* preprocessing — the paper

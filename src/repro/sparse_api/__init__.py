@@ -9,8 +9,10 @@ One differentiable, format-agnostic SpMM:
     >>> g = jax.grad(lambda v: sp.spmm(A.with_values(v), b).sum())(A.values)
 
 Formats (``Format.HFLEX`` slabs, ``Format.BSR`` tiles) and execution
-backends (``pallas``, ``pallas_onehot``, ``jnp``, ``auto``) are orthogonal;
-new ones plug in through :func:`register_backend`.
+backends are orthogonal: ``pallas`` runs the format's chip kernel (on
+HFLEX at a column tile :func:`hflex_lane` takes from N), ``jnp`` is the
+XLA reference, and ``auto`` picks one of them; new ones plug in through
+:func:`register_backend`.
 
 Serving hot loops should prepare a :func:`plan` (an :class:`SpmmPlan`):
 backend resolution, index precompute and executable compilation happen
@@ -44,11 +46,13 @@ from .autotune import (
 )
 from .backends import (
     BACKEND_STATS,
-    SKINNY_BACKENDS,
     SKINNY_N_MAX,
     Backend,
     StreamOps,
     get_backend,
+    hflex_lane,
+    is_kernel,
+    is_skinny,
     list_backends,
     register_backend,
     resolve_backend,
@@ -121,9 +125,11 @@ __all__ = [
     "set_auto_policy",
     "BACKEND_STATS",
     "SKINNY_N_MAX",
-    "SKINNY_BACKENDS",
     "skinny_n_max",
     "set_skinny_n_max",
+    "hflex_lane",
+    "is_skinny",
+    "is_kernel",
     "AUTOTUNE_MODES",
     "TUNE_SCHEMA",
     "TUNE_STATS",

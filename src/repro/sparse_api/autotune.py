@@ -4,8 +4,8 @@ The paper's HFlex property makes execution geometry a *runtime* parameter
 — which also makes it tunable at runtime.  This module closes the loop:
 
 * a **candidate enumerator** over the execution-side knobs — backend
-  (``pallas`` / ``pallas_onehot`` / ``jnp`` / ``spmv`` / ``spmv_jnp``),
-  streaming ``window_chunk`` / ``n_tile``, and the skinny-N routing
+  (``jnp``, or ``pallas`` at the 128-column lane or, for N ≤ 32, the
+  narrow lane), streaming ``window_chunk`` / ``n_tile``, and the skinny-N
   threshold — pruned by ranking with the :mod:`repro.core.perfmodel`
   event-cycle model and then measured best-of-N
   (``perf_counter`` + ``block_until_ready``);
@@ -49,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hflex import bucket_geometry
-from repro.core.partition import SextansParams, cdiv
+from repro.core.partition import SextansParams
 
 from . import backends as _bk
 from .tensor import Format, SparseTensor, bucket_block_count
@@ -73,7 +73,8 @@ __all__ = [
 #: Bump when the record layout (or anything that invalidates stored
 #: decisions, e.g. the measurement protocol) changes — a DB written by a
 #: different schema is ignored wholesale and re-tuned, never migrated.
-TUNE_SCHEMA = 1
+#: 2: backends are ``pallas``/``jnp`` only, the kernel's lane is ``tn``.
+TUNE_SCHEMA = 2
 
 AUTOTUNE_MODES = ("off", "cached", "measure")
 
@@ -300,11 +301,14 @@ def skinny_key(platform: Optional[str] = None, dtype=jnp.float32) -> str:
 
 @dataclasses.dataclass(frozen=True)
 class Candidate:
-    """One point in the execution-knob space the tuner can measure."""
+    """One point in the execution-knob space the tuner can measure.
+    ``tn`` pins the kernel's column tile (None: the backend's own
+    choice)."""
 
     backend: str
     window_chunk: Optional[int] = None
     n_tile: Optional[int] = None
+    tn: Optional[int] = None
 
 
 # Static backend priors multiplying the event-cycle rank: off-TPU the
@@ -321,7 +325,7 @@ DISPATCH_OVERHEAD_CYCLES = 25_000.0
 
 def _backend_factor(name: str, platform: str) -> float:
     f = 1.0
-    if platform != "tpu" and name in ("pallas", "pallas_onehot", "spmv"):
+    if platform != "tpu" and _bk.is_kernel(name):
         f *= _INTERPRET_PENALTY
     return f
 
@@ -355,16 +359,15 @@ def enumerate_candidates(a: SparseTensor, n: int, *, dtype=jnp.float32,
     caller prunes with :func:`rank_candidates` before measuring.
     """
     opts = dict(opts or {})
-    if a.format is Format.BSR:
-        names = ["jnp", "pallas"]
-    elif a.batch is not None:
-        names = ["jnp", "pallas", "pallas_onehot"]
+    if a.format is Format.BSR or opts.get("tn") is not None:
+        lanes = [("jnp", None), ("pallas", None)]     # lane pinned
     else:
-        names = ["jnp", "spmv_jnp", "pallas", "pallas_onehot"]
-        if int(n) <= 32:            # spmv pads N up to its stripe — cap it
-            names.append("spmv")
+        lanes = [("jnp", None), ("pallas", _bk.hflex_lane(n, narrow=False))]
+        if a.batch is None and int(n) <= 32:
+            # the narrow lane pads N up to a multiple of 8 — cap it
+            lanes.append(("pallas", _bk.hflex_lane(n, narrow=True)))
     if not stream:
-        return [Candidate(b) for b in names]
+        return [Candidate(b, tn=tn) for b, tn in lanes]
 
     from .plan import _per_window_bytes  # lazy: plan imports this module
 
@@ -372,18 +375,16 @@ def enumerate_candidates(a: SparseTensor, n: int, *, dtype=jnp.float32,
     itemsize = np.dtype(dtype).itemsize
     m = a.shape[0]
     out: List[Candidate] = []
-    for name in names:
-        try:
-            be = _bk.get_backend(name)
-        except (KeyError, ValueError):
-            continue
+    for name, tn in lanes:
+        be = _bk.get_backend(name)
         if be.stream is None or Format.HFLEX not in be.formats:
             continue
+        topts = dict(opts, tn=tn) if tn is not None else opts
         ntiles = [int(n_tile)] if n_tile is not None else _pow2_down(int(n))
         for ntile in ntiles:
             try:
                 acc_shape = jax.eval_shape(
-                    lambda s=be.stream, w=ntile: s.init(a, w, **opts)).shape
+                    lambda s=be.stream, w=ntile: s.init(a, w, **topts)).shape
             except Exception:
                 break                       # backend can't stream this shape
             acc_bytes = int(np.prod(acc_shape)) * 4
@@ -395,7 +396,7 @@ def enumerate_candidates(a: SparseTensor, n: int, *, dtype=jnp.float32,
                 peak = 2 * wc * per_w + acc_bytes + out_bytes
                 if device_bytes is not None and peak > int(device_bytes):
                     continue
-                out.append(Candidate(name, wc, ntile))
+                out.append(Candidate(name, wc, ntile, tn))
     return out
 
 
@@ -492,11 +493,12 @@ def tune_plan(a: SparseTensor, n: int, *, dtype=jnp.float32,
     alpha, beta = 1.25, -0.5
 
     def _build(cand: Candidate):
+        copts = dict(opts, tn=cand.tn) if cand.tn is not None else opts
         return _plan(a, n, backend=cand.backend, dtype=dtype,
                      autotune="off", stream=stream or None,
                      device_bytes=device_bytes if stream else None,
                      window_chunk=cand.window_chunk if stream else None,
-                     n_tile=cand.n_tile if stream else None, **opts)
+                     n_tile=cand.n_tile if stream else None, **copts)
 
     # the reference: exactly what the caller would run untuned
     default_pl = _plan(a, n, backend=backend, dtype=dtype, autotune="off",
@@ -508,7 +510,8 @@ def tune_plan(a: SparseTensor, n: int, *, dtype=jnp.float32,
         default_pl.run(b, c, alpha, beta)))
     default_cand = Candidate(default_pl.backend,
                              getattr(default_pl, "window_chunk", None),
-                             getattr(default_pl, "n_tile", None))
+                             getattr(default_pl, "n_tile", None),
+                             default_pl.opts.get("tn"))
 
     cands = enumerate_candidates(a, n, dtype=dtype, stream=stream,
                                  device_bytes=device_bytes,
@@ -537,8 +540,9 @@ def tune_plan(a: SparseTensor, n: int, *, dtype=jnp.float32,
             _bump("rejected")               # bit-identity guard: reject
             continue
         us = _best_of(lambda p=pl: p.run(b, c, alpha, beta), repeats) * 1e6
-        row = {"backend": cand.backend, "window_chunk": cand.window_chunk,
-               "n_tile": cand.n_tile, "us": us}
+        row = {"backend": cand.backend, "tn": cand.tn,
+               "window_chunk": cand.window_chunk, "n_tile": cand.n_tile,
+               "us": us}
         measured.append(row)
         if cand == default_cand:
             default_us = us
@@ -553,6 +557,7 @@ def tune_plan(a: SparseTensor, n: int, *, dtype=jnp.float32,
         "schema": TUNE_SCHEMA,
         "platform": platform,
         "backend": win["backend"],
+        "tn": win["tn"],
         "window_chunk": win["window_chunk"],
         "n_tile": win["n_tile"],
         "stream": bool(stream),
@@ -578,20 +583,23 @@ def resolve_plan_knobs(a: SparseTensor, n: int, *, dtype, mode: str,
                        n_tile: Optional[int],
                        opts: Optional[Dict[str, Any]] = None,
                        group: Optional[int] = None
-                       ) -> Tuple[str, Optional[int], Optional[int], bool]:
-    """``plan()``'s tuning hook: returns (backend, window_chunk, n_tile,
-    tuned).
+                       ) -> Tuple[str, Dict[str, Any], Optional[int],
+                                  Optional[int], bool]:
+    """``plan()``'s tuning hook: returns (backend, opts, window_chunk,
+    n_tile, tuned).
 
-    Only knobs the caller left open are ever overridden: ``backend`` when
-    ``"auto"``, ``window_chunk``/``n_tile`` when None on a streaming plan.
+    Only knobs the caller left open are ever overridden: ``backend`` (and
+    with it the kernel's lane, ``opts["tn"]``) when ``"auto"``,
+    ``window_chunk``/``n_tile`` when None on a streaming plan.
     ``"cached"`` applies a stored decision or does nothing; ``"measure"``
     tunes + stores on a miss (failures fall back to the heuristics with a
     warning — tuning must never take serving down).
     """
+    opts = dict(opts or {})
     tunable_backend = backend == "auto"
     tunable_geo = bool(stream) and (window_chunk is None or n_tile is None)
     if mode == "off" or not (tunable_backend or tunable_geo):
-        return backend, window_chunk, n_tile, False
+        return backend, opts, window_chunk, n_tile, False
     db = get_db()
     key = tune_key(a, n, dtype=dtype, group=group, stream=bool(stream),
                    device_bytes=device_bytes)
@@ -605,17 +613,19 @@ def resolve_plan_knobs(a: SparseTensor, n: int, *, dtype, mode: str,
         except Exception as e:  # noqa: BLE001 — degrade, don't take serving down
             warnings.warn(f"autotune measurement failed ({e!r}); using "
                           "default heuristics", stacklevel=3)
-            return backend, window_chunk, n_tile, False
+            return backend, opts, window_chunk, n_tile, False
     if rec is None:
-        return backend, window_chunk, n_tile, False
+        return backend, opts, window_chunk, n_tile, False
     if tunable_backend and rec.get("backend"):
         backend = str(rec["backend"])
+        if rec.get("tn") and opts.get("tn") is None:
+            opts["tn"] = int(rec["tn"])
     if stream:
         if window_chunk is None and rec.get("window_chunk"):
             window_chunk = int(rec["window_chunk"])
         if n_tile is None and rec.get("n_tile"):
             n_tile = int(rec["n_tile"])
-    return backend, window_chunk, n_tile, True
+    return backend, opts, window_chunk, n_tile, True
 
 
 # ---------------------------------------------------------------------------
@@ -630,21 +640,22 @@ def tune_skinny_threshold(a: SparseTensor, *, widths: Optional[List[int]] = None
     """Measure the profitable skinny-lane boundary on this platform.
 
     For each candidate width (default: around the built-in
-    ``SKINNY_N_MAX``), times the skinny lane (``spmv`` on TPU /
-    ``spmv_jnp`` elsewhere) against the platform's tall-N default on the
-    given representative matrix; the threshold is the largest width whose
-    lane run is at least as fast (within 2% noise) with every smaller
-    width also winning — Serpens' observation that the lane's profitable
-    region is workload-dependent, made a measurement.  Stored platform-
-    wide under :func:`skinny_key`; ``apply=True`` pushes it into the auto
-    policy via :func:`apply_skinny_from_db`.
+    ``SKINNY_N_MAX``), times the narrow lane (``pallas`` at
+    ``hflex_lane(n, narrow=True)`` on TPU, ``jnp`` elsewhere) against the
+    platform's tall-N default (``pallas`` at the 128-column lane on TPU,
+    ``jnp`` elsewhere) on the given representative matrix; the threshold
+    is the largest width whose lane run is at least as fast (within 2%
+    noise) with every smaller width also winning — Serpens' observation
+    that the lane's profitable region is workload-dependent, made a
+    measurement.  Stored platform-wide under :func:`skinny_key`;
+    ``apply=True`` pushes it into the policy via
+    :func:`apply_skinny_from_db`.
     """
     from .plan import plan as _plan
 
     db = db or get_db()
     platform = jax.default_backend()
-    lane = "spmv" if platform == "tpu" else "spmv_jnp"
-    tall = "pallas" if platform == "tpu" else "jnp"
+    backend = "pallas" if platform == "tpu" else "jnp"
     base = _bk.SKINNY_N_MAX
     widths = sorted(set(widths or (max(1, base // 2), base, 2 * base)))
     np_dtype = np.dtype(jnp.dtype(dtype).name)
@@ -655,8 +666,10 @@ def tune_skinny_threshold(a: SparseTensor, *, widths: Optional[List[int]] = None
     for w in widths:
         b = rng.standard_normal((k, w)).astype(np_dtype)
         try:
-            pl_lane = _plan(a, w, backend=lane, dtype=dtype, autotune="off")
-            pl_tall = _plan(a, w, backend=tall, dtype=dtype, autotune="off")
+            pl_lane, pl_tall = (
+                _plan(a, w, backend=backend, dtype=dtype, autotune="off",
+                      tn=_bk.hflex_lane(w, narrow=narrow))
+                for narrow in (True, False))
         except Exception:
             break
         t_lane = _best_of(lambda p=pl_lane, x=b: p.run(x), repeats)
@@ -670,7 +683,7 @@ def tune_skinny_threshold(a: SparseTensor, *, widths: Optional[List[int]] = None
         "schema": TUNE_SCHEMA,
         "platform": platform,
         "skinny_n_max": int(threshold),
-        "lane": lane,
+        "backend": backend,
         "widths": rows,
     })
     _bump("measured")

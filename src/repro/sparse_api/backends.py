@@ -4,35 +4,27 @@ A *backend* is a callable ``fn(A, b, c, alpha, beta, **opts) -> jax.Array``
 computing ``alpha * A @ b + beta * c`` on padded-consistent operands, where
 ``alpha``/``beta`` are traced scalars (no recompile per value — HFlex).
 Backends declare which :class:`Format` s they support and are registered by
-name:
+name.  Two are built in, one chip kernel per format and its reference:
 
-* ``pallas``        — Sextans streaming kernel (HFLEX) / BSR tile kernel,
-                      vector row-gather (HFLEX: interpret mode only —
-                      Mosaic refuses the gather).
-* ``pallas_onehot`` — Sextans kernel with pure-MXU one-hot gather (the
-                      HFLEX kernel that lowers on TPU; HFLEX only).
-* ``jnp``           — segment-sum / einsum XLA path; also the CPU
-                      production path and the autodiff reference.
-* ``spmv``          — skinny-N (N ≤ ``SKINNY_N_MAX``) vector lane: the
-                      Sextans kernel with the dense operand padded to a
-                      few lanes instead of TN = 128, so the NT grid axis
-                      has one tile and each B window streams once
-                      (Serpens-style; HFLEX only; one-hot gather).
-* ``spmv_jnp``      — flat-jnp twin of the skinny lane (bit-identical to
-                      ``jnp``; the off-TPU production path for SpMV shapes).
-* ``auto``          — resolves to one of the above from platform, format,
-                      density and the dense-operand width N (override with
-                      :func:`set_auto_policy`).
+* ``pallas`` — the format's Pallas kernel: the Sextans streaming kernel
+  with its one-hot MXU gather (HFLEX) or the BSR tile kernel (single,
+  batched and ragged modes).  On HFLEX its column tile comes from the
+  dense width N (:func:`hflex_lane`): a narrow lane of 8·⌈N/8⌉ columns
+  for SpMV-shaped calls (N ≤ :func:`skinny_n_max`), else 128.
+* ``jnp``    — segment-sum / einsum XLA path: the reference, and the
+  off-TPU production and autodiff path.
+* ``auto``   — resolves to one of the above from platform, format and
+  density (override with :func:`set_auto_policy`).
 
-``register_backend`` is the extension point the ROADMAP's multi-workload
-north star needs: a Serpens-style SpMV/CSR or SpArch-style merge format
-plugs in as (new Format, new backend) without another API fork.
+This module is the one place that knows what the names mean: callers ask
+:func:`hflex_lane`, :func:`is_skinny` and :func:`is_kernel` instead of
+testing names.  ``register_backend`` stays the extension point: a new
+format plugs in as (new Format, new backend) without another API fork.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 import os
 import threading
 from typing import Callable, Dict, FrozenSet, List, Optional
@@ -61,17 +53,19 @@ __all__ = [
     "set_auto_policy",
     "BACKEND_STATS",
     "SKINNY_N_MAX",
-    "SKINNY_BACKENDS",
     "skinny_n_max",
     "set_skinny_n_max",
+    "hflex_lane",
+    "with_lane",
+    "is_skinny",
+    "is_kernel",
 ]
 
-# Default auto-policy skinny-N routing width: HFLEX requests with N at or
-# below the threshold go to the dedicated SpMV lane ("spmv" on TPU, its
-# flat-jnp twin elsewhere) — the paper's SNAP/SuiteSparse graph workloads
-# live at N = 1..8.  The *live* threshold is ``skinny_n_max()``: this
-# constant is only its lowest-precedence fallback (kept as a module
-# attribute for back-compat).
+# Default skinny-N width: HFLEX calls with N at or below it are SpMV-shaped
+# and the kernel runs them at the narrow lane (:func:`hflex_lane`) — the
+# paper's SNAP/SuiteSparse graph workloads live at N = 1..8.  The *live*
+# threshold is ``skinny_n_max()``: this constant is only its
+# lowest-precedence fallback.
 SKINNY_N_MAX = 8
 
 _SKINNY_OVERRIDE: Optional[int] = None
@@ -97,14 +91,59 @@ def skinny_n_max() -> int:
 
 
 def set_skinny_n_max(value: Optional[int]) -> None:
-    """Override the skinny-N routing threshold (``None`` restores the
-    env/default precedence chain).  ``0`` disables the skinny lane."""
+    """Override the skinny-N threshold (``None`` restores the
+    env/default precedence chain).  ``0`` disables the narrow lane."""
     global _SKINNY_OVERRIDE
     _SKINNY_OVERRIDE = None if value is None else max(0, int(value))
 
-# Backend names that constitute the skinny lane (engine/scheduler stats
-# count dispatches routed through them as ``skinny_dispatches``).
-SKINNY_BACKENDS = frozenset({"spmv", "spmv_jnp"})
+
+#: sublane multiple the narrow HFLEX lane pads N up to
+_NARROW_LANE = 8
+#: column tile of the HFLEX kernel above the skinny threshold
+_WIDE_LANE = 128
+
+
+def hflex_lane(n: int, narrow: Optional[bool] = None) -> int:
+    """Column tile (TN) of the HFLEX kernel for dense width ``n``.
+
+    SpMV-shaped calls (``n`` ≤ :func:`skinny_n_max`, unless ``narrow``
+    says otherwise) take the narrow lane, ``8·⌈n/8⌉`` columns: the NT
+    grid axis has one tile and each B window streams once.  Wider calls
+    take 128-column tiles.  An explicit ``tn=`` backend option overrides
+    this."""
+    if narrow is None:
+        narrow = n <= skinny_n_max()
+    if narrow:
+        return cdiv(n, _NARROW_LANE) * _NARROW_LANE
+    return _WIDE_LANE
+
+
+def is_kernel(backend: str) -> bool:
+    """True when ``backend`` runs the format's Pallas kernel (which XLA
+    cannot partition, and which interprets off-TPU)."""
+    return backend == "pallas"
+
+
+def with_lane(a: SparseTensor, backend: str, n: int,
+              opts: Dict) -> Dict:
+    """``opts`` with the HFLEX kernel's column tile pinned for width ``n``
+    (``tn = hflex_lane(n)``, unless the caller set one), so an executable
+    key records the lane the trace will use."""
+    if (a.format is Format.HFLEX and is_kernel(backend)
+            and opts.get("tn") is None):
+        return dict(opts, tn=hflex_lane(n))
+    return dict(opts)
+
+
+def is_skinny(a: SparseTensor, backend: str, n: int) -> bool:
+    """Whether a dispatch of ``a`` at dense width ``n`` on ``backend`` is
+    SpMV-shaped: an HFLEX matrix, a built-in backend, and ``n`` ≤
+    :func:`skinny_n_max` (the kernel's narrow lane; ``jnp`` serves every
+    width with one body).  Engine and scheduler stats count these as
+    ``skinny_dispatches``."""
+    return (a.format is Format.HFLEX and backend in ("pallas", "jnp")
+            and n <= skinny_n_max())
+
 
 # Incremented once per *trace* of a backend body (i.e. per compiled
 # executable, not per call) — the JAX analogue of the paper counting
@@ -238,45 +277,26 @@ def list_backends() -> List[str]:
     return sorted(_REGISTRY)
 
 
-def _operand_width(b) -> Optional[int]:
-    """Trailing (column) width of a dense operand, or None when unknowable.
-
-    Accepts arrays, ShapeDtypeStructs and numpy operands; a 1-D ``b`` (the
-    ``A @ v`` matvec path reshapes it later) counts as width 1.
-    """
-    shape = getattr(b, "shape", None)
-    if shape is None or len(shape) == 0:
-        return None
-    return 1 if len(shape) == 1 else int(shape[-1])
-
-
 def _default_auto_policy(a: SparseTensor, b, platform: Optional[str] = None) -> str:
-    """Pick a backend from platform / format / density / dense width N.
+    """Pick a backend from platform / format / density.
 
-    * HFLEX requests whose dense operand is skinny (N ≤ the tunable
-      :func:`skinny_n_max` threshold) are SpMV-shaped: they take the
-      dedicated vector lane — ``spmv`` on TPU, its flat-jnp twin
-      elsewhere (unless density already rules the slab format out,
-      below);
     * off-TPU the Pallas kernels run in interpret mode — the XLA ``jnp``
       path is the production one;
-    * on TPU, BSR always goes to the tile kernel and HFLEX to the one-hot
-      kernel (the vector gather of ``pallas`` does not lower);
-    * dense-ish unstructured matrices (density > 0.25) blow up slab padding,
-      so they fall back to the XLA path too.
+    * on TPU, BSR always goes to the tile kernel (``pallas``);
+    * on TPU, HFLEX goes to the one-hot kernel (``pallas``) unless the
+      matrix is dense-ish (density > 0.25 blows up slab padding), which
+      falls back to ``jnp``.
+
+    The dense width N does not choose the backend: it chooses the
+    kernel's lane (:func:`hflex_lane`).  ``b`` is accepted (and may be
+    None) so custom policies can inspect the operand.
     """
     platform = platform or jax.default_backend()
-    n = _operand_width(b)
-    if (a.format is Format.HFLEX and n is not None and n <= skinny_n_max()
-            and not (platform == "tpu" and a.density > 0.25)):
-        return "spmv" if platform == "tpu" else "spmv_jnp"
     if platform != "tpu":
         return "jnp"
-    if a.format is Format.BSR:
+    if a.format is Format.BSR or a.density <= 0.25:
         return "pallas"
-    if a.density > 0.25:
-        return "jnp"
-    return "pallas_onehot"
+    return "jnp"
 
 
 _AUTO_POLICY = _default_auto_policy
@@ -415,10 +435,11 @@ def _hflex_jnp(a: SparseTensor, b, c, alpha, beta):
                             b, c, alpha, beta, d.m)
 
 
-def _hflex_pallas(a: SparseTensor, b, c, alpha, beta, *, gather, tn, interpret):
+def _hflex_pallas(a: SparseTensor, b, c, alpha, beta, *, tn, interpret):
     d = a.data
     m, k, tm, k0, mb, nw = d.m, d.k, d.tm, d.k0, d.mb, d.nw
     n = b.shape[-1]
+    tn = tn or hflex_lane(n)
     npad = cdiv(n, tn) * tn
     lead_pad = ((0, 0),) if d.batch is not None else ()
     bp = jnp.pad(b, (*lead_pad, (0, nw * k0 - k), (0, npad - n)))
@@ -427,21 +448,11 @@ def _hflex_pallas(a: SparseTensor, b, c, alpha, beta, *, gather, tn, interpret):
         cp = _permute_rows_fwd(cp, mb, tm)
     out = sextans_spmm_pallas(
         d.vals, d.cols, d.rows, d.q, bp, cp, alpha, beta,
-        tm=tm, k0=k0, tn=tn, gather=gather, interpret=interpret,
+        tm=tm, k0=k0, tn=tn, interpret=interpret,
     )
     if d.interleaved:
         out = _permute_rows_inv(out, mb, tm)
     return out[..., :m, :n]
-
-
-def _hflex_spmv(a: SparseTensor, b, c, alpha, beta, *, gather, nv, interpret):
-    """Skinny-N vector lane: the tall-N kernel with the dense operands
-    padded to ``nvp`` columns (a small multiple of ``nv``, NOT TN=128) and
-    one column tile — each B window streamed once, vector stripe
-    resident."""
-    nvp = cdiv(b.shape[-1], nv) * nv
-    return _hflex_pallas(a, b, c, alpha, beta, gather=gather, tn=nvp,
-                         interpret=interpret)
 
 
 # -- out-of-core streaming hooks (K0-window chunk accumulation) -------------
@@ -473,21 +484,23 @@ def _hflex_jnp_stream_collect(a: SparseTensor, acc, n: int, **_unused):
     return acc
 
 
-def _hflex_pallas_stream_init(a: SparseTensor, n: int, *, tn=128, **_unused):
+def _hflex_pallas_stream_init(a: SparseTensor, n: int, *, tn=None,
+                              **_unused):
     d = a.data
+    tn = tn or hflex_lane(n)
     npad = cdiv(n, tn) * tn
     return jnp.zeros((d.mb * d.tm, npad), jnp.float32)
 
 
 def _hflex_pallas_stream_step(a_chunk: SparseTensor, b_chunk, acc, *,
-                              gather="gather", tn=128, interpret=None,
-                              **_unused):
+                              tn=None, interpret=None, **_unused):
     """One accumulate-mode kernel launch over the chunk's NW grid.
 
     The carried acc stays in kernel layout (padded rows, interleave
     permutation) between dispatches; the kernel seeds its VMEM scratch from
     it and emits the raw f32 accumulator — the same add sequence a full-NW
-    launch performs, split at chunk boundaries.
+    launch performs, split at chunk boundaries.  ``b_chunk`` carries the
+    column tile's full width, so the lane is the one ``init`` chose.
     """
     d = a_chunk.data
     npad = acc.shape[-1]
@@ -495,7 +508,7 @@ def _hflex_pallas_stream_step(a_chunk: SparseTensor, b_chunk, acc, *,
     bp = jnp.pad(b_chunk, ((0, d.nw * d.k0 - kc), (0, npad - nc)))
     return sextans_spmm_pallas(
         d.vals, d.cols, d.rows, d.q, bp, acc,
-        tm=d.tm, k0=d.k0, tn=tn, gather=gather,
+        tm=d.tm, k0=d.k0, tn=tn or hflex_lane(nc),
         interpret=interpret, accumulate=True,
     )
 
@@ -507,28 +520,12 @@ def _hflex_pallas_stream_collect(a: SparseTensor, acc, n: int, **_unused):
     return acc[..., :a.shape[0], :n]
 
 
-def _hflex_spmv_stream_init(a: SparseTensor, n: int, *, nv=8, **_unused):
-    return _hflex_pallas_stream_init(a, n, tn=cdiv(n, nv) * nv)
-
-
-def _hflex_spmv_stream_step(a_chunk: SparseTensor, b_chunk, acc, *,
-                            gather="onehot", interpret=None, **_unused):
-    """Accumulate-mode launch of the skinny lane over the chunk's NW grid —
-    :func:`_hflex_pallas_stream_step` with one column tile as wide as the
-    carried accumulator."""
-    return _hflex_pallas_stream_step(a_chunk, b_chunk, acc, gather=gather,
-                                     tn=acc.shape[-1], interpret=interpret)
-
-
 _JNP_STREAM = StreamOps(init=_hflex_jnp_stream_init,
                         step=_hflex_jnp_stream_step,
                         collect=_hflex_jnp_stream_collect)
 _PALLAS_STREAM = StreamOps(init=_hflex_pallas_stream_init,
                            step=_hflex_pallas_stream_step,
                            collect=_hflex_pallas_stream_collect)
-_SPMV_STREAM = StreamOps(init=_hflex_spmv_stream_init,
-                         step=_hflex_spmv_stream_step,
-                         collect=_hflex_pallas_stream_collect)
 
 
 def _bsr_raw_jnp(a: SparseTensor, b):
@@ -567,6 +564,7 @@ def _bsr_pallas(a: SparseTensor, b, c, alpha, beta, *, tn, interpret):
     w = a.data
     m, k = a.shape
     n = b.shape[-1]
+    tn = tn or 128
     npad = cdiv(n, tn) * tn
     if a.batch is not None:
         xb = jnp.pad(b, ((0, 0), (0, w.k - k), (0, 0)))
@@ -638,65 +636,24 @@ def _backend_jnp(a, b, c, alpha, beta, **_unused):
     return _bsr_jnp(a, b, c, alpha, beta)
 
 
-def _backend_pallas(a, b, c, alpha, beta, *, gather="gather", tn=128,
-                    interpret=None, **_unused):
+def _backend_pallas(a, b, c, alpha, beta, *, tn=None, interpret=None,
+                    **_unused):
     bump_trace()
     if a.format is Format.HFLEX:
-        return _hflex_pallas(a, b, c, alpha, beta, gather=gather, tn=tn,
+        return _hflex_pallas(a, b, c, alpha, beta, tn=tn,
                              interpret=interpret)
     return _bsr_pallas(a, b, c, alpha, beta, tn=tn, interpret=interpret)
-
-
-def _backend_pallas_onehot(a, b, c, alpha, beta, *, tn=128, interpret=None,
-                           **_unused):
-    bump_trace()
-    return _hflex_pallas(a, b, c, alpha, beta, gather="onehot", tn=tn,
-                         interpret=interpret)
-
-
-def _backend_spmv(a, b, c, alpha, beta, *, gather="onehot", nv=8,
-                  interpret=None, **_unused):
-    bump_trace()
-    return _hflex_spmv(a, b, c, alpha, beta, gather=gather, nv=nv,
-                       interpret=interpret)
-
-
-def _backend_spmv_jnp(a, b, c, alpha, beta, **_unused):
-    # The flat segment-sum body needs no N padding at all, so it already IS
-    # the optimal skinny shape — register it under its own name so routing,
-    # plan keys and stats can distinguish the lane, while results stay
-    # bit-identical to "jnp" by construction (same function).
-    bump_trace()
-    return _hflex_jnp(a, b, c, alpha, beta)
 
 
 register_backend(
     "pallas", _backend_pallas,
     formats=(Format.HFLEX, Format.BSR),
-    description="Sextans streaming kernel / BSR tile kernel (row-gather; "
-                "HFLEX interprets only)",
+    description="the format's Pallas kernel: Sextans one-hot kernel "
+                "(HFLEX, lane from N) / BSR tile kernel",
     stream=_PALLAS_STREAM)
-register_backend(
-    "pallas_onehot", _backend_pallas_onehot,
-    formats=(Format.HFLEX,),
-    description="Sextans kernel, pure-MXU one-hot gather",
-    stream=StreamOps(
-        init=_hflex_pallas_stream_init,
-        step=functools.partial(_hflex_pallas_stream_step, gather="onehot"),
-        collect=_hflex_pallas_stream_collect))
 register_backend(
     "jnp", _backend_jnp,
     formats=(Format.HFLEX, Format.BSR),
-    description="XLA segment-sum/einsum path (CPU production + autodiff ref)",
-    stream=_JNP_STREAM)
-register_backend(
-    "spmv", _backend_spmv,
-    formats=(Format.HFLEX,),
-    description="skinny-N vector lane: Sextans kernel at a few lanes, "
-                "vector stripe resident per PE pass",
-    stream=_SPMV_STREAM)
-register_backend(
-    "spmv_jnp", _backend_spmv_jnp,
-    formats=(Format.HFLEX,),
-    description="skinny-N lane, flat-jnp twin (bit-identical to 'jnp')",
+    description="XLA segment-sum/einsum path (reference, CPU production, "
+                "autodiff)",
     stream=_JNP_STREAM)

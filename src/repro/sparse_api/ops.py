@@ -309,7 +309,7 @@ def spmm_streaming(
     name = _bk.resolve_backend(backend, a, b)
     if _bk.get_backend(name).stream is None:
         raise ValueError(f"backend {name!r} has no streaming hooks")
-    okey = tuple(sorted(opts.items()))
+    okey = tuple(sorted(_bk.with_lane(a, name, ntile, opts).items()))
     return _stream_jit(name, okey, wchunk, ntile, a, b, c_,
                        jnp.asarray(alpha, jnp.float32),
                        jnp.asarray(beta, jnp.float32))
@@ -379,5 +379,5 @@ def spmm(
             raise ValueError(
                 f"vector {nm} must have shape (G,)=({g},), got {x.shape}")
     name = _bk.resolve_backend(backend, a, b)
-    okey = tuple(sorted(opts.items()))
+    okey = tuple(sorted(_bk.with_lane(a, name, b.shape[-1], opts).items()))
     return _spmm_jit(name, okey, a, b, c_, alpha_, beta_)

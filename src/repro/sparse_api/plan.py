@@ -39,8 +39,8 @@ dispatch.  ``values=`` substitution stays per-group (shape
 is AOT-compiled with the engine's multi-chip shardings (A row-blocks over
 ``data``, B column-tiles over ``model`` — see
 ``SextansEngine.shard_specs``), so the sharded multi-chip path and the
-batched serving path run through one plan abstraction.  On the Pallas
-HFLEX backends XLA cannot partition the kernel call, so the plan pads the
+batched serving path run through one plan abstraction.  XLA cannot
+partition the HFLEX kernel's call, so on ``pallas`` the plan pads the
 row blocks to a multiple of the ``data`` axis, places each chip's share
 of the slabs on that chip, and runs the kernel per chip under
 ``shard_map``: no chip ever holds another chip's slabs.
@@ -105,11 +105,6 @@ PLAN_STATS: Dict[str, int] = {"exec_hits": 0, "exec_misses": 0,
 
 _EXEC_CACHE: Dict[Tuple, Any] = {}
 
-#: Pallas HFLEX backends a mesh plan runs row-split under shard_map, with
-#: the gather strategy each uses.
-_ROW_SPLIT = {"pallas": "gather", "pallas_onehot": "onehot",
-              "spmv": "onehot"}
-
 # Plans are built both by the owning thread and the async dispatch thread
 # (PackExecutePipeline serializes *dispatch*, but a sync engine call can
 # trace concurrently with it).  One lock makes hit/miss accounting exact
@@ -151,7 +146,7 @@ def _aot_compile(key: Tuple, fn, arg_shapes, in_shardings=None,
 def _check_bsr_tiles(a: SparseTensor, backend: str, opts: Dict[str, Any]):
     """Refuse a BSR tiling that the compiled TPU kernel cannot run."""
     d = a.data
-    if (backend == "pallas" and (d.tk % 128 or d.tf % 128)
+    if (_bk.is_kernel(backend) and (d.tk % 128 or d.tf % 128)
             and not resolve_interpret(opts.get("interpret"))):
         raise ValueError(
             f"BSR blocks of {d.tk}x{d.tf} cannot run on the TPU "
@@ -253,7 +248,7 @@ class SpmmPlan:
         self.group = a.batch
         self.mesh = mesh
         self.backend = _bk.resolve_backend(backend, a, n=self.n)
-        self.opts = dict(opts)
+        self.opts = _bk.with_lane(a, self.backend, self.n, opts)
         self.dtype = jnp.dtype(dtype)
         okey = tuple(sorted(self.opts.items()))
         if a.format is Format.BSR:
@@ -271,7 +266,7 @@ class SpmmPlan:
                 and mesh is None and g is None)
         self._flat = flat
         row_split = (mesh is not None and a.format is Format.HFLEX
-                     and self.backend in _ROW_SPLIT)
+                     and _bk.is_kernel(self.backend))
         if a.format is Format.HFLEX:
             d = a.data
             bucket = bucket_geometry(d.mb, d.nw, d.lw, n)
@@ -359,7 +354,7 @@ class SpmmPlan:
         self._ab_cache: Dict[Tuple[float, float], Tuple[Any, Any]] = {}
 
     def _init_row_split(self, mesh):
-        """Row-split mesh plan of a Pallas HFLEX backend (see
+        """Row-split mesh plan of the HFLEX kernel (see
         :func:`row_split_spmm`): places each chip's row blocks of the
         payload on that chip and returns the operand and result
         shardings."""
@@ -367,8 +362,7 @@ class SpmmPlan:
 
         d = self.a.data
         traced, mbp, slab_spec, q_spec = row_split_spmm(
-            d, mesh, self.m, self.k, self.n, self.group, self.backend,
-            self.opts)
+            d, mesh, self.m, self.k, self.n, self.group, self.opts)
 
         def place(x, spec, axis):
             pad = [(0, 0)] * x.ndim
@@ -554,8 +548,8 @@ class RaggedPlan:
 
 
 def row_split_spmm(d: PackedSpMM, mesh, m: int, k: int, n: int,
-                   group: Optional[int], backend: str, opts: Dict[str, Any]):
-    """The traced row-split SpMM of a Pallas HFLEX backend on ``mesh``.
+                   group: Optional[int], opts: Dict[str, Any]):
+    """The traced row-split SpMM of the HFLEX kernel on ``mesh``.
 
     Each chip runs the kernel on its own row blocks (``shard_map`` over
     ``data``) against the whole, replicated ``b``; the ``b``/``c``
@@ -575,13 +569,7 @@ def row_split_spmm(d: PackedSpMM, mesh, m: int, k: int, n: int,
     lead = (None,) if group is not None else ()
     slab_spec = P(*lead, "data", None, None, None)
     q_spec = P(*lead, "data", None)
-    gather = ("onehot" if backend == "pallas_onehot"
-              else opts.get("gather", _ROW_SPLIT[backend]))
-    if backend == "spmv":
-        nv = opts.get("nv", 8)
-        tn = cdiv(n, nv) * nv
-    else:
-        tn = opts.get("tn", 128)
+    tn = opts.get("tn") or _bk.hflex_lane(n)
     npad = cdiv(n, tn * n_model) * tn * n_model
     interpret = opts.get("interpret")
     lp = ((0, 0),) if group is not None else ()
@@ -590,7 +578,7 @@ def row_split_spmm(d: PackedSpMM, mesh, m: int, k: int, n: int,
     def local(vals, cols, rows, q, b, c, alpha, beta):
         return sextans_spmm_pallas(vals, cols, rows, q, b, c, alpha, beta,
                                    tm=tm, k0=k0, tn=tn,
-                                   gather=gather, interpret=interpret)
+                                   interpret=interpret)
 
     split = jax.shard_map(
         local, mesh=mesh,
@@ -698,7 +686,6 @@ class StreamingPlan:
         self.opts = dict(opts)
         self.dtype = jnp.dtype(dtype)
         self.device_bytes = device_bytes
-        okey = tuple(sorted(self.opts.items()))
 
         d = a.data
         # Host staging: the out-of-core contract — the full payload lives in
@@ -732,6 +719,8 @@ class StreamingPlan:
                 raise ValueError(
                     f"n_tile must be in [1, N={self.n}], got {n_tile}")
         ntile, wc = self._choose_tiling(device_bytes, n_tile, window_chunk)
+        self.opts = _bk.with_lane(a, self.backend, ntile, self.opts)
+        okey = tuple(sorted(self.opts.items()))
         self.n_tile = ntile
         self.n_tiles = cdiv(self.n, ntile)
         self.window_chunk = wc
@@ -1113,7 +1102,7 @@ def plan(
                 stream = working > budget
         tuned = False
         if mode != "off":
-            backend, window_chunk, n_tile, tuned = resolve_plan_knobs(
+            backend, opts, window_chunk, n_tile, tuned = resolve_plan_knobs(
                 a, n, dtype=jnp.dtype(dtype), mode=mode, backend=backend,
                 stream=bool(stream), device_bytes=budget,
                 window_chunk=window_chunk, n_tile=n_tile, opts=opts)
@@ -1182,7 +1171,7 @@ def plan_group(
 
             mode = resolve_mode(autotune)
             if mode != "off":
-                backend, _, _, tuned = resolve_plan_knobs(
+                backend, opts, _, _, tuned = resolve_plan_knobs(
                     a, n, dtype=jnp.dtype(dtype), mode=mode, backend=backend,
                     stream=False, device_bytes=None, window_chunk=None,
                     n_tile=None, opts=opts, group=a.batch)

@@ -789,8 +789,8 @@ def stack_bsr(tensors, device: bool = True) -> SparseTensor:
 
 def from_bsr_weight(w: BsrWeight) -> SparseTensor:
     """Wrap an existing (K, F) BSR *weight* as the SparseTensor ``W^T`` of
-    shape (F, K), so that ``W^T @ x^T = (x @ W)^T`` — the natural bridge from
-    the legacy ``bsr_matmul(x, w)`` orientation to ``spmm(A, b)``."""
+    shape (F, K), so that ``W^T @ x^T = (x @ W)^T`` — the bridge from the
+    ``x @ W`` orientation of a layer to ``spmm(A, b)``."""
     nb, tk, tf = w.blocks.shape
     return SparseTensor(data=w, format=Format.BSR, shape=(w.f, w.k),
                         nse=int(nb * tk * tf))

@@ -190,9 +190,13 @@ class TestTunedPlans:
 
     def test_tune_plan_records_decision(self, tune_dir):
         A = _packed()
+        # the two backends; the kernel at its 128-column and narrow lanes
+        assert {(c.backend, c.tn) for c in at.enumerate_candidates(A, 8)} == {
+            ("jnp", None), ("pallas", 128), ("pallas", 8)}
         res = at.tune_plan(A, 8, repeats=2, measure_top=2)
         assert res.record["schema"] == at.TUNE_SCHEMA
-        assert res.record["backend"] in sp.list_backends()
+        assert res.record["backend"] in sp.list_backends() == ["jnp",
+                                                               "pallas"]
         db = at.get_db()
         assert db.lookup(res.key)["backend"] == res.record["backend"]
         # the stored decision beat or matched the default measurement

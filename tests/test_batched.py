@@ -428,24 +428,25 @@ class TestShardedEnginePlan:
         assert np.array_equal(np.asarray(fn(same, b, c)),
                               np.asarray(fn(packed, b, c)))
 
-    @pytest.mark.parametrize("backend", ["pallas_onehot", "spmv"])
-    def test_row_split_four_devices_bit_exact(self, rng, backend):
+    @pytest.mark.parametrize("n", [16, 8])
+    def test_row_split_four_devices_bit_exact(self, rng, n):
         """A Pallas mesh plan runs each device's own row blocks (shard_map
-        over ``data``): MB = 15 is padded to 16 with empty blocks, every
-        device holds only its quarter of the slabs, and the result is
-        bit-identical to the one-device plan."""
+        over ``data``) at the lane N selects (128 columns for N = 16, the
+        narrow 8 for N = 8): MB = 15 is padded to 16 with empty blocks,
+        every device holds only its quarter of the slabs, and the result
+        is bit-identical to the one-device plan."""
         if len(jax.devices()) < 4:
             pytest.skip("needs 4 devices")
         mesh = jax.make_mesh((4, 1), ("data", "model"))
-        eng = SextansEngine(tm=64, k0=128, chunk=8, impl=backend,
+        eng = SextansEngine(tm=64, k0=128, chunk=8, impl="pallas",
                             interpret=True)
         a = power_law_sparse(900, 700, 5, seed=7)
         packed = eng.pack(a, device=False)
         assert packed.data.mb == 15
-        n = 8 if backend == "spmv" else 16
         b = jnp.asarray(rng.standard_normal((700, n)), jnp.float32)
         c = jnp.asarray(rng.standard_normal((900, n)), jnp.float32)
         fn = eng.sharded_spmm_fn(mesh, packed, n, alpha=1.5, beta=-0.5)
+        assert fn.plan.opts["tn"] == sp.hflex_lane(n)
         vals = fn.plan._operands[0]
         assert {s.data.shape[0] for s in vals.addressable_shards} == {4}
         out = np.asarray(fn(None, b, c))

@@ -282,18 +282,20 @@ class TestSkinnyRoutingTable:
                 picked = sp.resolve_backend(
                     "auto", t, jnp.zeros(bshape, jnp.float32),
                     platform=platform)
-                assert picked not in sp.SKINNY_BACKENDS
+                assert not sp.is_skinny(t, picked, n)
                 assert picked == ("pallas" if platform == "tpu" else "jnp")
 
     def test_hflex_skinny_does_take_the_lane(self, rng):
         hf, _, _ = self._cases(rng)
-        for n, expect_cpu in ((4, "spmv_jnp"), (64, "jnp")):
+        for n, skinny in ((4, True), (64, False)):
             picked = sp.resolve_backend(
                 "auto", hf, jnp.zeros((64, n), jnp.float32), platform="cpu")
-            assert picked == expect_cpu
+            assert picked == "jnp"
+            assert sp.is_skinny(hf, picked, n) == skinny
         assert sp.resolve_backend(
             "auto", hf, jnp.zeros((64, 4), jnp.float32),
-            platform="tpu") == "spmv"
+            platform="tpu") == "pallas"
+        assert sp.hflex_lane(4) == 8
 
 
 class TestDlmcGenerators:

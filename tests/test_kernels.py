@@ -1,5 +1,6 @@
-"""Pallas kernel validation: shape/dtype sweeps against the ref.py oracles
-(interpret mode), both gather strategies, plus BSR and property tests."""
+"""Pallas kernel validation: shape/dtype sweeps against the float64
+oracle (interpret mode), in the single and batched launch modes, plus BSR
+and property tests."""
 
 import numpy as np
 import jax
@@ -12,19 +13,33 @@ from hypothesis import given, settings, strategies as st
 from repro.core.sparse import (
     banded_sparse, power_law_sparse, random_sparse, spmm_reference,
 )
-from repro.kernels.ops import (
-    BsrWeight, bsr_matmul, bsr_pack, pack_for_device, sextans_spmm,
-)
-from repro.kernels.ref import spmm_coo_ref, spmm_dense_ref
+import repro.sparse_api as sp
+
+
+def _spmm(packed, b, c=None, alpha=1.0, beta=0.0, *, impl, tn):
+    opts = {"tn": tn} if impl != "jnp" else {}
+    return sp.spmm(packed, jnp.asarray(b),
+                   None if c is None else jnp.asarray(c), alpha, beta,
+                   backend=impl, **opts)
 
 
 def _check(a, b, c, alpha, beta, tm, k0, tn, impl, interleave=True, atol=2e-4):
     ref = spmm_reference(a, b, c, alpha, beta)
-    packed = pack_for_device(a, tm=tm, k0=k0, chunk=8, interleave=interleave)
-    out = sextans_spmm(packed, jnp.asarray(b), jnp.asarray(c),
-                       alpha=alpha, beta=beta, impl=impl, tn=tn)
+    packed = sp.from_sparse_matrix(a, tm=tm, k0=k0, chunk=8,
+                                   interleave=interleave, bucket=False)
+    out = _spmm(packed, b, c, alpha, beta, impl=impl, tn=tn)
     np.testing.assert_allclose(np.asarray(out), ref,
                                rtol=2e-4, atol=atol * max(1, np.abs(ref).max()))
+
+
+def _bsr_matmul(x, w, impl):
+    """``x @ W`` for a packed (K, F) BSR weight, through ``spmm`` on the
+    transposed view ``(W^T @ x^T)^T``; leading axes of ``x`` pass
+    through."""
+    a = sp.from_bsr_weight(w)                     # W^T, shape (F, K)
+    lead = x.shape[:-1]
+    y = sp.spmm(a, jnp.asarray(x).reshape(-1, w.k).T, backend=impl).T
+    return y.reshape(*lead, w.f)
 
 
 SHAPE_SWEEP = [
@@ -38,13 +53,34 @@ SHAPE_SWEEP = [
 ]
 
 
-@pytest.mark.parametrize("impl", ["pallas", "pallas_onehot", "jnp"])
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
 @pytest.mark.parametrize("m,k,n,d,tm,k0,tn", SHAPE_SWEEP)
 def test_shape_sweep(impl, m, k, n, d, tm, k0, tn, rng):
     a = random_sparse(m, k, d, seed=m + k)
     b = rng.standard_normal((k, n)).astype(np.float32)
     c = rng.standard_normal((m, n)).astype(np.float32)
     _check(a, b, c, 1.25, -0.5, tm, k0, tn, impl)
+
+
+@pytest.mark.parametrize("m,k,n,d,tm,k0,tn", SHAPE_SWEEP)
+def test_shape_sweep_batched(m, k, n, d, tm, k0, tn, rng):
+    """The kernel's batched launch mode at the sweep's geometries: the
+    sweep matrix and a second seeded one, stacked (LW widened to the
+    pair's maximum), each member checked against its float64 reference
+    with its own epilogue."""
+    mats = [random_sparse(m, k, d, seed=m + k + s) for s in (0, 1)]
+    ts = [sp.from_sparse_matrix(a, tm=tm, k0=k0, chunk=8) for a in mats]
+    lw = max(t.data.lw for t in ts)
+    S = sp.stack_hflex([sp.repad_lw(t, lw) if t.data.lw < lw else t
+                        for t in ts])
+    b = rng.standard_normal((2, k, n)).astype(np.float32)
+    c = rng.standard_normal((2, m, n)).astype(np.float32)
+    alpha, beta = np.float32([1.25, -0.75]), np.float32([-0.5, 2.0])
+    out = np.asarray(_spmm(S, b, c, alpha, beta, impl="pallas", tn=tn))
+    for i, a in enumerate(mats):
+        ref = spmm_reference(a, b[i], c[i], alpha[i], beta[i])
+        np.testing.assert_allclose(out[i], ref, rtol=2e-4,
+                                   atol=2e-4 * max(1, np.abs(ref).max()))
 
 
 @pytest.mark.parametrize("impl", ["pallas", "jnp"])
@@ -63,8 +99,8 @@ def test_b_dtypes(dtype, rng):
     a = random_sparse(96, 96, 0.1, seed=7)
     b = jnp.asarray(rng.standard_normal((96, 16)), dtype)
     c = jnp.zeros((96, 16), dtype)
-    packed = pack_for_device(a, tm=32, k0=32, chunk=8)
-    out = sextans_spmm(packed, b, c, impl="pallas", tn=16)
+    packed = sp.from_sparse_matrix(a, tm=32, k0=32, chunk=8, bucket=False)
+    out = _spmm(packed, b, c, impl="pallas", tn=16)
     ref = spmm_reference(a, np.asarray(b, np.float32),
                          np.zeros((96, 16), np.float32))
     tol = 5e-2 if dtype == jnp.bfloat16 else 5e-5
@@ -103,8 +139,9 @@ def test_chunk_sizes(rng):
     b = rng.standard_normal((128, 16)).astype(np.float32)
     ref = spmm_reference(a, b, np.zeros((128, 16), np.float32))
     for chunk in (8, 16, 32, 128):
-        packed = pack_for_device(a, tm=64, k0=64, chunk=chunk)
-        out = sextans_spmm(packed, jnp.asarray(b), impl="pallas", tn=16)
+        packed = sp.from_sparse_matrix(a, tm=64, k0=64, chunk=chunk,
+                                       bucket=False)
+        out = _spmm(packed, b, impl="pallas", tn=16)
         np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4,
                                    atol=2e-4 * np.abs(ref).max())
 
@@ -128,10 +165,10 @@ class TestBsr:
         mask = rng.random((k // 128, f // 128)) < 0.5
         w = (w.reshape(k // 128, 128, f // 128, 128)
              * mask[:, None, :, None]).reshape(k, f)
-        bw = bsr_pack(w, 128, 128)
+        bw = sp.pack_bsr_weight(w, 128, 128)
         x = rng.standard_normal((100, k)).astype(np.float32)
         for impl in ("pallas", "jnp"):
-            y = bsr_matmul(jnp.asarray(x), bw, impl=impl)
+            y = _bsr_matmul(x, bw, impl)
             np.testing.assert_allclose(np.asarray(y), x @ w, rtol=2e-4,
                                        atol=1e-3)
 
@@ -139,17 +176,17 @@ class TestBsr:
         k, f = 128, 256
         w = np.zeros((k, f), np.float32)
         w[:, :128] = rng.standard_normal((k, 128))
-        bw = bsr_pack(w, 128, 128)
+        bw = sp.pack_bsr_weight(w, 128, 128)
         assert bw.blocks.shape[0] == 1
         x = rng.standard_normal((32, k)).astype(np.float32)
-        y = bsr_matmul(jnp.asarray(x), bw, impl="pallas")
+        y = _bsr_matmul(x, bw, "pallas")
         np.testing.assert_allclose(np.asarray(y), x @ w, rtol=2e-4, atol=1e-3)
 
     def test_batch_leading_dims(self, rng):
         k, f = 128, 128
         w = rng.standard_normal((k, f)).astype(np.float32)
-        bw = bsr_pack(w, 128, 128)
+        bw = sp.pack_bsr_weight(w, 128, 128)
         x = rng.standard_normal((2, 5, k)).astype(np.float32)
-        y = bsr_matmul(jnp.asarray(x), bw, impl="pallas")
+        y = _bsr_matmul(x, bw, "pallas")
         assert y.shape == (2, 5, f)
         np.testing.assert_allclose(np.asarray(y), x @ w, rtol=2e-4, atol=1e-3)

@@ -128,16 +128,11 @@ class TestPlanIntegration:
         """PackedSpMM callers get a fresh SparseTensor wrapper per call; the
         plan cache must key on the caller's object, not the wrapper
         (regression: one leaked plan per spmm call)."""
-        import warnings
-
         from repro.core.engine import SextansEngine
-        from repro.kernels.ops import pack_for_device
 
         rng = np.random.default_rng(3)
         a = random_sparse(64, 64, 0.1, seed=1)
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            packed = pack_for_device(a, tm=32, k0=32, chunk=8)
+        packed = sp.pack_hflex(a, tm=32, k0=32, chunk=8)
         eng = SextansEngine(tm=32, k0=32, chunk=8, impl="jnp")
         b = jnp.asarray(rng.standard_normal((64, 8)), jnp.float32)
         for _ in range(5):

@@ -19,7 +19,7 @@ import pytest
 import repro.sparse_api as sp
 from repro.core.perfmodel import packed_event_cycles
 from repro.core.sparse import power_law_sparse, spmm_reference
-from repro.launch.policy import (ABVEC_BACKENDS, FLAT_BACKENDS, GroupSketch,
+from repro.launch.policy import (ABVEC_BACKENDS, GroupSketch,
                                  MergeCluster, MergePolicy, family_key)
 from repro.core.hflex import slab_lw
 from repro.sparse_api import Format, from_sparse_matrix, repad_lw
@@ -181,8 +181,9 @@ class TestFoldGateAndAdmission:
         assert not pol.fold_epilogue("my_custom_backend")
 
     def test_abvec_backends_are_registered(self):
-        assert ABVEC_BACKENDS <= set(sp.list_backends())
-        assert FLAT_BACKENDS <= ABVEC_BACKENDS
+        # both built-in backends fold: the kernel's SMEM (G, 2) epilogue
+        # and jnp's broadcast one
+        assert ABVEC_BACKENDS == set(sp.list_backends()) == {"pallas", "jnp"}
 
     def test_full_enough_grows_with_members(self):
         pol = MergePolicy(dispatch_overhead_cycles=5e3, fill_ratio=0.5)

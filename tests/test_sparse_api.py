@@ -29,7 +29,7 @@ class TestSparseTensor:
         b = rng.standard_normal((70, 16)).astype(np.float32)
         c = rng.standard_normal((60, 16)).astype(np.float32)
         ref = spmm_reference(a, b, c, 1.25, -0.5)
-        for backend in ("pallas", "pallas_onehot", "jnp"):
+        for backend in ("pallas", "jnp"):
             opts = {"tn": 16} if backend != "jnp" else {}
             out = sp.spmm(A, b, c, 1.25, -0.5, backend=backend, **opts)
             np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4,
@@ -162,21 +162,30 @@ class TestAutodiff:
 
 class TestBackendRegistry:
     def test_explicit_dispatch_and_validation(self):
+        from repro.sparse_api import backends as _bk
+
         _, A = _tensor()
         assert sp.resolve_backend("jnp", A) == "jnp"
-        for name in ("pallas", "pallas_onehot", "jnp"):
-            assert name in sp.list_backends()
+        # one chip kernel per format, and its XLA reference
+        assert sp.list_backends() == ["jnp", "pallas"]
         with pytest.raises(KeyError):
             sp.get_backend("no_such_backend")
         w = np.ones((16, 16), np.float32)
         B = sp.from_dense(w, format=sp.Format.BSR, block=(16, 16))
-        with pytest.raises(ValueError):           # HFLEX-only backend
-            sp.resolve_backend("pallas_onehot", B)
+        sp.register_backend("test_hflex_only", _bk._backend_jnp,
+                            formats=(sp.Format.HFLEX,), overwrite=True)
+        try:
+            assert sp.resolve_backend("test_hflex_only", A) == (
+                "test_hflex_only")
+            with pytest.raises(ValueError):       # HFLEX-only backend
+                sp.resolve_backend("test_hflex_only", B)
+        finally:
+            _bk._REGISTRY.pop("test_hflex_only")
 
     def test_auto_policy(self):
         _, A = _tensor()                           # density 0.08
         assert sp.resolve_backend("auto", A, platform="cpu") == "jnp"
-        assert sp.resolve_backend("auto", A, platform="tpu") == "pallas_onehot"
+        assert sp.resolve_backend("auto", A, platform="tpu") == "pallas"
         a_dense, = (random_sparse(32, 32, 0.5, seed=0),)
         D = sp.from_sparse_matrix(a_dense, tm=32, k0=32, bucket=False)
         assert sp.resolve_backend("auto", D, platform="tpu") == "jnp"
@@ -192,16 +201,21 @@ class TestBackendRegistry:
             return (alpha * a.todense() @ b
                     + beta * c.astype(jnp.float32)).astype(b.dtype)
 
+        from repro.sparse_api import backends as _bk
+
         sp.register_backend("test_dense", fake_backend, overwrite=True)
-        a, A = _tensor()
-        b = rng.standard_normal((70, 8)).astype(np.float32)
-        out = sp.spmm(A, b, backend="test_dense")
-        ref = spmm_reference(a, b, np.zeros((60, 8), np.float32))
-        np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4,
-                                   atol=1e-5)
-        assert calls == [sp.Format.HFLEX]
-        with pytest.raises(ValueError):            # no silent clobbering
-            sp.register_backend("test_dense", fake_backend)
+        try:
+            a, A = _tensor()
+            b = rng.standard_normal((70, 8)).astype(np.float32)
+            out = sp.spmm(A, b, backend="test_dense")
+            ref = spmm_reference(a, b, np.zeros((60, 8), np.float32))
+            np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4,
+                                       atol=1e-5)
+            assert calls == [sp.Format.HFLEX]
+            with pytest.raises(ValueError):        # no silent clobbering
+                sp.register_backend("test_dense", fake_backend)
+        finally:
+            _bk._REGISTRY.pop("test_dense")
 
     def test_auto_policy_override(self):
         _, A = _tensor()
@@ -210,34 +224,6 @@ class TestBackendRegistry:
             assert sp.resolve_backend("auto", A, platform="tpu") == "jnp"
         finally:
             sp.set_auto_policy(None)
-
-
-class TestLegacyShims:
-    def test_sextans_spmm_shim(self, rng):
-        from repro.kernels.ops import pack_for_device, sextans_spmm
-
-        a = random_sparse(50, 40, 0.1, seed=3)
-        b = rng.standard_normal((40, 8)).astype(np.float32)
-        c = rng.standard_normal((50, 8)).astype(np.float32)
-        with pytest.deprecated_call():
-            packed = pack_for_device(a, tm=32, k0=32, chunk=8)
-        ref = spmm_reference(a, b, c, 2.0, 0.5)
-        for impl in ("pallas", "jnp"):
-            out = sextans_spmm(packed, jnp.asarray(b), jnp.asarray(c),
-                               alpha=2.0, beta=0.5, impl=impl, tn=8)
-            np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4,
-                                       atol=2e-4 * np.abs(ref).max())
-
-    def test_bsr_matmul_shim(self, rng):
-        from repro.kernels.ops import bsr_matmul, bsr_pack
-
-        w = rng.standard_normal((32, 64)).astype(np.float32)
-        with pytest.deprecated_call():
-            bw = bsr_pack(w, 16, 16)
-        x = rng.standard_normal((2, 5, 32)).astype(np.float32)
-        y = bsr_matmul(jnp.asarray(x), bw, impl="pallas", tb=16)
-        assert y.shape == (2, 5, 64)
-        np.testing.assert_allclose(np.asarray(y), x @ w, rtol=2e-4, atol=1e-3)
 
 
 class TestSparseLinear:

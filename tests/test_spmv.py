@@ -1,16 +1,17 @@
-"""Skinny-N SpMV fast-lane tests.
+"""Skinny-N SpMV lane tests.
 
 Acceptance criteria of the vector lane:
 
-* the NT-less ``spmv`` kernel is **bit-identical** to the tall-N Sextans
-  kernel (per-column math is shared discipline) and ``spmv_jnp`` is
-  bit-identical to ``jnp`` (same function, own routing name);
-* the default ``auto`` policy routes HFLEX requests with
-  N <= ``SKINNY_N_MAX`` to the lane — ``spmv`` on TPU, ``spmv_jnp``
-  elsewhere — without disturbing the existing platform/format/density
-  rules (the policy table is pinned below);
-* plans, the engine and the serving scheduler resolve/route/count the lane
-  (``skinny_dispatches``), and the lane streams and differentiates.
+* ``pallas`` on HFLEX takes its column tile from N (``hflex_lane``): the
+  narrow lane, 8·⌈N/8⌉, for N <= ``skinny_n_max()``, else 128; an
+  explicit ``tn=`` wins.  The narrow lane is **bit-identical** to the
+  128-column kernel (per-column math is shared discipline);
+* the default ``auto`` policy picks the backend from platform, format and
+  density only — ``pallas`` on TPU, ``jnp`` elsewhere — and the lane
+  follows N (the (backend, lane) table is pinned below);
+* plans, the engine and the serving scheduler count SpMV-shaped
+  dispatches (``is_skinny`` -> ``skinny_dispatches``), and the lane
+  streams and differentiates.
 """
 
 import numpy as np
@@ -20,7 +21,7 @@ import pytest
 
 import repro.sparse_api as sp
 from repro.core.sparse import power_law_sparse, spmm_reference
-from repro.sparse_api.backends import _default_auto_policy, _operand_width
+from repro.sparse_api.backends import _default_auto_policy
 
 TALL_OPTS = dict(tn=16, interpret=True)
 
@@ -34,6 +35,14 @@ def _packed(m=300, k=500, seed=1, n=5, tm=64, k0=64):
     return a, A, b, c
 
 
+def _route(a, n, platform):
+    """(backend, HFLEX column tile) the default policy gives a width-``n``
+    call; the tile is None off the kernel."""
+    backend = _default_auto_policy(a, np.zeros((a.shape[1], n), np.float32),
+                                   platform=platform)
+    return backend, (sp.hflex_lane(n) if backend == "pallas" else None)
+
+
 class TestSpmvKernel:
     @pytest.mark.parametrize("n", [1, 3, 5, 8])
     def test_bit_identical_to_tall_n_kernel(self, n):
@@ -42,22 +51,29 @@ class TestSpmvKernel:
         _, A, b, c = _packed(n=n)
         y_tall = np.asarray(sp.spmm(A, b, c, 1.25, -0.5, backend="pallas",
                                     **TALL_OPTS))
-        y_v = np.asarray(sp.spmm(A, b, c, 1.25, -0.5, backend="spmv",
+        y_v = np.asarray(sp.spmm(A, b, c, 1.25, -0.5, backend="pallas",
                                  interpret=True))
         np.testing.assert_array_equal(y_v, y_tall)
 
     def test_onehot_gather_variant(self):
+        """The lane is a width, not a name: ``pallas`` at N = 5 runs the
+        one-hot kernel at TN = 8, exactly as an explicit ``tn=8`` does,
+        and an explicit ``tn=`` wins over the lane."""
         _, A, b, c = _packed()
-        y_tall = np.asarray(sp.spmm(A, b, c, 2.0, 0.5,
-                                    backend="pallas_onehot", **TALL_OPTS))
-        y_v = np.asarray(sp.spmm(A, b, c, 2.0, 0.5, backend="spmv",
-                                 gather="onehot", interpret=True))
-        np.testing.assert_array_equal(y_v, y_tall)
+        assert sp.hflex_lane(b.shape[1]) == 8
+        y_lane = np.asarray(sp.spmm(A, b, c, 2.0, 0.5, backend="pallas",
+                                    interpret=True))
+        y_tn8 = np.asarray(sp.spmm(A, b, c, 2.0, 0.5, backend="pallas",
+                                   tn=8, interpret=True))
+        np.testing.assert_array_equal(y_lane, y_tn8)
+        y_tall = np.asarray(sp.spmm(A, b, c, 2.0, 0.5, backend="pallas",
+                                    **TALL_OPTS))
+        np.testing.assert_array_equal(y_lane, y_tall)
 
     def test_matches_reference(self):
         a, A, b, c = _packed(seed=3)
         ref = spmm_reference(a, b, c, 1.5, -0.25)
-        y = np.asarray(sp.spmm(A, b, c, 1.5, -0.25, backend="spmv",
+        y = np.asarray(sp.spmm(A, b, c, 1.5, -0.25, backend="pallas",
                                interpret=True))
         np.testing.assert_allclose(y, ref, rtol=2e-4,
                                    atol=2e-4 * max(1, np.abs(ref).max()))
@@ -68,37 +84,45 @@ class TestSpmvKernel:
         _, A2, _, _ = _packed(seed=2)
         S = sp.stack_hflex([A1, A2])
         bg = np.stack([b1, rng.standard_normal(b1.shape).astype(np.float32)])
-        yg = np.asarray(sp.spmm(S, bg, backend="spmv", interpret=True))
+        yg = np.asarray(sp.spmm(S, bg, backend="pallas", interpret=True))
         for i, Ai in enumerate((A1, A2)):
             np.testing.assert_array_equal(
-                yg[i], np.asarray(sp.spmm(Ai, bg[i], backend="spmv",
+                yg[i], np.asarray(sp.spmm(Ai, bg[i], backend="pallas",
                                           interpret=True)))
 
     def test_streams_through_spmv_hooks(self):
-        """The lane's StreamOps carry the raw f32 accumulator bit-exactly —
-        the out-of-core tier works at vector widths too."""
+        """The kernel's StreamOps carry the raw f32 accumulator bit-exactly
+        at the narrow lane — the out-of-core tier works at vector widths
+        too."""
         _, A, b, c = _packed()
-        y_res = np.asarray(sp.spmm(A, b, c, 1.25, -0.5, backend="spmv",
+        y_res = np.asarray(sp.spmm(A, b, c, 1.25, -0.5, backend="pallas",
                                    interpret=True))
-        P = sp.plan(A, b.shape[1], backend="spmv", stream=True,
+        P = sp.plan(A, b.shape[1], backend="pallas", stream=True,
                     window_chunk=3, interpret=True)
         np.testing.assert_array_equal(np.asarray(P.run(b, c, 1.25, -0.5)),
                                       y_res)
 
     def test_rejects_bsr(self):
+        """The lane is HFLEX's: a skinny BSR product keeps the tile
+        kernel's 128-row x tile, is not counted as SpMV-shaped, and
+        matches the dense product."""
         rng = np.random.default_rng(0)
-        B = sp.from_dense(rng.standard_normal((64, 96)).astype(np.float32),
-                          format=sp.Format.BSR, block=(16, 16))
-        with pytest.raises(ValueError):
-            sp.spmm(B, rng.standard_normal((96, 4)).astype(np.float32),
-                    backend="spmv")
+        w = rng.standard_normal((64, 96)).astype(np.float32)
+        B = sp.from_dense(w, format=sp.Format.BSR, block=(16, 16))
+        x = rng.standard_normal((96, 4)).astype(np.float32)
+        assert not sp.is_skinny(B, "pallas", 4)
+        y = np.asarray(sp.spmm(B, x, backend="pallas", interpret=True))
+        np.testing.assert_allclose(y, w @ x, rtol=2e-4, atol=1e-4)
 
 
 class TestSpmvJnpTwin:
     def test_bit_identical_to_jnp(self):
+        """Off-TPU a skinny call resolves to ``jnp`` itself: ``auto`` and
+        an explicit ``jnp`` are one executable."""
         _, A, b, c = _packed()
+        assert sp.resolve_backend("auto", A, b, platform="cpu") == "jnp"
         y_j = np.asarray(sp.spmm(A, b, c, 1.25, -0.5, backend="jnp"))
-        y_v = np.asarray(sp.spmm(A, b, c, 1.25, -0.5, backend="spmv_jnp"))
+        y_v = np.asarray(sp.spmm(A, b, c, 1.25, -0.5, backend="auto"))
         np.testing.assert_array_equal(y_v, y_j)
 
     def test_grads_match_dense_oracle(self):
@@ -107,7 +131,7 @@ class TestSpmvJnpTwin:
 
         def loss(v):
             return jnp.sum(jnp.sin(sp.spmm(A.with_values(v), b, c, 1.3, 0.7,
-                                           backend="spmv_jnp")))
+                                           backend="auto")))
 
         def loss_dense(v):
             return jnp.sum(jnp.sin(1.3 * A.with_values(v).todense() @ b
@@ -123,7 +147,7 @@ class TestSpmvJnpTwin:
 
 
 class TestAutoPolicyTable:
-    """Pins the default ``auto`` dispatch table, N-awareness included."""
+    """Pins the default ``auto`` dispatch table as (backend, lane) pairs."""
 
     def _A(self, density=0.05):
         m, k = 64, 128
@@ -133,66 +157,66 @@ class TestAutoPolicyTable:
         d[rng.integers(0, m, nnz), rng.integers(0, k, nnz)] = 1.0
         return sp.from_dense(d, tm=32, k0=32, chunk=8)
 
-    def _b(self, n):
-        return np.zeros((128, n), np.float32)
-
     @pytest.mark.parametrize("platform,n,expect", [
-        # skinny HFLEX: the vector lane, platform-split
-        ("tpu", 1, "spmv"),
-        ("tpu", sp.SKINNY_N_MAX, "spmv"),
-        ("cpu", 1, "spmv_jnp"),
-        ("cpu", sp.SKINNY_N_MAX, "spmv_jnp"),
-        # one past the threshold: the old rules verbatim
-        ("tpu", sp.SKINNY_N_MAX + 1, "pallas_onehot"),
-        ("cpu", sp.SKINNY_N_MAX + 1, "jnp"),
-        ("gpu", 64, "jnp"),
+        # skinny HFLEX: the kernel's narrow lane on TPU, jnp elsewhere
+        ("tpu", 1, ("pallas", 8)),
+        ("tpu", sp.SKINNY_N_MAX, ("pallas", 8)),
+        ("cpu", 1, ("jnp", None)),
+        ("cpu", sp.SKINNY_N_MAX, ("jnp", None)),
+        # one past the threshold: the 128-column lane
+        ("tpu", sp.SKINNY_N_MAX + 1, ("pallas", 128)),
+        ("cpu", sp.SKINNY_N_MAX + 1, ("jnp", None)),
+        ("gpu", 64, ("jnp", None)),
     ])
     def test_hflex_width_split(self, platform, n, expect):
-        assert _default_auto_policy(self._A(), self._b(n),
-                                    platform=platform) == expect
+        assert _route(self._A(), n, platform) == expect
 
     def test_unknown_width_keeps_old_rules(self):
         A = self._A()
-        assert _default_auto_policy(A, None, platform="tpu") == "pallas_onehot"
+        assert _default_auto_policy(A, None, platform="tpu") == "pallas"
         assert _default_auto_policy(A, None, platform="cpu") == "jnp"
 
     def test_dense_ish_tpu_overrides_skinny(self):
-        """On TPU the density>0.25 rule wins over the skinny lane (slab
-        padding blows up either kernel); off-TPU the flat twin has no slab
-        padding, so skinny still applies."""
+        """On TPU the density>0.25 rule wins over the narrow lane (slab
+        padding blows up the kernel at any width); off-TPU it is ``jnp``
+        either way, and the call still counts as SpMV-shaped."""
         A = self._A(density=0.5)
         assert A.density > 0.25
-        assert _default_auto_policy(A, self._b(4), platform="tpu") == "jnp"
-        assert _default_auto_policy(A, self._b(4),
-                                    platform="cpu") == "spmv_jnp"
+        assert _route(A, 4, "tpu") == ("jnp", None)
+        assert _route(A, 4, "cpu") == ("jnp", None)
+        assert sp.is_skinny(A, "jnp", 4)
 
     def test_bsr_never_takes_the_lane(self):
         rng = np.random.default_rng(0)
         B = sp.from_dense(rng.standard_normal((64, 96)).astype(np.float32),
                           format=sp.Format.BSR, block=(16, 16))
-        assert _default_auto_policy(B, self._b(4), platform="tpu") == "pallas"
-        assert _default_auto_policy(B, self._b(4), platform="cpu") == "jnp"
+        b = np.zeros((96, 4), np.float32)
+        assert _default_auto_policy(B, b, platform="tpu") == "pallas"
+        assert _default_auto_policy(B, b, platform="cpu") == "jnp"
+        assert not sp.is_skinny(B, "pallas", 4)
 
     def test_operand_width(self):
-        assert _operand_width(np.zeros((128, 4))) == 4
-        assert _operand_width(np.zeros(128)) == 1        # matvec path
-        assert _operand_width(jax.ShapeDtypeStruct((128, 7),
-                                                   jnp.float32)) == 7
-        assert _operand_width(None) is None
+        """The lane width table: 8·⌈N/8⌉ up to the threshold, 128 above
+        it, and ``narrow=`` forces either side."""
+        assert [sp.hflex_lane(n) for n in (1, 5, 8, 9, 128, 1000)] == [
+            8, 8, 8, 128, 128, 128]
+        assert sp.hflex_lane(20, narrow=True) == 24
+        assert sp.hflex_lane(3, narrow=False) == 128
 
     def test_resolve_backend_n_stub(self):
-        """``resolve_backend(..., n=)`` synthesizes a shape stub so N-aware
-        resolution works before the operand exists."""
+        """``resolve_backend(..., n=)`` synthesizes a shape stub, so a
+        custom policy that reads the operand's width resolves before the
+        operand exists."""
         A = self._A()
-        assert sp.resolve_backend("auto", A, n=4,
-                                  platform="tpu") == "spmv"
-        assert sp.resolve_backend("auto", A, n=4,
-                                  platform="cpu") == "spmv_jnp"
-        assert sp.resolve_backend("auto", A, n=64,
-                                  platform="tpu") == "pallas_onehot"
-        # no operand, no n: pre-operand resolution keeps the old rules
-        assert sp.resolve_backend("auto", A,
-                                  platform="tpu") == "pallas_onehot"
+        try:
+            sp.set_auto_policy(lambda a, b, platform=None: (
+                "pallas" if b is not None and b.shape[-1] <= 4 else "jnp"))
+            assert sp.resolve_backend("auto", A, n=4) == "pallas"
+            assert sp.resolve_backend("auto", A, n=64) == "jnp"
+            # no operand, no n: the policy sees None
+            assert sp.resolve_backend("auto", A) == "jnp"
+        finally:
+            sp.set_auto_policy(None)
 
 
 class TestSkinnyThresholdTunable:
@@ -208,23 +232,17 @@ class TestSkinnyThresholdTunable:
         d[rng.integers(0, m, nnz), rng.integers(0, k, nnz)] = 1.0
         return sp.from_dense(d, tm=32, k0=32, chunk=8)
 
-    def _b(self, n):
-        return np.zeros((128, n), np.float32)
-
     @pytest.mark.parametrize("thr", [2, 12])
     def test_override_moves_the_boundary(self, thr):
         A = self._A()
+        lane = -(-thr // 8) * 8
         try:
             sp.set_skinny_n_max(thr)
             assert sp.skinny_n_max() == thr
-            assert _default_auto_policy(A, self._b(thr),
-                                        platform="cpu") == "spmv_jnp"
-            assert _default_auto_policy(A, self._b(thr + 1),
-                                        platform="cpu") == "jnp"
-            assert _default_auto_policy(A, self._b(thr),
-                                        platform="tpu") == "spmv"
-            assert _default_auto_policy(A, self._b(thr + 1),
-                                        platform="tpu") == "pallas_onehot"
+            assert sp.is_skinny(A, "jnp", thr)
+            assert not sp.is_skinny(A, "jnp", thr + 1)
+            assert _route(A, thr, "tpu") == ("pallas", lane)
+            assert _route(A, thr + 1, "tpu") == ("pallas", 128)
         finally:
             sp.set_skinny_n_max(None)
 
@@ -232,22 +250,19 @@ class TestSkinnyThresholdTunable:
         A = self._A()
         try:
             sp.set_skinny_n_max(0)
-            assert _default_auto_policy(A, self._b(1),
-                                        platform="cpu") == "jnp"
+            assert _route(A, 1, "tpu") == ("pallas", 128)
+            assert not sp.is_skinny(A, "jnp", 1)
         finally:
             sp.set_skinny_n_max(None)
 
     def test_env_beats_default_override_beats_env(self, monkeypatch):
         monkeypatch.setenv("SEXTANS_SKINNY_N_MAX", "12")
         assert sp.skinny_n_max() == 12
-        A = self._A()
-        assert _default_auto_policy(A, self._b(12),
-                                    platform="cpu") == "spmv_jnp"
+        assert sp.hflex_lane(12) == 16
         try:
             sp.set_skinny_n_max(3)
             assert sp.skinny_n_max() == 3       # override wins over env
-            assert _default_auto_policy(A, self._b(12),
-                                        platform="cpu") == "jnp"
+            assert sp.hflex_lane(12) == 128
         finally:
             sp.set_skinny_n_max(None)
         assert sp.skinny_n_max() == 12          # env chain restored
@@ -257,14 +272,20 @@ class TestSkinnyThresholdTunable:
         assert sp.skinny_n_max() == sp.SKINNY_N_MAX
 
     def test_plan_routing_follows_live_threshold(self):
-        """``plan(backend="auto")`` consults the live threshold, so a
-        DB-tuned value changes routing without re-imports."""
-        _, A, _, _ = _packed()
+        """A plan's lane follows the live threshold, so a DB-tuned value
+        changes the kernel's width without re-imports; the plan records
+        it in ``opts`` and runs what an explicit ``tn=`` call runs."""
+        _, A, b, _ = _packed(n=4)
         try:
-            sp.set_skinny_n_max(2)
-            assert sp.plan(A, 4).backend not in sp.SKINNY_BACKENDS
-            sp.set_skinny_n_max(16)
-            assert sp.plan(A, 16).backend in sp.SKINNY_BACKENDS
+            for thr, tn in ((2, 128), (16, 8)):
+                sp.set_skinny_n_max(thr)
+                P = sp.plan(A, 4, backend="pallas", interpret=True)
+                assert sp.is_skinny(A, P.backend, 4) == (tn == 8)
+                assert P.opts["tn"] == tn
+                np.testing.assert_array_equal(
+                    np.asarray(P.run(b)),
+                    np.asarray(sp.spmm(A, b, backend="pallas", tn=tn,
+                                       interpret=True)))
         finally:
             sp.set_skinny_n_max(None)
 
@@ -273,9 +294,9 @@ class TestSkinnyRouting:
     def test_plan_resolves_lane(self):
         _, A, _, _ = _packed()
         P = sp.plan(A, 4, backend="auto")
-        assert P.backend in sp.SKINNY_BACKENDS
+        assert sp.is_skinny(A, P.backend, P.n)
         P_tall = sp.plan(A, 64, backend="auto")
-        assert P_tall.backend not in sp.SKINNY_BACKENDS
+        assert not sp.is_skinny(A, P_tall.backend, P_tall.n)
 
     def test_engine_counts_skinny_dispatches(self):
         from repro.core.engine import SextansEngine

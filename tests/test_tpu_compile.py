@@ -113,8 +113,7 @@ def _compile_hflex(shape, lead=(), n=128, tn=128, nw=NW, accumulate=False,
 
     def f(vals, cols, rows, q, b, c, alpha, beta):
         return sextans_spmm_pallas(vals, cols, rows, q, b, c, alpha, beta,
-                                   tm=TM, k0=K0, tn=tn,
-                                   gather="onehot", interpret=False,
+                                   tm=TM, k0=K0, tn=tn, interpret=False,
                                    accumulate=accumulate)
 
     out = (Format(Layout(major_to_minor=(0, 1)), vals.sharding)
@@ -220,8 +219,7 @@ def test_row_split_four_chips(topo, no_cache):
                    m=169343, k=169343, tm=TM, k0=K0, chunk=8,
                    interleaved=True, nnz=0)
     traced, mbp, slab_spec, q_spec = row_split_spmm(
-        d, mesh, 169343, 169343, 128, None, "pallas_onehot",
-        {"interpret": False})
+        d, mesh, 169343, 169343, 128, None, {"interpret": False})
     on = lambda spec: NamedSharding(mesh, spec)
     rep = on(jax.sharding.PartitionSpec())
     slab = (mbp, NW, R, L)
