@@ -22,7 +22,9 @@ and stores zeros.  ``L = min(NF·min(NB, K/TK), NB + NF)`` comes from the
 shapes; steps past a member's walk repeat its last step (no DMA, no
 matmul).  The index maps read the walk from scalar prefetch, so one
 compiled kernel serves any sparsity pattern of the same bucketed geometry
-(HFlex).
+(HFlex).  Every row tile walks the whole list, so a wider ``TB`` streams
+each weight block fewer times in fewer grid steps: the backend takes it
+from the token count (``repro.sparse_api.backends.bsr_row_tile``).
 
 The *ragged* mode (:func:`bsr_matmul_pallas_ragged`, Pallas name
 ``bsr_spmm_ragged``) serves a dropless mixture of experts: the rows of

@@ -9,10 +9,10 @@ One differentiable, format-agnostic SpMM:
     >>> g = jax.grad(lambda v: sp.spmm(A.with_values(v), b).sum())(A.values)
 
 Formats (``Format.HFLEX`` slabs, ``Format.BSR`` tiles) and execution
-backends are orthogonal: ``pallas`` runs the format's chip kernel (on
-HFLEX at a column tile :func:`hflex_lane` takes from N), ``jnp`` is the
-XLA reference, and ``auto`` picks one of them; new ones plug in through
-:func:`register_backend`.
+backends are orthogonal: ``pallas`` runs the format's chip kernel (at a
+tile it takes from N: :func:`hflex_lane` on HFLEX, :func:`bsr_row_tile`
+on BSR), ``jnp`` is the XLA reference, and ``auto`` picks one of them;
+new ones plug in through :func:`register_backend`.
 
 Serving hot loops should prepare a :func:`plan` (an :class:`SpmmPlan`):
 backend resolution, index precompute and executable compilation happen
@@ -49,6 +49,7 @@ from .backends import (
     SKINNY_N_MAX,
     Backend,
     StreamOps,
+    bsr_row_tile,
     get_backend,
     hflex_lane,
     is_kernel,
@@ -128,6 +129,7 @@ __all__ = [
     "skinny_n_max",
     "set_skinny_n_max",
     "hflex_lane",
+    "bsr_row_tile",
     "is_skinny",
     "is_kernel",
     "AUTOTUNE_MODES",

@@ -10,16 +10,19 @@ name.  Two are built in, one chip kernel per format and its reference:
   with its one-hot MXU gather (HFLEX) or the BSR tile kernel (single,
   batched and ragged modes).  On HFLEX its column tile comes from the
   dense width N (:func:`hflex_lane`): a narrow lane of 8·⌈N/8⌉ columns
-  for SpMV-shaped calls (N ≤ :func:`skinny_n_max`), else 128.
+  for SpMV-shaped calls (N ≤ :func:`skinny_n_max`), else 128.  On
+  BSR its row tile comes from N too (:func:`bsr_row_tile`): up to 2,048
+  token rows a tile, so a prefill chunk streams each weight block once.
 * ``jnp``    — segment-sum / einsum XLA path: the reference, and the
   off-TPU production and autodiff path.
 * ``auto``   — resolves to one of the above from platform, format and
   density (override with :func:`set_auto_policy`).
 
 This module is the one place that knows what the names mean: callers ask
-:func:`hflex_lane`, :func:`is_skinny` and :func:`is_kernel` instead of
-testing names.  ``register_backend`` stays the extension point: a new
-format plugs in as (new Format, new backend) without another API fork.
+:func:`hflex_lane`, :func:`bsr_row_tile`, :func:`is_skinny` and
+:func:`is_kernel` instead of testing names.  ``register_backend`` stays
+the extension point: a new format plugs in as (new Format, new backend)
+without another API fork.
 """
 
 from __future__ import annotations
@@ -56,6 +59,7 @@ __all__ = [
     "skinny_n_max",
     "set_skinny_n_max",
     "hflex_lane",
+    "bsr_row_tile",
     "with_lane",
     "is_skinny",
     "is_kernel",
@@ -118,6 +122,25 @@ def hflex_lane(n: int, narrow: Optional[bool] = None) -> int:
     return _WIDE_LANE
 
 
+#: the BSR kernel's widest row tile: its (TB, 128) x and output tiles,
+#: double-buffered, and the f32 accumulator grow with TB, and at 4,096
+#: rows the kernel needs 16.82 MiB of scoped VMEM, over v5e's 16 MiB
+_BSR_ROW_TILE_MAX = 2048
+
+
+def bsr_row_tile(n: int) -> int:
+    """Row tile (TB) of the BSR kernel for dense width ``n`` (the token
+    count): the largest multiple of 128 that divides ``n`` rounded up to
+    128, at most :data:`_BSR_ROW_TILE_MAX`, so no row is padded beyond
+    what 128-row tiles pad.  Every row tile walks the whole block list,
+    so a wider tile streams each weight block fewer times and takes
+    fewer grid steps.  An explicit ``tn=`` backend option overrides
+    this; the ragged mode keeps :data:`RAGGED_TILE`."""
+    units = cdiv(max(int(n), 1), 128)
+    cap = min(units, _BSR_ROW_TILE_MAX // 128)
+    return 128 * max(d for d in range(1, cap + 1) if units % d == 0)
+
+
 def is_kernel(backend: str) -> bool:
     """True when ``backend`` runs the format's Pallas kernel (which XLA
     cannot partition, and which interprets off-TPU)."""
@@ -126,12 +149,13 @@ def is_kernel(backend: str) -> bool:
 
 def with_lane(a: SparseTensor, backend: str, n: int,
               opts: Dict) -> Dict:
-    """``opts`` with the HFLEX kernel's column tile pinned for width ``n``
-    (``tn = hflex_lane(n)``, unless the caller set one), so an executable
-    key records the lane the trace will use."""
-    if (a.format is Format.HFLEX and is_kernel(backend)
-            and opts.get("tn") is None):
-        return dict(opts, tn=hflex_lane(n))
+    """``opts`` with the kernel's tile along the dense width pinned for
+    width ``n`` (``tn``: :func:`hflex_lane` on HFLEX, :func:`bsr_row_tile`
+    on BSR, unless the caller set one), so an executable key records the
+    tile the trace will use."""
+    if is_kernel(backend) and opts.get("tn") is None:
+        tile = hflex_lane if a.format is Format.HFLEX else bsr_row_tile
+        return dict(opts, tn=tile(n))
     return dict(opts)
 
 
@@ -564,7 +588,7 @@ def _bsr_pallas(a: SparseTensor, b, c, alpha, beta, *, tn, interpret):
     w = a.data
     m, k = a.shape
     n = b.shape[-1]
-    tn = tn or 128
+    tn = tn or bsr_row_tile(n)
     npad = cdiv(n, tn) * tn
     if a.batch is not None:
         xb = jnp.pad(b, ((0, 0), (0, w.k - k), (0, 0)))

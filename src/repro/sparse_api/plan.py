@@ -98,10 +98,13 @@ __all__ = ["SpmmPlan", "StreamingPlan", "RaggedPlan", "plan", "plan_group",
 # batched scheduler's amortization target: dispatches << requests).
 # ``window_dispatches`` counts the streaming tier's per-chunk dispatches
 # separately (they are deliberate pipeline steps, not missed batching).
+# ``bsr_wide_tile_dispatches`` counts BSR kernel dispatches whose row tile
+# (``backends.bsr_row_tile``) is wider than 128 rows.
 # A second process reuses compiled code through JAX's persistent
 # compilation cache (repro.compile_cache), not through this table.
 PLAN_STATS: Dict[str, int] = {"exec_hits": 0, "exec_misses": 0,
-                              "dispatches": 0, "window_dispatches": 0}
+                              "dispatches": 0, "window_dispatches": 0,
+                              "bsr_wide_tile_dispatches": 0}
 
 _EXEC_CACHE: Dict[Tuple, Any] = {}
 
@@ -253,6 +256,9 @@ class SpmmPlan:
         okey = tuple(sorted(self.opts.items()))
         if a.format is Format.BSR:
             _check_bsr_tiles(a, self.backend, self.opts)
+        self._wide_tile = (a.format is Format.BSR
+                           and _bk.is_kernel(self.backend)
+                           and self.opts["tn"] > 128)
 
         m, k, n = self.m, self.k, self.n
         g = self.group
@@ -462,6 +468,8 @@ class SpmmPlan:
                 ops = (ops[:self._values_slot] + (values,)
                        + ops[self._values_slot + 1:])
             PLAN_STATS["dispatches"] += 1
+            if self._wide_tile:
+                PLAN_STATS["bsr_wide_tile_dispatches"] += 1
             return self._compiled(*ops, b, c, alpha, beta)
 
     def __call__(self, b, c=None, alpha=1.0, beta=0.0, **kw) -> jax.Array:

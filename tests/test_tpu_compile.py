@@ -24,6 +24,7 @@ from repro.kernels.bsr_spmm import (bsr_matmul_pallas,
                                     bsr_matmul_pallas_batched,
                                     bsr_matmul_pallas_ragged)
 from repro.kernels.sextans_spmm import sextans_spmm_pallas
+from repro.sparse_api.backends import bsr_row_tile
 from repro.sparse_api.plan import row_split_spmm
 from repro.sparse_api.tensor import PackedSpMM
 
@@ -47,6 +48,10 @@ MOE_E, MOE_D, MOE_FF, MOE_NB, MOE_ROWS = 64, 2048, 1408, 18, 20480
 # sparsity, a 2,048-token prefill chunk: gate and up as one group of two
 # (258 blocks each in the 512-block bucket), down alone (258 blocks).
 OLMO_D, OLMO_FF, OLMO_T = 3840, 11008, 2048
+# DeepSeek-V2-Lite's two shared experts as one FFN of 2816 at 90% 128x128
+# block sparsity, on the same 2,048-token chunk: gate and up as a group
+# of two (35 blocks each in the 64-block bucket), down alone (35 blocks).
+MOE_SHARED = 2816
 
 
 @pytest.fixture(scope="module")
@@ -166,25 +171,43 @@ def test_bsr_grouped_ffn(shape):
     _check(jax.jit(f).lower(x, blocks, brow, indptr).compile())
 
 
-@pytest.mark.parametrize("g,k,f,nb", [(2, OLMO_D, OLMO_FF, 512),
-                                      (None, OLMO_FF, OLMO_D, 258)])
-def test_bsr_olmo_prefill(shape, g, k, f, nb):
-    """The Olmo prefill cell's gate+up (batched) and down (single) calls:
-    the walk's tables go to scalar prefetch flat, and the kernel keeps
-    the name the trace metrics read."""
+def _compile_bsr_prefill(shape, g, k, f, nb, tokens):
+    """One BSR call of a prefill chunk, batched (``g`` members) or single,
+    at the row tile :func:`bsr_row_tile` picks for ``tokens``: the walk's
+    tables go to scalar prefetch flat, the tile's x, output and
+    accumulator fit the kernel's VMEM, and the kernel keeps the name the
+    trace metrics read."""
     lead = () if g is None else (g,)
-    x = shape((*lead, OLMO_T, k), jnp.float32)
+    x = shape((*lead, tokens, k), jnp.float32)
     blocks = shape((*lead, nb, 128, 128), jnp.float32)
     brow = shape((*lead, nb), jnp.int32)
     indptr = shape((*lead, f // 128 + 1), jnp.int32)
     call = bsr_matmul_pallas if g is None else bsr_matmul_pallas_batched
+    tb = bsr_row_tile(tokens)
 
     def run(x, blocks, brow, indptr):
-        return call(x, blocks, brow, indptr, interpret=False)
+        return call(x, blocks, brow, indptr, tb=tb, interpret=False)
 
     compiled = jax.jit(run).lower(x, blocks, brow, indptr).compile()
     _check(compiled)
     assert "bsr_spmm" in compiled.as_text()
+
+
+@pytest.mark.parametrize("g,k,f,nb", [(2, OLMO_D, OLMO_FF, 512),
+                                      (None, OLMO_FF, OLMO_D, 258)])
+def test_bsr_olmo_prefill(shape, g, k, f, nb):
+    """The Olmo prefill cell's gate+up (batched) and down (single)
+    calls."""
+    assert bsr_row_tile(OLMO_T) == OLMO_T
+    _compile_bsr_prefill(shape, g, k, f, nb, OLMO_T)
+
+
+@pytest.mark.parametrize("g,k,f,nb", [(2, MOE_D, MOE_SHARED, 64),
+                                      (None, MOE_SHARED, MOE_D, 35)])
+def test_bsr_moe_shared_prefill(shape, g, k, f, nb):
+    """The MoE prefill cell's shared experts, gate+up (batched) and down
+    (single), on a 2,048-token chunk."""
+    _compile_bsr_prefill(shape, g, k, f, nb, OLMO_T)
 
 
 @pytest.mark.parametrize("k,f", [(MOE_D, MOE_FF), (MOE_FF, MOE_D)])
